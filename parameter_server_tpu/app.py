@@ -428,6 +428,7 @@ def _build_async_lr(cfg: AppConfig) -> Callable[[], dict]:
     over the LoopbackVan with BSP/SSP/ASP gating and elastic workloads."""
 
     def run() -> dict:
+        import jax
         import numpy as np
 
         from parameter_server_tpu.core.fleet import FleetMonitor
@@ -440,6 +441,10 @@ def _build_async_lr(cfg: AppConfig) -> Callable[[], dict]:
         from parameter_server_tpu.learner.elastic import ElasticTrainer
         from parameter_server_tpu.utils.keys import HashLocalizer
         from parameter_server_tpu.utils.metrics import transport_counters
+        from parameter_server_tpu.utils.platform import (
+            bytes_in_use,
+            role_device,
+        )
 
         nw, ns = cfg.topology.num_workers, cfg.topology.num_servers
         # metered outermost: per-link wire accounting on every logical
@@ -453,10 +458,25 @@ def _build_async_lr(cfg: AppConfig) -> Callable[[], dict]:
             sched.fleet = FleetMonitor()
             tables = {cfg.table.name: cfg.table}
             loc = {cfg.table.name: HashLocalizer(cfg.table.rows)}
-            _servers = {
-                server_id(i): KVServer(posts[server_id(i)], tables, i, ns)
-                for i in range(ns)
-            }
+            # server i keeps its shard on local device i % n; what a shard
+            # costs there is the allocator's growth around its construction
+            servers, placement = {}, {}
+            for i in range(ns):
+                before = bytes_in_use(role_device(i))
+                srv = KVServer(posts[server_id(i)], tables, i, ns)
+                tbl = srv.tables[cfg.table.name]
+                jax.block_until_ready((tbl.value, tbl.state))
+                servers[server_id(i)] = srv
+                placement[server_id(i)] = {
+                    "device": str(srv.device),
+                    "rows": tbl.rows,
+                    "nominal_bytes": tbl.nominal_bytes,
+                    "allocated_bytes": (
+                        None
+                        if before is None
+                        else bytes_in_use(srv.device) - before
+                    ),
+                }
             workers = {
                 worker_id(i): KVWorker(
                     posts[worker_id(i)], tables, ns, localizers=loc
@@ -481,11 +501,30 @@ def _build_async_lr(cfg: AppConfig) -> Callable[[], dict]:
                 ckpt_every=cfg.ckpt_every,
             )
             losses = trainer.run()
+            for sid, srv in servers.items():
+                placement[sid].update(pushes=srv.pushes, pulls=srv.pulls)
             return {
                 "losses": losses,
                 "steps": len(losses),
                 "mean_loss_tail": float(np.mean(losses[-10:])),
                 "last_ckpt_step": trainer.last_ckpt_step,
+                "workloads_done": trainer.pool.num_done(),
+                # workers the Van cut off and survivors covered for, nodes
+                # the heartbeat sweep declared dead, legs answered
+                # ``__error__``, gate deadlines that fired: none on a
+                # healthy run
+                "retired_workers": sorted(trainer._killed),
+                "dead_nodes": [
+                    n.node_id for n in sched.nodes() if not n.alive
+                ],
+                "error_replies": sum(
+                    w.error_replies for w in workers.values()
+                ),
+                "gate_sheds": sum(
+                    w.consist_sheds + w.consist_forced
+                    for w in workers.values()
+                ),
+                "servers": placement,
                 "net": transport_counters(van),
                 "fleet": sched.fleet.snapshot(),
                 "stragglers": sched.fleet.stragglers(),

@@ -310,20 +310,9 @@ def restore_shard(
             f"table shard rows {table.rows} != saved range "
             f"{arrays['value'].shape[0]}"
         )
-    import jax.numpy as jnp
-
-    fills = table.optimizer.state_shapes()
-    value = np.zeros((table.rows + 1, table.dim), np.asarray(table.value).dtype)
-    value[: table.rows] = arrays["value"]
-    table.value = jnp.asarray(value)
-    for k in table.state:
-        buf = np.full(
-            (table.rows + 1, table.dim),
-            fills[k],
-            np.asarray(table.state[k]).dtype,
-        )
-        buf[: table.rows] = arrays[f"state.{k}"]
-        table.state[k] = jnp.asarray(buf)
+    table.install_rows(
+        arrays["value"], {k: arrays[f"state.{k}"] for k in table.state}
+    )
 
 
 def load_global_weights(root: str, step: int, table_name: str) -> np.ndarray:
@@ -688,7 +677,7 @@ def restore_segments(
         for lo, hi in segments
         if hi > lo
     ]
-    dtype = np.asarray(table.value).dtype
+    dtype = np.dtype(table.value.dtype)
     if pieces:
         value = np.concatenate([v for v, _ in pieces], axis=0)
         state = {

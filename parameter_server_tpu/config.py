@@ -444,10 +444,10 @@ class TableConfig:
     #: contiguous ranges — the NodeAssigner scheme); if False it is replicated.
     sharded: bool = True
     #: row gather/scatter kernel on the Push/Pull hot path: "auto"/"xla"
-    #: (take / at[].set — measured at the HBM roofline on v5e, the default
-    #: verdict of bench.py --micro), or "pallas" (DMA kernels,
-    #: ops/scatter.py — interpreter-run off TPU so tests exercise the same
-    #: code path; dim == 128 or dim % 1024 == 0).
+    #: (take / at[].set, the default; no roofline figure measured yet) or
+    #: "pallas" (DMA kernels, ops/scatter.py: compiled on the TPU, an error
+    #: elsewhere unless a test asks for the interpreter; dim == 128 or
+    #: dim % 1024 == 0).
     scatter_impl: str = "auto"
     #: fused push apply: gather → optimizer step → scatter as ONE pass
     #: (``ops.scatter.apply_rows``).  Under ``scatter_impl="pallas"`` this

@@ -69,10 +69,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import numpy as np
 
-try:  # registers bfloat16/fp8 extension dtypes with numpy (ships with jax)
-    import ml_dtypes  # noqa: F401
-except ImportError:  # pragma: no cover - jax env always has it
-    ml_dtypes = None
+import ml_dtypes  # noqa: F401 — registers bfloat16/fp8 dtypes with numpy
 
 from parameter_server_tpu.core.messages import (
     INCARNATION_KEY,

@@ -46,6 +46,12 @@ def worker_id(i: int) -> str:
     return f"W{i}"
 
 
+def node_index(node_id: str) -> int:
+    """``i`` of ``S<i>`` / ``W<i>``; 0 for ids that carry no index."""
+    digits = "".join(itertools.takewhile(str.isdigit, node_id[1:]))
+    return int(digits) if digits else 0
+
+
 def node_role(node_id: str) -> NodeRole:
     if node_id == SCHEDULER:
         return NodeRole.SCHEDULER

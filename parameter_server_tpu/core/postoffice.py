@@ -240,6 +240,16 @@ class Postoffice:
             customer._on_response(msg)
 
 
+class VanError(RuntimeError):
+    """A task failed at the Van, not in this process: a leg was
+    undeliverable or cancelled, or a peer answered ``__error__``.
+
+    The one failure class (with ``TimeoutError``) a learner may read as
+    "this node is partitioned"; anything else — a ``JaxRuntimeError`` from
+    a device step included — is a bug or a device fault and propagates.
+    """
+
+
 class Customer:
     """Async task issuer/handler bound to one Postoffice node.
 
@@ -261,6 +271,9 @@ class Customer:
         self._receivers: dict[int, list[str]] = {}  # per-ts fan-out targets
         self._kept: set[int] = set()  # timestamps whose responses are retained
         self._executed: dict[str, int] = {}  # per-sender executed task time
+        #: legs answered ``__error__`` or dropped as undeliverable, ever —
+        #: fences and gate defers included (they ride the same payload key)
+        self.error_replies = 0
         self._cond = threading.Condition()
         post.register(self)
 
@@ -312,6 +325,7 @@ class Customer:
                 [m.recver for m in undeliverable],
             )
             with self._cond:
+                self.error_replies += len(undeliverable)
                 for m in undeliverable:
                     self._errors.setdefault(ts, []).append(
                         f"{m.recver}: undeliverable"
@@ -424,6 +438,7 @@ class Customer:
                 return
             responded.add(msg.sender)
             if err is not None:
+                self.error_replies += 1
                 self._errors.setdefault(ts, []).append(f"{msg.sender}: {err}")
             if ts in self._responses:
                 self._responses[ts].append(msg)
@@ -440,7 +455,7 @@ class Customer:
         """Raise if any receiver answered task ``ts`` with an error."""
         errs = self.errors(ts)
         if errs:
-            raise RuntimeError(f"task {ts} failed on: " + "; ".join(errs))
+            raise VanError(f"task {ts} failed on: " + "; ".join(errs))
 
     def _finish_locked(self, ts: int) -> None:
         del self._pending[ts]

@@ -152,16 +152,12 @@ def _lib() -> ctypes.CDLL:
 
 
 def _load_wire(wire: str) -> Tuple[ctypes.CDLL, str]:
-    """Resolve the wire backend: requested (env beats config), with a quiet
-    fallback from epoll to threaded when the epoll core fails to build."""
+    """Resolve the wire backend: requested (env beats config).  A backend
+    that fails to build raises with the compiler's stderr — asking for
+    epoll never quietly yields the threaded core."""
     wire = os.environ.get(WIRE_ENV, wire)
     if wire == "epoll":
-        lib = native.load("epollvan")
-        if lib is not None:
-            return _setup_sigs(lib), "epoll"
-        logging.getLogger(__name__).warning(
-            "tcpvan: epoll backend unavailable; falling back to threaded"
-        )
+        return _setup_sigs(native.load("epollvan", required=True)), "epoll"
     return _lib(), "threaded"
 
 

@@ -54,6 +54,7 @@ What the ledger feeds:
 from __future__ import annotations
 
 import collections
+import logging
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -146,6 +147,11 @@ class ApplyLedger:
         self._overloaded = False
         self._reaper: Optional[threading.Thread] = None
         self._closed = False
+        #: first exception a dispatched apply raised that was NOT the
+        #: donated-buffer case — an asynchronous device fault surfaces at
+        #: the reaper's wait.  It ends the run: every later :meth:`begin`
+        #: re-raises it, and the reaper stops.
+        self.fatal: Optional[BaseException] = None
 
     # -- submit side (recv thread; sync-free by AST contract) ---------------
     def begin(
@@ -158,6 +164,10 @@ class ApplyLedger:
         """Open an in-flight entry at dispatch start; returns the token the
         apply path marks its split points on.  ``tid``: sampled trace id
         riding this apply, if any (ISSUE 18)."""
+        if self.fatal is not None:
+            raise RuntimeError(
+                f"{self.node_id}: an earlier device apply failed"
+            ) from self.fatal
         with self._lock:
             self._bundle_seq += 1
             seq = self._bundle_seq
@@ -273,21 +283,30 @@ class ApplyLedger:
                             return
                 if self._closed:
                     return
-            self._reap_once()
-            head = self._oldest_head()
-            if head is None:
-                continue
+            head = None
             try:
-                # sleep INSIDE the runtime until the oldest dispatched
-                # apply completes: the wait releases the GIL and wakes once
-                # per completion — no poll cadence, no recv-thread
-                # preemption.  Single device queue => oldest completes
-                # first, so this is never a priority inversion.
-                head.ref.block_until_ready()
-            except Exception:
-                # donated away mid-wait (or table replaced): degrade to one
-                # interval of polling; _reap_once swaps in the fallback
-                time.sleep(self.cfg.reap_interval_s)
+                self._reap_once()
+                head = self._oldest_head()
+                if head is not None:
+                    # sleep INSIDE the runtime until the oldest dispatched
+                    # apply completes: the wait releases the GIL and wakes
+                    # once per completion — no poll cadence, no recv-thread
+                    # preemption.  One device queue per server => oldest
+                    # completes first, so this is never a priority inversion.
+                    head.ref.block_until_ready()
+            except Exception as e:  # noqa: BLE001 — sorted out just below
+                if head is not None and head.ref.is_deleted():
+                    # donated away mid-wait (or table replaced): degrade to
+                    # one interval of polling; _reap_once swaps in the
+                    # fallback
+                    time.sleep(self.cfg.reap_interval_s)
+                    continue
+                # anything else is the device reporting a failed apply
+                logging.getLogger(__name__).exception(
+                    "%s: dispatched device apply failed", self.node_id
+                )
+                self.fatal = e
+                return
 
     def _oldest_head(self) -> Optional[_Inflight]:
         with self._lock:
@@ -310,6 +329,8 @@ class ApplyLedger:
                 try:
                     ready = head.ref.is_ready()
                 except Exception:
+                    if not head.ref.is_deleted():
+                        raise  # a device fault, not a donation: fatal
                     # a later apply donated this buffer away: poll the
                     # table's CURRENT value instead — its readiness bounds
                     # this (older) apply's completion

@@ -59,6 +59,7 @@ from parameter_server_tpu.kv.routing import (
 )
 from parameter_server_tpu.kv.table import KVTable
 from parameter_server_tpu.utils.keys import bucket_size
+from parameter_server_tpu.utils.platform import role_device
 from parameter_server_tpu.utils.trace import NULL_TRACER, LatencyHistogram, Tracer
 
 
@@ -88,6 +89,7 @@ class KVServer(Customer):
         migrate_timeout: float = 30.0,
         apply: Optional[ApplyEngineConfig] = None,
         devobs: Optional[LedgerConfig] = None,
+        pallas_interpret: bool = False,
     ) -> None:
         """``replica``: node id of a hot-standby KVServer holding the same
         shard (chain replication of key ranges, the reference paper's §4.3
@@ -104,8 +106,17 @@ class KVServer(Customer):
         epoch-0 split (identical to the legacy ``RangePartition``).  Pass a
         post-migration table to spawn a server into an already-rebalanced
         cluster (``scale_up`` spawns with ZERO owned rows and migrates onto
-        it)."""
+        it).
+
+        ``pallas_interpret``: a CPU test's explicit request to run
+        ``scatter_impl="pallas"`` tables in the Pallas interpreter (see
+        :class:`~parameter_server_tpu.kv.table.KVTable`)."""
         super().__init__(name, post)
+        #: the chip this server's shards, optimizer state and staged request
+        #: arrays live on: servers of an in-process cluster spread over the
+        #: host's chips, and each owns ONE device queue (the ApplyLedger's
+        #: oldest-completes-first assumption holds per server).
+        self.device = role_device(server_index)
         #: bundle-batched apply engine knobs (ISSUE 11): how many same-table
         #: PUSHes of one coalesced bundle collapse into a single device
         #: apply, and the cross-member duplicate-row policy.
@@ -157,6 +168,8 @@ class KVServer(Customer):
                 # init different rows than an in-process cluster, breaking
                 # cross-deployment loss parity and restart determinism)
                 seed=zlib.crc32(f"{t}:{server_index}".encode()) & 0x7FFFFFFF,
+                device=self.device,
+                interpret=pallas_interpret,
             )
             for t, cfg in table_cfgs.items()
         }
@@ -734,15 +747,19 @@ class KVServer(Customer):
         padded_ids[:n] = ids_np
         return padded_ids
 
+    def _put(self, x) -> jax.Array:
+        """Stage a request array on THIS server's chip, committed — staged
+        on the default device it would be copied across on every push."""
+        return jax.device_put(x, self.device)
+
     def _upload_values(self, vals, b: int, n: int) -> jax.Array:
-        if not isinstance(vals, jax.Array):
-            # direct device handoff: the wire value plane (a zero-copy
-            # frombuffer view of the received frame) feeds the device
-            # transfer as-is — no intermediate padded host copy
-            vals = jnp.asarray(np.asarray(vals))
+        # direct device handoff: the wire value plane (a zero-copy
+        # frombuffer view of the received frame) feeds the device transfer
+        # as-is — no intermediate padded host copy.  A device plane pushed
+        # by a worker on another chip crosses here, once.
+        vals = self._put(vals if isinstance(vals, jax.Array) else np.asarray(vals))
         if b != n:  # pad on device (exact zeros: bitwise-neutral)
-            zeros = jnp.zeros((b - n,) + vals.shape[1:], vals.dtype)
-            vals = jnp.concatenate([vals, zeros])
+            vals = jnp.pad(vals, ((0, b - n),) + ((0, 0),) * (vals.ndim - 1))
         return vals
 
     def _stack_planes(
@@ -768,7 +785,7 @@ class KVServer(Customer):
                     buf[i, n:] = 0.0
             if tok is not None:
                 tok.mark_host()  # pinned-buffer pack done; H2D is next
-            stack = jnp.asarray(buf)
+            stack = self._put(buf)
             if tok is not None:
                 tok.mark_h2d()
             return stack
@@ -808,7 +825,7 @@ class KVServer(Customer):
         ids_host = self._pad_ids(table, ids_np, b)
         if tok is not None:
             tok.mark_host()
-        ids = jnp.asarray(ids_host)
+        ids = self._put(ids_host)
         vals = self._upload_values(msg.values[0], b, n)
         if tok is not None:
             tok.mark_h2d()
@@ -897,7 +914,7 @@ class KVServer(Customer):
         table = self.tables[tname]
         n = int(ids_np.shape[0])
         b = _bucket(n)
-        ids = jnp.asarray(self._pad_ids(table, ids_np, b))
+        ids = self._put(self._pad_ids(table, ids_np, b))
         with self.tracer.span("kv.server.pull", **self._span_attrs(msg, tname)):
             rows = table.pull(ids)
         self.pulls += 1
@@ -919,7 +936,7 @@ class KVServer(Customer):
         table = self.tables[tname]
         n = int(ids_np.shape[0])
         b = _bucket(n)
-        ids = jnp.asarray(self._pad_ids(table, ids_np, b))
+        ids = self._put(self._pad_ids(table, ids_np, b))
         with self.tracer.span(
             "kv.server.pull_ro", **self._span_attrs(msg, tname)
         ):
@@ -1198,7 +1215,7 @@ class KVServer(Customer):
             pos_np = np.full(bu, pad_pos, dtype=np.int32)
             pos_np[:nt] = pos_t
             ref = table.push_batch(
-                jnp.asarray(ids_np), jnp.asarray(pos_np), stack
+                self._put(ids_np), self._put(pos_np), stack
             )
         return ref  # last round's value: its readiness bounds every round
 
@@ -1227,7 +1244,7 @@ class KVServer(Customer):
         inverse = np.full(k * bm, min(nu, bu - 1), dtype=np.int32)
         inverse[rpos] = inv_real.astype(np.int32)
         return table.push_combined(
-            jnp.asarray(ids_np), jnp.asarray(inverse), stack
+            self._put(ids_np), self._put(inverse), stack
         )
 
     # -- shard transfer (same-id restart: kv/replica.restart_same_id) --------
@@ -1256,11 +1273,7 @@ class KVServer(Customer):
         next push jit-step runs on the imported arrays.
         """
         for t, blob in shard.items():
-            table = self.tables[t]
-            table.value = jnp.asarray(blob["value"])
-            table.state = {
-                k: jnp.asarray(v) for k, v in blob["state"].items()
-            }
+            self.tables[t].resize(blob["value"], blob["state"])
 
     def _export_rows(
         self, table: str, gids: np.ndarray
@@ -1617,7 +1630,7 @@ class KVServer(Customer):
         st = self._staging.pop(p["mid"], {"chunks": []})
         tbl = self.tables[t]
         n = hi - lo
-        dtype = np.asarray(tbl.value).dtype
+        dtype = np.dtype(tbl.value.dtype)
         value = np.zeros((n, tbl.dim), dtype=dtype)
         state_names = sorted(tbl.state)
         state = {k: np.zeros((n, tbl.dim), dtype=dtype) for k in state_names}

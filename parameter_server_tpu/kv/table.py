@@ -33,40 +33,72 @@ from parameter_server_tpu.ops import scatter
 class KVTable:
     """One table (or one row-range shard of a table) on the local device."""
 
-    def __init__(self, cfg: TableConfig, *, rows: Optional[int] = None, seed: int = 0):
+    def __init__(
+        self,
+        cfg: TableConfig,
+        *,
+        rows: Optional[int] = None,
+        seed: int = 0,
+        device: Optional[jax.Device] = None,
+        interpret: bool = False,
+    ):
+        """``device``: the chip this shard lives on.  Value and optimizer
+        state are allocated there and COMMITTED to it, so every jitted step
+        runs there and installs (:meth:`resize`, :meth:`set_value`) land
+        there too.  ``None`` keeps the arrays uncommitted on the default
+        device (single-table trainers that re-shard them under a mesh).
+
+        ``interpret``: run the Pallas kernels in the interpreter — what a CPU
+        test of ``scatter_impl="pallas"`` asks for explicitly.  It is never
+        inferred: a Pallas table on a non-TPU backend without it is an error,
+        so a chip run cannot silently become an interpreter run.
+        """
         self.cfg = cfg
+        self.device = device
         #: actual row count of this shard (cfg.rows is the global table size);
         #: one extra trash row is appended for padded ids.
         self.rows = cfg.rows if rows is None else rows
         self.dim = cfg.dim
         dtype = jnp.dtype(cfg.dtype)
-        if cfg.init_scale > 0.0:
-            key = jax.random.PRNGKey(seed)
-            value = (
-                jax.random.normal(key, (self.rows + 1, self.dim), dtype) * cfg.init_scale
-            )
-            value = value.at[self.rows].set(0.0)
-        else:
-            value = jnp.zeros((self.rows + 1, self.dim), dtype)
-        self.value: jax.Array = value
         self.optimizer: ServerOptimizer = make_optimizer(cfg.optimizer)
+        with jax.default_device(device):  # allocate in place, then commit
+            if cfg.init_scale > 0.0:
+                key = jax.random.PRNGKey(seed)
+                value = (
+                    jax.random.normal(key, (self.rows + 1, self.dim), dtype)
+                    * cfg.init_scale
+                )
+                value = value.at[self.rows].set(0.0)
+            else:
+                value = jnp.zeros((self.rows + 1, self.dim), dtype)
+            state = {
+                name: jnp.full((self.rows + 1, self.dim), fill, dtype)
+                for name, fill in self.optimizer.state_shapes().items()
+            }
+        self.value: jax.Array = self._place(value)
         self.state: Dict[str, jax.Array] = {
-            name: jnp.full((self.rows + 1, self.dim), fill, dtype)
-            for name, fill in self.optimizer.state_shapes().items()
+            k: self._place(v) for k, v in state.items()
         }
-        #: hot-path kernel selection (VERDICT r2 #4): "pallas" routes the
-        #: gather + write-back through ops/scatter's DMA kernels — compiled
-        #: on TPU, interpreter-run elsewhere so the FULL server path stays
-        #: testable on the CPU mesh; "xla"/"auto" as documented on the flag.
+        #: hot-path kernel selection: "pallas" routes the gather + write-back
+        #: through ops/scatter's DMA kernels; "xla"/"auto" as documented on
+        #: the flag.
         if cfg.scatter_impl not in ("auto", "xla", "pallas"):
             raise ValueError(
                 f"scatter_impl must be auto|xla|pallas, got {cfg.scatter_impl!r}"
             )
         self.scatter_impl = cfg.scatter_impl
         self.fused_apply = cfg.fused_apply
-        self._interpret = (
-            cfg.scatter_impl == "pallas" and jax.default_backend() != "tpu"
-        )
+        if (
+            cfg.scatter_impl == "pallas"
+            and not interpret
+            and jax.default_backend() != "tpu"
+        ):
+            raise ValueError(
+                "scatter_impl='pallas' compiles for the TPU; the backend is "
+                f"{jax.default_backend()!r}.  Pass interpret=True to run the "
+                "kernels in the Pallas interpreter (CPU tests only)."
+            )
+        self._interpret = interpret
         self._push_fn = jax.jit(self._push_impl, donate_argnums=(0, 1))
         self._pull_fn = jax.jit(self._pull_impl)
         self._push_batch_fn = jax.jit(
@@ -74,6 +106,25 @@ class KVTable:
         )
         self._push_combined_fn = jax.jit(
             self._push_combined_impl, donate_argnums=(0, 1)
+        )
+
+    def _place(self, x, dtype=None) -> jax.Array:
+        """``x`` as an array on this table's device (committed if one is set)."""
+        if dtype is not None and x.dtype != dtype:
+            x = x.astype(dtype)
+        if self.device is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self.device)
+
+    @property
+    def nominal_bytes(self) -> int:
+        """``(rows + 1) x dim x itemsize`` over the value and state planes —
+        what the shard should cost before layout or allocator padding."""
+        return (
+            (self.rows + 1)
+            * self.dim
+            * self.value.dtype.itemsize
+            * (1 + len(self.state))
         )
 
     def _kern(self, fn, *args):
@@ -195,7 +246,7 @@ class KVTable:
             raise ValueError(
                 f"expected {(self.rows + 1, self.dim)}, got {value.shape}"
             )
-        self.value = jnp.asarray(value, dtype=self.value.dtype)
+        self.value = self._place(value, self.value.dtype)
 
     def install_rows(
         self, value: np.ndarray, state: Dict[str, np.ndarray]
@@ -213,7 +264,7 @@ class KVTable:
                 f"optimizer state keys mismatch: {set(state)} != {set(self.state)}"
             )
         n = int(value.shape[0])
-        dtype = np.asarray(self.value).dtype
+        dtype = np.dtype(self.value.dtype)
         fills = self.optimizer.state_shapes()
         buf = np.zeros((n + 1, self.dim), dtype)
         buf[:n] = value
@@ -241,8 +292,8 @@ class KVTable:
             )
         dtype = self.value.dtype
         self.rows = int(value.shape[0]) - 1
-        self.value = jnp.asarray(value, dtype)
-        self.state = {k: jnp.asarray(v, dtype) for k, v in state.items()}
+        self.value = self._place(value, dtype)
+        self.state = {k: self._place(v, dtype) for k, v in state.items()}
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows",))

@@ -48,8 +48,14 @@ from parameter_server_tpu.config import GroupConfig, TableConfig, TraceConfig
 from parameter_server_tpu.core import flightrec
 from parameter_server_tpu.core.coalesce import GroupReducer
 from parameter_server_tpu.core.tracectx import TRACE_KEY, sampled
-from parameter_server_tpu.core.messages import Message, Task, TaskKind, server_id
-from parameter_server_tpu.core.postoffice import Customer, Postoffice
+from parameter_server_tpu.core.messages import (
+    Message,
+    Task,
+    TaskKind,
+    node_index,
+    server_id,
+)
+from parameter_server_tpu.core.postoffice import Customer, Postoffice, VanError
 from parameter_server_tpu.kv.cache import HotRowCache
 from parameter_server_tpu.kv.partition import RangePartition
 from parameter_server_tpu.kv.routing import (
@@ -67,6 +73,7 @@ from parameter_server_tpu.kv.routing import (
 )
 from parameter_server_tpu.ops import scatter
 from parameter_server_tpu.utils.keys import HashLocalizer, localize_to_slots
+from parameter_server_tpu.utils.platform import role_device
 from parameter_server_tpu.utils.trace import NULL_TRACER, LatencyHistogram, Tracer
 
 
@@ -119,6 +126,10 @@ class KVWorker(Customer):
         fallback/reduce behaviour (defaults to ``GroupConfig`` matched to
         the group's size and election mode)."""
         super().__init__(name, post)
+        #: the chip this worker computes on (gradient step, duplicate
+        #: pre-combine, device-side pull assembly): workers of an in-process
+        #: cluster spread over the host's chips by their node index
+        self.device = role_device(node_index(post.node_id))
         #: host-side span recorder (Push/Pull latency histograms, SURVEY §5)
         self.tracer = tracer
         self.table_cfgs = table_cfgs
@@ -785,7 +796,7 @@ class KVWorker(Customer):
         """Direct per-worker push of this member's own gradient — the
         same-step, no-loss degradation the group contract promises."""
         if self._group_cfg.fallback == "none":
-            raise RuntimeError(
+            raise VanError(
                 f"group push of {table!r} step {step}: leader unreachable "
                 f"({reason}) and fallback='none'"
             )
@@ -1142,7 +1153,11 @@ class KVWorker(Customer):
             keys, self.localizers[table], min_bucket=self.min_bucket
         )
         combined = np.asarray(
-            _segment_combine(jnp.asarray(inverse), jnp.asarray(vals), slots.shape[0])
+            _segment_combine(
+                jax.device_put(inverse, self.device),
+                jax.device_put(vals, self.device),
+                slots.shape[0],
+            )
         )
         return slots, combined
 
@@ -1418,9 +1433,9 @@ class KVWorker(Customer):
             skip = fenced_senders | {r.sender for r in waits}
             real = self._real_errors(errs, skip)
             if real:  # a dropped leg must not read as zero weights
-                raise RuntimeError(f"pull ts={ts} failed on: " + "; ".join(real))
+                raise VanError(f"pull ts={ts} failed on: " + "; ".join(real))
             if len(responses) + len(waits) < len(plan["order"]):
-                raise RuntimeError(
+                raise VanError(
                     f"pull ts={ts} incomplete: {len(responses)}/"
                     f"{len(plan['order'])} servers answered (dead server?)"
                 )
@@ -1500,7 +1515,7 @@ class KVWorker(Customer):
                 read_only=first_plan.get("ro", False),
                 ungated=ungated,
             )
-        raise RuntimeError(
+        raise VanError(
             f"pull of {first_plan['table']!r}: routing fence retries "
             f"exhausted after {self.max_fence_retries} refreshes"
         )
@@ -1556,12 +1571,16 @@ class KVWorker(Customer):
         plan, pairs = self._pull_pairs(ts, timeout)
         cfg = self.table_cfgs[plan["table"]]
         sole = self._sole_full_pair(pairs, plan["n_slots"])
+        # replies from servers on other chips cross to this worker's here
+        dtype = jnp.dtype(cfg.dtype)
         if sole is not None:
-            uniq = jnp.asarray(sole, jnp.dtype(cfg.dtype)).reshape(-1, cfg.dim)
+            uniq = jax.device_put(sole, self.device)
+            uniq = uniq.astype(dtype).reshape(-1, cfg.dim)
         else:
-            uniq = jnp.zeros((plan["n_slots"], cfg.dim), jnp.dtype(cfg.dtype))
+            with jax.default_device(self.device):
+                uniq = jnp.zeros((plan["n_slots"], cfg.dim), dtype)
             for pos, rows, *_meta in pairs:
-                rows = jnp.asarray(rows).reshape(-1, cfg.dim)
+                rows = jax.device_put(rows, self.device).reshape(-1, cfg.dim)
                 uniq = uniq.at[jnp.asarray(pos)].set(rows)
         out = jnp.take(uniq, jnp.asarray(plan["inverse"]), axis=0)
         if cfg.dim == 1:
@@ -1782,7 +1801,7 @@ class KVWorker(Customer):
             skip = fenced_senders | {r.sender for r in waits}
             real = self._real_errors(errs, skip)
             if real:
-                raise RuntimeError(
+                raise VanError(
                     f"push ts={ts} failed on: " + "; ".join(real)
                 )
             if not fenced and not waits:
@@ -1827,7 +1846,7 @@ class KVWorker(Customer):
                 if attempt > 1:  # mid-broadcast epoch bounce: outlast it
                     time.sleep(self.fence_backoff * (attempt - 1))
             positions = np.sort(np.concatenate(pending))
-        raise RuntimeError(
+        raise VanError(
             f"push of {table!r}: routing fence retries exhausted after "
             f"{self.max_fence_retries} refreshes"
         )

@@ -24,6 +24,12 @@ parity guarantee (the reference's SSP regime).
 
 Roles mirror ``launch.py`` (scheduler H / servers S* / bodies W*); the
 scheduler is the same Manager barrier host.
+
+This is a CPU-simulation harness for the wire and for ``jax.distributed``:
+every process it spawns is pinned to the CPU by environment (several
+processes on one host would race for the same chips), and ``cpu_devices=0``
+is refused.  On a chip the hybrid runs in one process (``psx run`` a
+``llama_hybrid`` config).
 """
 
 from __future__ import annotations
@@ -211,6 +217,12 @@ def launch_hybrid(
     from parameter_server_tpu.core.filters import make_chain
 
     make_chain(filters)  # validate the spec HERE, not in five children
+    if cpu_devices <= 0:
+        raise ValueError(
+            "cpu_devices=0: the body and server processes on this host "
+            "would all claim the same chips; this launcher is a CPU "
+            "simulation (cpu_devices>0)"
+        )
     sched_port = _free_port()
     coord_port = _free_port()
     outdir = tempfile.mkdtemp(prefix="psx_hybrid_")
@@ -218,6 +230,7 @@ def launch_hybrid(
     pypath = os.environ.get("PYTHONPATH", "")
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         PYTHONPATH=f"{repo_root}:{pypath}" if pypath else repo_root,
     )
 
@@ -326,11 +339,16 @@ def main(argv=None) -> int:
     # Manager/launch code sizes barriers by num_workers: the bodies ARE the
     # workers of this topology
     args.num_workers = args.num_body
-    if args.role != "body":
-        # host-side roles must never touch the chip (or jax.distributed)
-        from parameter_server_tpu.utils.platform import force_cpu
+    from parameter_server_tpu.utils.platform import (
+        enable_compile_cache,
+        force_cpu,
+    )
 
+    if args.role != "body":
+        # host-side roles never touch a chip (or jax.distributed); bodies
+        # are pinned by distributed.initialize(cpu_devices=...)
         force_cpu()
+    enable_compile_cache()
     return {
         "scheduler": run_scheduler,
         "server": run_server,

@@ -14,9 +14,13 @@ deterministic global batch (seeded stream, the reference's WorkloadPool
 determinism) and feeds only its :func:`~parameter_server_tpu.parallel.distributed.local_batch_slice`
 of it.  Process 0 writes the loss trajectory for the launcher to aggregate.
 
-``launch_spmd`` spawns the whole job locally (the CPU-sim pod) and returns
-the losses — used by tests and ``__graft_entry__.dryrun_multichip`` to prove
-multi-process GSPMD training matches single-process loss-for-loss.
+``launch_spmd`` spawns the whole job locally and returns the losses — used
+by tests and ``__graft_entry__.dryrun_multichip`` to prove multi-process
+GSPMD training matches single-process loss-for-loss.  It is a CPU-simulation
+harness for ``jax.distributed``: several processes on one host would race
+for the same chips, so it refuses ``cpu_devices=0`` with more than one
+process and pins its children to the CPU by environment.  On a real pod,
+start ``python -m parameter_server_tpu.launch_spmd`` once per HOST.
 """
 
 from __future__ import annotations
@@ -236,6 +240,9 @@ def run_job(
 
 
 def main(argv=None) -> int:
+    from parameter_server_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--coordinator", default=None)
     p.add_argument("--num-procs", type=int, default=1)
@@ -304,14 +311,22 @@ def launch_spmd(
     Returns ``{"returncodes": [...], "losses": {proc_id: [...]},
     "digests": {...}, "start_steps": {...}}``.
     """
-    port = _free_port()
-    outdir = tempfile.mkdtemp(prefix="psx_spmd_")
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     pypath = os.environ.get("PYTHONPATH", "")
     env = dict(
         os.environ,
         PYTHONPATH=f"{repo_root}:{pypath}" if pypath else repo_root,
     )
+    if cpu_devices > 0:
+        env["JAX_PLATFORMS"] = "cpu"
+    elif num_procs > 1:
+        raise ValueError(
+            f"cpu_devices=0 with num_procs={num_procs}: every process on "
+            "this host would claim the same chips.  Use cpu_devices>0 (CPU "
+            "simulation) here, or start one process per host on a pod."
+        )
+    port = _free_port()
+    outdir = tempfile.mkdtemp(prefix="psx_spmd_")
 
     extra = []
     if data_shards is not None:

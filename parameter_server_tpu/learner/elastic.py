@@ -42,12 +42,13 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from parameter_server_tpu.config import CheckpointConfig, ConsistencyConfig
 from parameter_server_tpu.core.clock import ConsistencyController
 from parameter_server_tpu.core.manager import Manager
+from parameter_server_tpu.core.postoffice import VanError
 from parameter_server_tpu.kv.consistency import BoundTuner
 from parameter_server_tpu.kv.worker import KVWorker
 from parameter_server_tpu.learner.workload import WorkloadPool
@@ -108,6 +109,9 @@ class ElasticTrainer:
         self.losses: List[float] = []
         self._loss_lock = threading.Lock()
         self._killed: set[str] = set()
+        #: set by the first worker that dies of anything but a Van failure;
+        #: the others stop drawing work so ``run`` can re-raise it
+        self._abort = threading.Event()
         # wire-enforced consistency plane (ISSUE 20): the trainer announces
         # workers to the servers' FleetClocks up front and (optionally)
         # closes the loop over the SSP bound
@@ -164,7 +168,7 @@ class ElasticTrainer:
         for t in self._gated_tables(kv):
             try:
                 kv.consist_hello(table=t, timeout=self.timeout)
-            except (TimeoutError, RuntimeError) as e:
+            except (TimeoutError, VanError) as e:
                 log.warning("consist_hello(%s, %s) failed: %s", wid, t, e)
 
     def announce_consistency(self) -> None:
@@ -202,16 +206,18 @@ class ElasticTrainer:
                 timeout=self.timeout,
             )
             log.info("retuned SSP bound -> %d (%s)", new_bound, why)
-        except (TimeoutError, RuntimeError) as e:  # pragma: no cover
+        except (TimeoutError, VanError) as e:  # pragma: no cover
             log.warning("set_consistency(bound=%d) failed: %s", new_bound, e)
 
     # -- training ------------------------------------------------------------
     def run(self, *, poll: float = 0.02) -> List[float]:
         """Drain the pool with all workers; returns recorded losses.
 
-        Individual worker failures (Van timeouts after a kill) are swallowed
-        — the scheduler's failure detection re-queues their work; only a
-        wholly-failed run (work left but no live workers) raises.
+        A worker the Van cuts off (timeouts after a kill, undeliverable or
+        ``__error__``-answered legs) is retired and the scheduler's failure
+        detection re-queues its work.  Any other exception in a worker step
+        — a ``JaxRuntimeError`` from the device above all — is not a
+        partition: it stops every worker and is re-raised here.
         """
         self.announce_consistency()
         hb_stop = threading.Event()
@@ -276,6 +282,9 @@ class ElasticTrainer:
         iteration = 0
         try:
             self._worker_loop_inner(wid, kv, idx, iteration, poll)
+        except BaseException:
+            self._abort.set()
+            raise
         finally:
             # Retire from the staleness bound on ANY exit (drained, died,
             # stalled): a stopped clock must not wedge survivors' SSP window.
@@ -285,7 +294,7 @@ class ElasticTrainer:
         self, wid: str, kv: KVWorker, idx: int, iteration: int, poll: float
     ) -> None:
         while True:
-            if wid in self._killed:
+            if wid in self._killed or self._abort.is_set():
                 return  # the "process" is gone; no further sends, no finish
             wl = self.pool.get(wid)
             if wl is None:
@@ -295,7 +304,7 @@ class ElasticTrainer:
                 continue
             try:
                 for keys, labels in wl.payload:
-                    if wid in self._killed:
+                    if wid in self._killed or self._abort.is_set():
                         return
                     if not self.controller.wait_turn(
                         idx, iteration, timeout=self.timeout
@@ -303,7 +312,8 @@ class ElasticTrainer:
                         raise TimeoutError(f"{wid} stalled (SSP bound)")
                     w_pos = kv.pull_sync(self.table, keys, timeout=self.timeout)
                     g, _gb, loss = linear.grad_rows(
-                        jnp.asarray(w_pos), jnp.asarray(labels)
+                        jax.device_put(w_pos, kv.device),
+                        jax.device_put(labels, kv.device),
                     )
                     # push_sync, not fire-and-forget push: only the kept-
                     # responses path can see a routing fence (PR 6), so this
@@ -320,12 +330,14 @@ class ElasticTrainer:
                     with self._loss_lock:
                         self.losses.append(float(loss))
                     self._maybe_retune(kv, float(loss))
-            except (TimeoutError, RuntimeError) as e:
+            except (TimeoutError, VanError) as e:
                 # This worker is partitioned/dead from the cluster's view
                 # (pull timeout, undeliverable sends, or a dead-server leg) —
                 # its thread exits (the "process" dies).  Joining _killed
                 # stops its heartbeats so the scheduler sweep actually
                 # detects the death and requeues the workload for survivors.
+                # Only Van-level failures qualify: a device fault raised by
+                # the step itself must fail the run, not shrink the fleet.
                 log.warning("worker %s failed (%s); exiting loop", wid, e)
                 self._killed.add(wid)
                 return
@@ -396,7 +408,7 @@ class ElasticTrainer:
                     self.ckpt_root, step, clocks=clocks, timeout=self.timeout
                 )
             self.last_ckpt_step = step
-        except (TimeoutError, RuntimeError, OSError) as e:
+        except (TimeoutError, VanError, OSError) as e:
             # checkpoint failure must not kill training (a dead server
             # mid-save is exactly the scenario recovery handles); an
             # aborted snapshot leaves no manifest, so the previous one

@@ -6,8 +6,8 @@ SGD / FTRL worker loops of ``src/app/linear_method/async_sgd.h`` [U].
 Two drivers over the same model math (``models/linear.py``):
 
 - :class:`LocalLRTrainer` — single-process fast path: the table lives on the
-  local device and each step is one fused XLA program.  This is the
-  examples/sec/chip bench path (BASELINE config #1).
+  local device and each step is one fused XLA program (BASELINE config
+  #1; what ``bench.py``'s default mode times).
 - :class:`AsyncLRLearner` — the classic PS topology over the Van: N worker
   threads pull/push through :class:`~parameter_server_tpu.kv.worker.KVWorker`
   under a :class:`~parameter_server_tpu.core.clock.ConsistencyController`
@@ -64,7 +64,7 @@ class LocalLRTrainer:
         host dedup; requires l1 == l2 == 0 and a g=0-stable optimizer.
         ``device_hash``: hash keys ON DEVICE (32-bit; dense mode) — raw
         uint32 keys ship to the chip and :meth:`step_block` runs K steps per
-        dispatch (for hosts/tunnels where the transfer is the bottleneck)."""
+        dispatch (for hosts where the transfer is the bottleneck)."""
         if table_cfg.dim != 1:
             raise ValueError("LR weight table must have dim=1")
         if mode not in ("rows", "dense"):
@@ -320,7 +320,8 @@ class AsyncLRLearner:
             keys, labels = batch_fn()
             w_pos = kv.pull_sync(self.table, keys, timeout=timeout)
             g, _gb, loss = linear.grad_rows(
-                jnp.asarray(w_pos), jnp.asarray(labels)
+                jax.device_put(w_pos, kv.device),
+                jax.device_put(labels, kv.device),
             )
             push_ts = kv.push(self.table, keys, np.asarray(g) / labels.shape[0])
             kv.wait(push_ts, timeout=timeout)

@@ -5,9 +5,13 @@ in C++; we do the same (SURVEY.md §2 native checklist).  pybind11 is not in
 this image, so the ABI is plain ``extern "C"`` + ctypes.
 
 :func:`load` compiles ``src/<name>.cc`` into ``lib/<name>.so`` on first use
-(cached; rebuilt when the source is newer) and returns the loaded CDLL, or
-``None`` when no toolchain is available — callers must degrade to their
-Python fallbacks so the package works on toolchain-less hosts.
+(cached; rebuilt when the source is newer; ``lib/`` is git-ignored, so a
+fresh checkout builds everything) and returns the loaded CDLL, or ``None``
+when no toolchain is available — callers then degrade to their Python
+fallbacks so the package works on toolchain-less hosts.  Entry points that
+run on the chip do not accept that quietly: they :func:`load` what their
+path needs with ``required=True`` (a failed build raises with the
+compiler's stderr) and print :func:`loaded`.
 """
 
 from __future__ import annotations
@@ -74,3 +78,10 @@ def load(name: str, *, required: bool = False) -> Optional[ctypes.CDLL]:
             return None
         _cache[name] = lib
         return lib
+
+
+def loaded() -> dict[str, bool]:
+    """Which native libraries this process has tried, and whether each
+    loaded (False = its callers are on their Python fallback)."""
+    with _lock:
+        return {name: lib is not None for name, lib in sorted(_cache.items())}

@@ -105,17 +105,16 @@ class CounterGroup:
 
 
 def _auto_peak_flops() -> float:
-    """Peak dense FLOP/s of the active backend for the MFU denominator.
+    """Peak dense FLOP/s of one device for the MFU denominator, from the
+    table keyed by ``device_kind`` (``utils.platform.DEVICE_PEAKS``).
 
-    TPU v5e ≈ 197 TFLOP/s bf16 (the honest MXU ceiling); CPU gets a nominal
-    100 GF so CPU-sim MFU numbers stay visibly "not a TPU measurement".
+    0.0 for a device the table does not know (every CPU): the MFU column
+    then stays off rather than dividing by a guess.
     """
-    try:
-        import jax
+    from parameter_server_tpu.utils.platform import device_peaks
 
-        return {"tpu": 197e12, "gpu": 60e12}.get(jax.default_backend(), 1e11)
-    except Exception:  # pragma: no cover — metrics must never crash training
-        return 1e11
+    peaks = device_peaks()
+    return peaks["flops"] if peaks else 0.0
 
 
 def lowered_flops(jitfn, *args) -> float:
@@ -264,12 +263,13 @@ class Dashboard:
         if self.flops_per_example > 0.0 and examples:
             if self.peak_flops <= 0.0:
                 self.peak_flops = _auto_peak_flops()
-            mfu = (
-                self.flops_per_example * examples
-                / max(interval, 1e-9)
-                / self.peak_flops
-            )
-            row["mfu_pct"] = round(mfu * 100.0, 4)
+            if self.peak_flops > 0.0:  # unknown device_kind: no MFU figure
+                mfu = (
+                    self.flops_per_example * examples
+                    / max(interval, 1e-9)
+                    / self.peak_flops
+                )
+                row["mfu_pct"] = round(mfu * 100.0, 4)
         if extra:
             row.update(extra)
         if self.transport is not None:

@@ -267,10 +267,12 @@ def test_push_ack_does_not_wait_for_device_apply(batched):
         def slow_push(ids, vals):
             orig_push(ids, vals)
             tbl.value = entangle(tbl.value)
+            return tbl.value  # the ledger's readiness ref, as KVTable.push
 
         def slow_push_batch(ids, positions, vals):
             orig_batch(ids, positions, vals)
             tbl.value = entangle(tbl.value)
+            return tbl.value
 
         tbl.push, tbl.push_batch = slow_push, slow_push_batch
         rng = np.random.default_rng(8)

@@ -1,6 +1,6 @@
 """Device-side hashing + scan-block training: parity with the host path.
 
-The tunnel/PCIe-bound optimization (``dense_scan_train_step``): raw uint32
+The host-link-bound optimization (``dense_scan_train_step``): raw uint32
 keys ship to the device, murmur fmix32 hashing runs inside the jit program,
 and K steps execute per dispatch.  These tests pin the invariant that makes
 it safe: host ``mix32`` and device ``mix32_jax`` agree bit-for-bit, so a

@@ -280,7 +280,10 @@ def test_hybrid_dashboard_reports_mfu():
         sink = io.StringIO()
         tr = hybrid.HybridLMTrainer(
             cfg, mesh, worker,
-            dashboard=metrics_lib.Dashboard(jsonl=sink, print_every=0),
+            # the CPU has no entry in the peak table: give the denominator
+            dashboard=metrics_lib.Dashboard(
+                jsonl=sink, print_every=0, peak_flops=1e12
+            ),
         )
         rng = np.random.default_rng(1)
         tr.step(_tokens(cfg, rng))

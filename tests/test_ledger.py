@@ -48,22 +48,30 @@ import pstop  # noqa: E402
 DIM = 4
 ROWS = 64
 
-#: fast reaper degraded-mode cadence for fake (non-jax) refs, which lack
-#: ``block_until_ready`` and so push the reaper onto its polling fallback.
+#: fast reaper cadence for the fake (non-jax) refs below.
 _FAST = dict(reap_interval_s=0.002, idle_stop_s=0.2)
 
 
 class _Ref:
-    """Controllable stand-in for a dispatched jax result array."""
+    """Controllable stand-in for a dispatched jax result array: ``dead`` is
+    the donated-away buffer (every query raises, ``is_deleted`` says why)."""
 
     def __init__(self, ready=False, dead=False):
         self.ready = ready
         self.dead = dead
 
+    def is_deleted(self):
+        return self.dead
+
     def is_ready(self):
         if self.dead:
             raise RuntimeError("buffer donated away")
         return self.ready
+
+    def block_until_ready(self):
+        if self.dead:
+            raise RuntimeError("buffer donated away")
+        time.sleep(_FAST["reap_interval_s"])
 
 
 def _drained(ledger, timeout=5.0):

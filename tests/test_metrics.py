@@ -29,11 +29,27 @@ def test_dashboard_mfu_per_iter():
     assert rows[0]["mfu_pct"] <= 100.0 or rows[0]["sec"] < 0.001
 
 
-def test_dashboard_auto_peak_flops_backend():
-    # auto-detect fills peak_flops lazily at first MFU computation
-    dash = metrics_lib.Dashboard(print_every=0, flops_per_example=10.0)
+def test_dashboard_auto_peak_flops_by_device_kind(monkeypatch):
+    import jax
+
+    from parameter_server_tpu.utils import platform
+
+    # a device_kind the peak table does not know (every CPU): no MFU figure
+    sink = io.StringIO()
+    dash = metrics_lib.Dashboard(
+        print_every=0, jsonl=sink, flops_per_example=10.0
+    )
     dash.record(1, 0.5, examples=10)
-    assert dash.peak_flops > 0
+    assert dash.peak_flops == 0.0
+    assert "mfu_pct" not in json.loads(sink.getvalue())
+    # a known kind fills peak_flops lazily at the first MFU computation
+    kind = jax.devices()[0].device_kind
+    monkeypatch.setitem(
+        platform.DEVICE_PEAKS, kind, {"flops": 1e6, "hbm_gbps": 100.0}
+    )
+    dash.record(2, 0.4, examples=10)
+    assert dash.peak_flops == 1e6
+    assert json.loads(sink.getvalue().splitlines()[-1])["mfu_pct"] > 0
 
 
 def test_dashboard_span_attribution():

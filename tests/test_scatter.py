@@ -201,9 +201,16 @@ def test_kvserver_full_path_pallas_parity():
         van = LoopbackVan()
         try:
             servers = [
-                KVServer(Postoffice(f"S{i}", van), cfgs, i, 2)
+                KVServer(
+                    Postoffice(f"S{i}", van), cfgs, i, 2,
+                    pallas_interpret=True,  # asked for, never inferred
+                )
                 for i in range(2)
             ]
+            assert all(
+                s.tables["e"]._interpret and s.tables["e"].device == d
+                for s, d in zip(servers, jax.local_devices())
+            )
             worker = KVWorker(
                 Postoffice("W0", van), cfgs, 2, min_bucket=16,
                 localizers={"e": HashLocalizer(rows)},
