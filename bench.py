@@ -544,7 +544,7 @@ def run_hybrid() -> tuple[dict, str]:
         tr.drain()
         dt = time.perf_counter() - t0
         pre_wait = float(
-            np.mean([s[2] for s in tracer.spans("hybrid.pull_wait")])
+            np.mean([s[2] for s in tracer.spans("ps.hybrid.pull_wait")])
         )
     finally:
         van.close()
@@ -558,7 +558,7 @@ def run_hybrid() -> tuple[dict, str]:
             tr.step(batches[i])
         tr.drain()
         sync_wait = float(
-            np.mean([s[2] for s in tracer.spans("hybrid.pull_wait")])
+            np.mean([s[2] for s in tracer.spans("ps.hybrid.pull_wait")])
         )
     finally:
         van.close()

@@ -38,7 +38,7 @@ import numpy as np
 from parameter_server_tpu.core import flightrec, frame
 from parameter_server_tpu.core.messages import Message, Task
 from parameter_server_tpu.core.van import Van, VanWrapper
-from parameter_server_tpu.utils.trace import LatencyHistogram
+from parameter_server_tpu.utils.trace import LatencyHistogram, req_id, span
 
 #: payload key carrying the send-side monotonic stamp (stripped on receive).
 STAMP_KEY = "__mts__"
@@ -134,6 +134,11 @@ class MeteredVan(VanWrapper):
 
     # -- send path -----------------------------------------------------------
     def send(self, msg: Message) -> bool:
+        # the whole method, its own metering included
+        with span("ps.van.send", verb=msg.task.kind.name) as sp:
+            return self._send(msg, sp)
+
+    def _send(self, msg: Message, sp) -> bool:
         nbytes = payload_nbytes(msg)
         saved = 0
         p = msg.task.payload
@@ -166,6 +171,7 @@ class MeteredVan(VanWrapper):
             fbytes, obytes = frame.frame_nbytes(out)
         except frame.FrameError:  # uncodable payload object (in-proc only)
             fbytes, obytes = nbytes + frame.HEADER_SIZE, frame.HEADER_SIZE
+        sp.set(bytes=fbytes)
         t0 = time.perf_counter()
         ok = self.inner.send(out)
         dt = time.perf_counter() - t0
@@ -196,6 +202,7 @@ class MeteredVan(VanWrapper):
         def metered(msg: Message) -> None:
             payload = msg.task.payload
             ts = payload.get(STAMP_KEY) if isinstance(payload, dict) else None
+            lat = None
             if ts is not None:
                 # strip the stamp before delivery: replies share the Task
                 # (msg.reply()), so a leaked stamp would time-travel into
@@ -220,7 +227,22 @@ class MeteredVan(VanWrapper):
                     "frame.recv", node=msg.recver, sender=msg.sender,
                     verb=msg.task.kind.name, deliver_ms=round(1e3 * lat, 3),
                 )
-            handler(msg)
+            # a reply carries its request's ``req``: the requester is its
+            # receiver.  The wait in the inbox began on the sender's thread
+            # and cannot be backdated into a trace: it is an attribute.
+            t = msg.task
+            with span(
+                "ps.van.deliver",
+                req=req_id(
+                    msg.sender if msg.is_request else msg.recver,
+                    t.customer, t.time,
+                ),
+                verb=t.kind.name, sender=msg.sender,
+                is_request=int(msg.is_request),
+            ) as sp:
+                if lat is not None:
+                    sp.set(wait_us=int(1e6 * max(lat, 0.0)))
+                handler(msg)
 
         self.inner.bind(node_id, metered)
 

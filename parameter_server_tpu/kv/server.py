@@ -60,7 +60,12 @@ from parameter_server_tpu.kv.routing import (
 from parameter_server_tpu.kv.table import KVTable
 from parameter_server_tpu.utils.keys import bucket_size
 from parameter_server_tpu.utils.platform import role_device
-from parameter_server_tpu.utils.trace import NULL_TRACER, LatencyHistogram, Tracer
+from parameter_server_tpu.utils.trace import (
+    NULL_TRACER,
+    LatencyHistogram,
+    Tracer,
+    req_id,
+)
 
 
 def _bucket(n: int) -> int:
@@ -668,15 +673,31 @@ class KVServer(Customer):
                 sender=msg.sender,
             )
 
-    def _span_attrs(self, msg: Message, tname: str) -> dict:
-        # cross-node stitching: echo the worker's trace context onto this
-        # handler's spans so merge_traces can pair both ends of the request
-        tctx = msg.task.payload.get("__trace__") or {}
-        span_attrs = {"table": tname}
+    def _span_attrs(self, msg: Message) -> dict:
+        """Attributes of a request's ``ps.server.*`` span: the ``req`` that
+        joins it to the worker's ``ps.worker.submit`` and, on a sampled
+        request, the worker's trace context, echoed so merge_traces can
+        pair both ends."""
+        t = msg.task
+        span_attrs = {
+            "req": req_id(msg.sender, t.customer, t.time),
+            "table": t.payload.get("table"),
+        }
+        tctx = t.payload.get("__trace__") or {}
         if tctx.get("tid"):
             span_attrs["trace"] = tctx["tid"]
             span_attrs["origin"] = tctx.get("origin")
         return span_attrs
+
+    def _admit(self, msg: Message, sp):
+        """Trace dispatch and :meth:`_validate_data_request` of a request
+        whose ``ps.server.*`` span ``sp`` is open: the span says whether the
+        consistency gate deferred it."""
+        self._trace_dispatch(msg)
+        v = self._validate_data_request(msg)
+        if isinstance(v, Message):
+            sp.set(deferred=int(bool(v.task.payload.get(WAIT_KEY))))
+        return v
 
     def _validate_data_request(self, msg: Message):
         """Routing fence + localization for a PUSH/PULL.
@@ -807,10 +828,13 @@ class KVServer(Customer):
         ids_np: np.ndarray,
         kn: np.ndarray,
         segs: np.ndarray,
+        sp,
     ) -> Message:
+        """Apply one push under its open ``ps.server.push`` span ``sp``."""
         table = self.tables[tname]
         n = int(ids_np.shape[0])
         b = _bucket(n)
+        sp.set(rows=n, bucket=b, members=1)
         tctx = msg.task.payload.get(TRACE_KEY)
         tok = (
             self.ledger.begin(
@@ -822,14 +846,16 @@ class KVServer(Customer):
             if self.ledger is not None
             else None
         )
-        ids_host = self._pad_ids(table, ids_np, b)
-        if tok is not None:
-            tok.mark_host()
-        ids = self._put(ids_host)
-        vals = self._upload_values(msg.values[0], b, n)
-        if tok is not None:
-            tok.mark_h2d()
-        with self.tracer.span("kv.server.push", **self._span_attrs(msg, tname)):
+        with self.tracer.span("ps.server.h2d") as h2d:
+            ids_host = self._pad_ids(table, ids_np, b)
+            if tok is not None:
+                tok.mark_host()
+            ids = self._put(ids_host)
+            vals = self._upload_values(msg.values[0], b, n)
+            if tok is not None:
+                tok.mark_h2d()
+            h2d.set(bytes=ids.nbytes + vals.nbytes)
+        with self.tracer.span("ps.server.dispatch", op="push"):
             ref = table.push(ids, vals)
         if tok is not None:
             self.ledger.submit(tok, ref, lambda t=table: t.value)
@@ -906,17 +932,34 @@ class KVServer(Customer):
             reply.task.payload[BUSY_KEY] = True
         return reply
 
-    def _pull_device(
-        self, msg: Message, tname: str, ids_np: np.ndarray, segs: np.ndarray
-    ) -> Tuple[jax.Array, int, int]:
-        """Dispatch the device gather; D2H is the CALLER's choice (the
-        bundle path defers it to one transfer per bundle)."""
+    def _dispatch_pull(
+        self, tname: str, ids_np: np.ndarray, op: str, sp
+    ) -> Tuple[jax.Array, int]:
+        """Upload the bucket-padded ids and enqueue the gather, under the
+        request's open ``ps.server.pull`` span ``sp``."""
         table = self.tables[tname]
         n = int(ids_np.shape[0])
         b = _bucket(n)
-        ids = self._put(self._pad_ids(table, ids_np, b))
-        with self.tracer.span("kv.server.pull", **self._span_attrs(msg, tname)):
+        sp.set(rows=n, bucket=b)
+        with self.tracer.span("ps.server.h2d", bytes=4 * b):
+            ids = self._put(self._pad_ids(table, ids_np, b))
+        with self.tracer.span("ps.server.dispatch", op=op):
             rows = table.pull(ids)
+        return rows, n
+
+    def _to_host(self, rows):
+        """The D2H of a pull's reply (one array, or a bundle's list)."""
+        bundle = isinstance(rows, list)
+        nbytes = sum(r.nbytes for r in rows) if bundle else rows.nbytes
+        with self.tracer.span("ps.server.d2h", bytes=nbytes):
+            return jax.device_get(rows) if bundle else np.asarray(rows)
+
+    def _pull_device(
+        self, tname: str, ids_np: np.ndarray, segs: np.ndarray, sp
+    ) -> Tuple[jax.Array, int, int]:
+        """Dispatch the device gather; D2H is the CALLER's choice (the
+        bundle path defers it to one transfer per bundle)."""
+        rows, n = self._dispatch_pull(tname, ids_np, "pull", sp)
         self.pulls += 1
         # staleness clock: the reply carries the current version of the
         # touched segments (read, not bumped) — what the worker computes
@@ -926,21 +969,14 @@ class KVServer(Customer):
         return rows, n, sver
 
     def _pull_ro_device(
-        self, msg: Message, tname: str, ids_np: np.ndarray, segs: np.ndarray
+        self, tname: str, ids_np: np.ndarray, segs: np.ndarray, sp
     ) -> Tuple[jax.Array, int, int]:
         """Read-only fast-path gather (ISSUE 13): same device dispatch as
         ``_pull_device`` but on the serving books — its own counter and
         per-table latency histogram, and (in the bundle path) NO flush of
         the open push group.  Skips everything a write needs: optimizer,
         dup policy, ApplyLedger, replica forwarding."""
-        table = self.tables[tname]
-        n = int(ids_np.shape[0])
-        b = _bucket(n)
-        ids = self._put(self._pad_ids(table, ids_np, b))
-        with self.tracer.span(
-            "kv.server.pull_ro", **self._span_attrs(msg, tname)
-        ):
-            rows = table.pull(ids)
+        rows, n = self._dispatch_pull(tname, ids_np, "pull_ro", sp)
         self.ro_pulls += 1
         ver = self._seg_versions[tname]
         sver = int(ver[segs].max()) if segs.size else self.version_max(tname)
@@ -949,30 +985,37 @@ class KVServer(Customer):
     def handle_request(self, msg: Message) -> Message:
         if msg.task.kind == TaskKind.CONTROL:
             return self._handle_control(msg)
-        self._trace_dispatch(msg)
-        v = self._validate_data_request(msg)
-        if isinstance(v, Message):
-            return v
-        tname, ids_np, kn, segs = v
         if msg.task.kind == TaskKind.PUSH:
-            return self._handle_push_single(msg, tname, ids_np, kn, segs)
-        elif msg.task.kind == TaskKind.PULL:
+            with self.tracer.span(
+                "ps.server.push", **self._span_attrs(msg)
+            ) as sp:
+                v = self._admit(msg, sp)
+                if isinstance(v, Message):
+                    return v
+                return self._handle_push_single(msg, *v, sp)
+        if msg.task.kind != TaskKind.PULL:
+            raise ValueError(f"unsupported task kind {msg.task.kind}")
+        # validation to reply built: the D2H is inside
+        with self.tracer.span("ps.server.pull", **self._span_attrs(msg)) as sp:
+            v = self._admit(msg, sp)
+            if isinstance(v, Message):
+                return v
+            tname, ids_np, _kn, segs = v
             if msg.task.payload.get(READ_ONLY_KEY):
                 t0 = time.perf_counter()
-                rows, n, sver = self._pull_ro_device(msg, tname, ids_np, segs)
+                rows, n, sver = self._pull_ro_device(tname, ids_np, segs, sp)
                 if self.device_replies:
                     vals = [rows[:n]]
                 else:
-                    vals = [np.asarray(rows)[:n]]
+                    vals = [self._to_host(rows)[:n]]
                 self.ro_hist[tname].record(time.perf_counter() - t0)
                 return self._stamp_version(msg, msg.reply(values=vals), sver)
-            rows, n, sver = self._pull_device(msg, tname, ids_np, segs)
+            rows, n, sver = self._pull_device(tname, ids_np, segs, sp)
             if self.device_replies:
                 return self._stamp_version(msg, msg.reply(values=[rows[:n]]), sver)
             return self._stamp_version(
-                msg, msg.reply(values=[np.asarray(rows)[:n]]), sver
+                msg, msg.reply(values=[self._to_host(rows)[:n]]), sver
             )
-        raise ValueError(f"unsupported task kind {msg.task.kind}")
 
     # -- bundle-batched apply engine (ISSUE 11) -------------------------------
     def _error_reply(self, msg: Message, exc: Exception) -> Message:
@@ -1024,9 +1067,12 @@ class KVServer(Customer):
             try:
                 if len(group) == 1:
                     i, m, tname, ids_np, kn, segs = group[0]
-                    replies[i] = self._handle_push_single(
-                        m, tname, ids_np, kn, segs
-                    )
+                    with self.tracer.span(
+                        "ps.server.push", **self._span_attrs(m)
+                    ) as sp:
+                        replies[i] = self._handle_push_single(
+                            m, tname, ids_np, kn, segs, sp
+                        )
                 else:
                     self._apply_push_group(group, replies)
             except Exception as e:  # noqa: BLE001
@@ -1063,13 +1109,21 @@ class KVServer(Customer):
                     if msg.task.payload.get(READ_ONLY_KEY):
                         # NO flush_group(): relaxed read, see docstring
                         t0 = time.perf_counter()
-                        rows, n, sver = self._pull_ro_device(
-                            msg, tname, ids_np, segs
-                        )
+                        with self.tracer.span(
+                            "ps.server.pull", **self._span_attrs(msg)
+                        ) as sp:
+                            rows, n, sver = self._pull_ro_device(
+                                tname, ids_np, segs, sp
+                            )
                         ro.append((i, msg, tname, rows, n, sver, t0))
                         continue
                     flush_group()  # the pull must see prior member pushes
-                    rows, n, sver = self._pull_device(msg, tname, ids_np, segs)
+                    with self.tracer.span(
+                        "ps.server.pull", **self._span_attrs(msg)
+                    ) as sp:
+                        rows, n, sver = self._pull_device(
+                            tname, ids_np, segs, sp
+                        )
                     pulls.append((i, msg, rows, n, sver))
                 else:
                     raise ValueError(
@@ -1099,7 +1153,7 @@ class KVServer(Customer):
                     m, m.reply(values=[rows[:n]]), sver
                 )
             return
-        host = jax.device_get([rows for _, _, rows, _, _ in pulls])
+        host = self._to_host([rows for _, _, rows, _, _ in pulls])
         for (i, m, _, n, sver), h in zip(pulls, host):
             replies[i] = self._stamp_version(m, m.reply(values=[h[:n]]), sver)
 
@@ -1116,7 +1170,7 @@ class KVServer(Customer):
                 )
                 self.ro_hist[tname].record(time.perf_counter() - t0)
             return
-        host = jax.device_get([rows for _, _, _, rows, _, _, _ in ro])
+        host = self._to_host([rows for _, _, _, rows, _, _, _ in ro])
         done = time.perf_counter()
         for (i, m, tname, _, n, sver, t0), h in zip(ro, host):
             replies[i] = self._stamp_version(m, m.reply(values=[h[:n]]), sver)
@@ -1139,20 +1193,22 @@ class KVServer(Customer):
         table = self.tables[tname]
         k = len(group)
         bm = _bucket(max(int(g[3].shape[0]) for g in group))
+        rows = sum(int(g[3].shape[0]) for g in group)
         tok = (
             self.ledger.begin(
-                tname,
-                k,
-                sum(int(g[3].shape[0]) for g in group),
-                tid=self._trace_tid_of(group),
+                tname, k, rows, tid=self._trace_tid_of(group)
             )
             if self.ledger is not None
             else None
         )
+        # one span for the group, under its first member's ``req``
         with self.tracer.span(
-            "kv.server.push_batch", table=tname, members=k
+            "ps.server.push", **self._span_attrs(group[0][1]),
+            rows=rows, bucket=bm, members=k,
         ):
-            stack = self._stack_planes(table, group, k, bm, tok)
+            with self.tracer.span("ps.server.h2d") as h2d:
+                stack = self._stack_planes(table, group, k, bm, tok)
+                h2d.set(bytes=stack.nbytes)
             # flat positions of every REAL id occurrence, in member order
             ids_list = [g[3] for g in group]
             all_ids = np.concatenate(ids_list).astype(np.int64)
@@ -1169,10 +1225,10 @@ class KVServer(Customer):
                 ref = self._push_group_combined(table, k, bm, rid, rpos, stack)
             else:
                 ref = self._push_group_rounds(table, k, bm, rid, rpos, stack)
-        if tok is not None:
-            self.ledger.submit(tok, ref, lambda t=table: t.value)
-        for i, m, tname_, _, kn, segs in group:
-            replies[i] = self._ack_push(m, tname_, kn, segs)
+            if tok is not None:
+                self.ledger.submit(tok, ref, lambda t=table: t.value)
+            for i, m, tname_, _, kn, segs in group:
+                replies[i] = self._ack_push(m, tname_, kn, segs)
 
     def _push_group_rounds(
         self,
@@ -1214,9 +1270,10 @@ class KVServer(Customer):
             ids_np[:nt] = uids_t.astype(np.int32)
             pos_np = np.full(bu, pad_pos, dtype=np.int32)
             pos_np[:nt] = pos_t
-            ref = table.push_batch(
-                self._put(ids_np), self._put(pos_np), stack
-            )
+            with self.tracer.span("ps.server.dispatch", op="push_batch"):
+                ref = table.push_batch(
+                    self._put(ids_np), self._put(pos_np), stack
+                )
         return ref  # last round's value: its readiness bounds every round
 
     def _push_group_combined(
@@ -1243,9 +1300,10 @@ class KVServer(Customer):
         ids_np[:nu] = uids.astype(np.int32)
         inverse = np.full(k * bm, min(nu, bu - 1), dtype=np.int32)
         inverse[rpos] = inv_real.astype(np.int32)
-        return table.push_combined(
-            self._put(ids_np), self._put(inverse), stack
-        )
+        with self.tracer.span("ps.server.dispatch", op="push_combined"):
+            return table.push_combined(
+                self._put(ids_np), self._put(inverse), stack
+            )
 
     # -- shard transfer (same-id restart: kv/replica.restart_same_id) --------
     def export_shard(self) -> Dict[str, dict]:

@@ -133,33 +133,44 @@ class KVTable:
     # -- jitted bodies ------------------------------------------------------
     def _apply_core(self, value, state, ids, grads):
         """Apply ``grads`` at unique ``ids``: fused or three-pass, then the
-        trash-row reset (shared by every push entry point)."""
-        if self.fused_apply:
-            value, state = scatter.apply_rows(
-                value, state, ids, grads, self.optimizer.apply,
-                impl=self.scatter_impl, interpret=self._interpret,
-            )
-        else:
-            v_rows = self._kern(scatter.gather_rows, value, ids)
-            s_rows = {
-                k: self._kern(scatter.gather_rows, v, ids)
-                for k, v in state.items()
-            }
-            new_v, new_s = self.optimizer.apply(v_rows, s_rows, grads)
-            value = self._kern(scatter.scatter_update_rows, value, ids, new_v)
-            state = {
-                k: self._kern(
-                    scatter.scatter_update_rows, state[k], ids, new_s[k]
-                )
-                for k in state
-            }
-        # Re-zero the trash row: PAD_KEY positions in real (variable-nnz)
-        # batches legitimately route gradients here; resetting keeps pulls of
-        # padded positions exactly zero and makes duplicate-trash-id scatters
-        # deterministic.
-        value = value.at[-1].set(0.0)
-        fills = self.optimizer.state_shapes()
-        state = {k: state[k].at[-1].set(fills[k]) for k in state}
+        trash-row reset (shared by every push entry point).  The
+        ``jax.named_scope``s put every device operation of the apply to its
+        line here (``PERF.md`` section 3)."""
+        with jax.named_scope("ps.table.apply"):
+            if self.fused_apply:
+                with jax.named_scope("ps.apply.fused"):
+                    value, state = scatter.apply_rows(
+                        value, state, ids, grads, self.optimizer.apply,
+                        impl=self.scatter_impl, interpret=self._interpret,
+                    )
+            else:
+                with jax.named_scope("ps.apply.gather"):
+                    v_rows = self._kern(scatter.gather_rows, value, ids)
+                    s_rows = {
+                        k: self._kern(scatter.gather_rows, v, ids)
+                        for k, v in state.items()
+                    }
+                with jax.named_scope("ps.apply.optimizer"):
+                    new_v, new_s = self.optimizer.apply(v_rows, s_rows, grads)
+                with jax.named_scope("ps.apply.scatter"):
+                    value = self._kern(
+                        scatter.scatter_update_rows, value, ids, new_v
+                    )
+                    state = {
+                        k: self._kern(
+                            scatter.scatter_update_rows, state[k], ids,
+                            new_s[k],
+                        )
+                        for k in state
+                    }
+            # Re-zero the trash row: PAD_KEY positions in real (variable-nnz)
+            # batches legitimately route gradients here; resetting keeps
+            # pulls of padded positions exactly zero and makes
+            # duplicate-trash-id scatters deterministic.
+            with jax.named_scope("ps.apply.trash_reset"):
+                value = value.at[-1].set(0.0)
+                fills = self.optimizer.state_shapes()
+                state = {k: state[k].at[-1].set(fills[k]) for k in state}
         return value, state
 
     def _push_impl(self, value, state, ids, combined):
@@ -169,22 +180,32 @@ class KVTable:
         # vals: (k, bm, dim) member stack; positions index its flattening,
         # with pads pointing at the appended zero row — the device-side
         # bucket pad (no host value copies, exact zeros: bitwise-neutral).
-        flat = vals.reshape(-1, vals.shape[-1])
-        flat = jnp.concatenate([flat, jnp.zeros((1,) + flat.shape[1:], flat.dtype)])
-        return self._apply_core(value, state, ids, flat[positions])
+        with jax.named_scope("ps.table.stack"):
+            flat = vals.reshape(-1, vals.shape[-1])
+            flat = jnp.concatenate(
+                [flat, jnp.zeros((1,) + flat.shape[1:], flat.dtype)]
+            )
+            grads = flat[positions]
+        return self._apply_core(value, state, ids, grads)
 
     def _push_combined_impl(self, value, state, ids, inverse, vals):
         # segment_combine pre-merges duplicate rows across bundle members on
         # device; slots past the unique count only ever receive pad/trash
         # positions, whose values are exact zeros.
-        flat = vals.reshape(-1, vals.shape[-1])
-        combined = scatter.segment_combine(flat, inverse, ids.shape[0])
+        with jax.named_scope("ps.table.stack"):
+            flat = vals.reshape(-1, vals.shape[-1])
+            combined = scatter.segment_combine(flat, inverse, ids.shape[0])
         return self._apply_core(value, state, ids, combined)
 
     def _pull_impl(self, value, state, ids):
-        v_rows = self._kern(scatter.gather_rows, value, ids)
-        s_rows = {k: self._kern(scatter.gather_rows, v, ids) for k, v in state.items()}
-        return self.optimizer.pull_weights(v_rows, s_rows)
+        with jax.named_scope("ps.table.pull"):
+            with jax.named_scope("ps.gather"):
+                v_rows = self._kern(scatter.gather_rows, value, ids)
+                s_rows = {
+                    k: self._kern(scatter.gather_rows, v, ids)
+                    for k, v in state.items()
+                }
+            return self.optimizer.pull_weights(v_rows, s_rows)
 
     # -- public ops ---------------------------------------------------------
     def push(self, ids: jax.Array, combined_grads: jax.Array) -> jax.Array:

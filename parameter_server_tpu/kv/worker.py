@@ -74,12 +74,18 @@ from parameter_server_tpu.kv.routing import (
 from parameter_server_tpu.ops import scatter
 from parameter_server_tpu.utils.keys import HashLocalizer, localize_to_slots
 from parameter_server_tpu.utils.platform import role_device
-from parameter_server_tpu.utils.trace import NULL_TRACER, LatencyHistogram, Tracer
+from parameter_server_tpu.utils.trace import (
+    NULL_TRACER,
+    LatencyHistogram,
+    Tracer,
+    req_id,
+)
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows",))
 def _segment_combine(inverse, values, num_rows: int):
-    return scatter.segment_combine(values, inverse, num_rows)
+    with jax.named_scope("ps.worker.combine"):
+        return scatter.segment_combine(values, inverse, num_rows)
 
 
 class KVWorker(Customer):
@@ -130,7 +136,8 @@ class KVWorker(Customer):
         #: pre-combine, device-side pull assembly): workers of an in-process
         #: cluster spread over the host's chips by their node index
         self.device = role_device(node_index(post.node_id))
-        #: host-side span recorder (Push/Pull latency histograms, SURVEY §5)
+        #: span recorder (``utils/trace.py``): its ``ps.worker.*`` spans reach
+        #: a capturing profiler session whether or not it is enabled
         self.tracer = tracer
         self.table_cfgs = table_cfgs
         self.num_servers = num_servers
@@ -861,33 +868,35 @@ class KVWorker(Customer):
         if positions is None:
             positions = np.arange(keys.shape[0], dtype=np.int64)
         sub = keys[positions]
-        msgs, order = [], {}
-        for s, rel, ids in routing.slice_ids(table, sub):
-            abs_pos = positions[rel]
-            order[server_id(s)] = abs_pos
-            payload = {
-                "table": table,
-                ROUTING_EPOCH_KEY: routing.epoch,
-                GROUP_KEY: dict(stamp),
-            }
-            if tctx is not None:
-                payload[TRACE_KEY] = tctx
-            msgs.append(
-                Message(
-                    task=Task(TaskKind.PUSH, self.name, payload=payload),
-                    recver=server_id(s),
-                    keys=ids.astype(np.int32),
-                    values=[vals[abs_pos]],
+        with self.tracer.span("ps.worker.submit") as sp:
+            msgs, order = [], {}
+            for s, rel, ids in routing.slice_ids(table, sub):
+                abs_pos = positions[rel]
+                order[server_id(s)] = abs_pos
+                payload = {
+                    "table": table,
+                    ROUTING_EPOCH_KEY: routing.epoch,
+                    GROUP_KEY: dict(stamp),
+                }
+                if tctx is not None:
+                    payload[TRACE_KEY] = tctx
+                msgs.append(
+                    Message(
+                        task=Task(TaskKind.PUSH, self.name, payload=payload),
+                        recver=server_id(s),
+                        keys=ids.astype(np.int32),
+                        values=[vals[abs_pos]],
+                    )
                 )
+            cb = functools.partial(
+                self._group_wire_done, table, step, keys, vals, fanin,
+                attempt, order,
             )
-        cb = functools.partial(
-            self._group_wire_done, table, step, keys, vals, fanin, attempt,
-            order,
-        )
-        # registered before the submit: the acks race the submit call
-        self._trace_submitted(tctx, "group_push", len(msgs))
-        with self.coalesce_window():
-            ts = self.submit(msgs, callback=cb)
+            # registered before the submit: the acks race the submit call
+            self._trace_submitted(tctx, "group_push", len(msgs))
+            with self.coalesce_window():
+                ts = self.submit(msgs, callback=cb)
+            sp.set(req=self._req(ts), legs=len(msgs))
         with self._group_lock:
             self.group_pushes += 1
             self.group_reduced_fanin += int(fanin)
@@ -1104,62 +1113,89 @@ class KVWorker(Customer):
         """
         tctx = tctx if tctx is not None else self._trace_ctx()
         routing = self.routing  # one consistent table per submit
-        if positions is None:
-            positions = np.arange(slots.shape[0], dtype=np.int64)
-        sub = slots[positions]
-        # consistency plane (ISSUE 20): gated tables stamp the sender's
-        # committed step (a plain int — the fast meta codec stays eligible)
-        cstep = (
-            self.consist_step(table)
-            if not ungated and self._gated(table)
-            else None
-        )
-        msgs, order = [], {}
-        for s, rel, ids in routing.slice_ids(table, sub):
-            abs_pos = positions[rel]
-            order[server_id(s)] = abs_pos
-            payload = {
-                "table": table,
-                ROUTING_EPOCH_KEY: routing.epoch,
-            }
-            if cstep is not None:
-                payload[CONSIST_STEP_KEY] = cstep
-            if tctx is not None:
-                payload[TRACE_KEY] = tctx
-            msgs.append(
-                Message(
-                    task=Task(TaskKind.PUSH, self.name, payload=payload),
-                    recver=server_id(s),
-                    keys=ids.astype(np.int32),
-                    values=[combined[abs_pos]],
-                )
+        with self.tracer.span("ps.worker.submit") as sp:
+            if positions is None:
+                positions = np.arange(slots.shape[0], dtype=np.int64)
+            sub = slots[positions]
+            # consistency plane (ISSUE 20): gated tables stamp the sender's
+            # committed step (a plain int — the fast meta codec stays
+            # eligible)
+            cstep = (
+                self.consist_step(table)
+                if not ungated and self._gated(table)
+                else None
             )
-        # register the span tree BEFORE the wire submit: replies race the
-        # submit call (a fast peer can ack before submit() returns), and a
-        # decrement that finds no pending entry would leak an open tree
-        self._trace_submitted(tctx, "push", len(msgs))
-        # window: under a CoalescingVan the burst flushes at submit
-        # exit (no flush-timer latency); nested inside push_many's
-        # window it coalesces across tables instead
-        with self.coalesce_window():
-            ts = self.submit(msgs, keep_responses=keep)
+            msgs, order = [], {}
+            for s, rel, ids in routing.slice_ids(table, sub):
+                abs_pos = positions[rel]
+                order[server_id(s)] = abs_pos
+                payload = {
+                    "table": table,
+                    ROUTING_EPOCH_KEY: routing.epoch,
+                }
+                if cstep is not None:
+                    payload[CONSIST_STEP_KEY] = cstep
+                if tctx is not None:
+                    payload[TRACE_KEY] = tctx
+                msgs.append(
+                    Message(
+                        task=Task(TaskKind.PUSH, self.name, payload=payload),
+                        recver=server_id(s),
+                        keys=ids.astype(np.int32),
+                        values=[combined[abs_pos]],
+                    )
+                )
+            # register the span tree BEFORE the wire submit: replies race
+            # the submit call (a fast peer can ack before submit() returns),
+            # and a decrement that finds no pending entry would leak an open
+            # tree
+            self._trace_submitted(tctx, "push", len(msgs))
+            # window: under a CoalescingVan the burst flushes at submit
+            # exit (no flush-timer latency); nested inside push_many's
+            # window it coalesces across tables instead
+            with self.coalesce_window():
+                ts = self.submit(msgs, keep_responses=keep)
+            sp.set(req=self._req(ts), legs=len(msgs))
         return ts, order
 
     def _prepare_push(self, table: str, keys, values):
         """Host half of a push: localize + device duplicate pre-combine."""
         cfg = self.table_cfgs[table]
         vals = np.asarray(values, dtype=cfg.dtype).reshape(keys.size, cfg.dim)
-        slots, inverse, _n = localize_to_slots(
-            keys, self.localizers[table], min_bucket=self.min_bucket
-        )
-        combined = np.asarray(
-            _segment_combine(
-                jax.device_put(inverse, self.device),
-                jax.device_put(vals, self.device),
-                slots.shape[0],
+        slots, inverse = self._localize(table, keys)
+        with self.tracer.span("ps.worker.combine", unique=slots.shape[0]):
+            combined = np.asarray(
+                _segment_combine(
+                    jax.device_put(inverse, self.device),
+                    jax.device_put(vals, self.device),
+                    slots.shape[0],
+                )
             )
-        )
         return slots, combined
+
+    def _localize(self, table: str, keys) -> Tuple[np.ndarray, np.ndarray]:
+        """``localize_to_slots`` under its span: ``(slots, inverse)``."""
+        with self.tracer.span(
+            "ps.worker.localize", keys=int(keys.size)
+        ) as sp:
+            slots, inverse, n = localize_to_slots(
+                keys, self.localizers[table], min_bucket=self.min_bucket
+            )
+            sp.set(unique=n)
+        return slots, inverse
+
+    def _req(self, ts: int) -> str:
+        """``req`` of this customer's task ``ts`` (``utils/trace.py``)."""
+        return req_id(self.post.node_id, self.name, ts)
+
+    def _wait_traced(
+        self, ts: int, legs: int, timeout: Optional[float], retry: int = 0
+    ) -> bool:
+        """``self.wait(ts, timeout)`` under ``ps.worker.wait``."""
+        with self.tracer.span(
+            "ps.worker.wait", req=self._req(ts), legs=legs, retry=retry
+        ):
+            return self.wait(ts, timeout)
 
     def push(self, table: str, keys: np.ndarray, values: np.ndarray) -> int:
         """Push per-position gradient rows for ``keys``.  Returns timestamp.
@@ -1176,7 +1212,7 @@ class KVWorker(Customer):
         """
         tctx = self._trace_ctx()
         with self.tracer.span(
-            "kv.push", table=table, n=int(keys.size),
+            "ps.worker.push", table=table, keys=int(keys.size),
             **({"trace": tctx["tid"]} if tctx is not None else {}),
         ):
             slots, combined = self._prepare_push(table, keys, values)
@@ -1199,15 +1235,16 @@ class KVWorker(Customer):
         """
         tctx = self._trace_ctx()
         with self.tracer.span(
-            "kv.push", table=table, n=int(keys.size),
+            "ps.worker.push", table=table, keys=int(keys.size),
             **({"trace": tctx["tid"]} if tctx is not None else {}),
         ):
             cfg = self.table_cfgs[table]
             vals = values.reshape(keys.size, cfg.dim)
-            slots, inverse, _n = localize_to_slots(
-                keys, self.localizers[table], min_bucket=self.min_bucket
-            )
-            combined = _segment_combine(jnp.asarray(inverse), vals, slots.shape[0])
+            slots, inverse = self._localize(table, keys)
+            with self.tracer.span("ps.worker.combine", unique=slots.shape[0]):
+                combined = _segment_combine(
+                    jnp.asarray(inverse), vals, slots.shape[0]
+                )
             ts, _ = self._submit_push(table, slots, combined, tctx=tctx)
             return ts
 
@@ -1254,9 +1291,7 @@ class KVWorker(Customer):
         reads that may NOT observe writes coalesced into the same wire
         bundle.  Training pulls must keep the default.
         """
-        slots, inverse, _n = localize_to_slots(
-            keys, self.localizers[table], min_bucket=self.min_bucket
-        )
+        slots, inverse = self._localize(table, keys)
         return self._submit_pull(
             table, slots, inverse, keys.shape, read_only=read_only
         )
@@ -1274,42 +1309,47 @@ class KVWorker(Customer):
     ) -> int:
         tctx = self._trace_ctx()
         routing = self.routing
-        if positions is None:
-            positions = np.arange(slots.shape[0], dtype=np.int64)
-        sub = slots[positions]
-        msgs = []
-        order = {}
-        payload = {
-            "table": table,
-            ROUTING_EPOCH_KEY: routing.epoch,
-        }
-        # consistency plane (ISSUE 20): training pulls on gated tables
-        # stamp the committed step so a lagging/ahead worker is gated at
-        # the server.  Read-only serving pulls are NEVER gated — they are
-        # the shed target — and ``ungated=True`` is the deadline
-        # force-through (fresh data can never violate a staleness bound).
-        if not read_only and not ungated and self._gated(table):
-            payload[CONSIST_STEP_KEY] = self.consist_step(table)
-        if tctx is not None:
-            payload[TRACE_KEY] = tctx
-        if read_only:
-            payload[READ_ONLY_KEY] = True
-        for s, rel, ids in routing.slice_ids(table, sub):
-            abs_pos = positions[rel]
-            order[server_id(s)] = abs_pos
-            msgs.append(
-                Message(
-                    # fresh dict per leg: payloads must never be shared
-                    # across messages (a Loopback reply path may alias them)
-                    task=Task(TaskKind.PULL, self.name, payload=dict(payload)),
-                    recver=server_id(s),
-                    keys=ids.astype(np.int32),
+        with self.tracer.span("ps.worker.submit") as sp:
+            if positions is None:
+                positions = np.arange(slots.shape[0], dtype=np.int64)
+            sub = slots[positions]
+            msgs = []
+            order = {}
+            payload = {
+                "table": table,
+                ROUTING_EPOCH_KEY: routing.epoch,
+            }
+            # consistency plane (ISSUE 20): training pulls on gated tables
+            # stamp the committed step so a lagging/ahead worker is gated at
+            # the server.  Read-only serving pulls are NEVER gated — they are
+            # the shed target — and ``ungated=True`` is the deadline
+            # force-through (fresh data can never violate a staleness bound).
+            if not read_only and not ungated and self._gated(table):
+                payload[CONSIST_STEP_KEY] = self.consist_step(table)
+            if tctx is not None:
+                payload[TRACE_KEY] = tctx
+            if read_only:
+                payload[READ_ONLY_KEY] = True
+            for s, rel, ids in routing.slice_ids(table, sub):
+                abs_pos = positions[rel]
+                order[server_id(s)] = abs_pos
+                msgs.append(
+                    Message(
+                        # fresh dict per leg: payloads must never be shared
+                        # across messages (a Loopback reply path may alias
+                        # them)
+                        task=Task(
+                            TaskKind.PULL, self.name, payload=dict(payload)
+                        ),
+                        recver=server_id(s),
+                        keys=ids.astype(np.int32),
+                    )
                 )
-            )
-        # registered before the submit: the replies race the submit call
-        self._trace_submitted(tctx, "pull", len(msgs))
-        with self.coalesce_window():
-            ts = self.submit(msgs, keep_responses=True)
+            # registered before the submit: the replies race the submit call
+            self._trace_submitted(tctx, "pull", len(msgs))
+            with self.coalesce_window():
+                ts = self.submit(msgs, keep_responses=True)
+            sp.set(req=self._req(ts), legs=len(msgs))
         self._pull_plans[ts] = {
             "order": order,
             "inverse": inverse,
@@ -1318,7 +1358,6 @@ class KVWorker(Customer):
             "table": table,
             # retained so deadline/fence retries can re-issue subsets
             "slots": slots,
-            "trace": tctx["tid"] if tctx is not None else None,
             "ro": read_only,
             "ungated": ungated,
         }
@@ -1330,9 +1369,9 @@ class KVWorker(Customer):
 
         Returns ``(plan, responses, errs)`` with all kept state drained.
         """
-        tid = self._pull_plans[ts].get("trace")
-        with self.tracer.span("kv.pull.wait", ts=ts, trace=tid):
-            completed = self.wait(ts, timeout)
+        completed = self._wait_traced(
+            ts, len(self._pull_plans[ts]["order"]), timeout
+        )
         if not completed and self.retry_on_timeout:
             plan = self._pull_plans.pop(ts)
             # remote=True fences the dead pull at servers whose request leg
@@ -1351,9 +1390,9 @@ class KVWorker(Customer):
                 read_only=plan.get("ro", False),
                 ungated=plan.get("ungated", False),
             )
-            tid = self._pull_plans[ts].get("trace")
-            with self.tracer.span("kv.pull.wait", ts=ts, retry=1, trace=tid):
-                completed = self.wait(ts, timeout)
+            completed = self._wait_traced(
+                ts, len(self._pull_plans[ts]["order"]), timeout, retry=1
+            )
         plan = self._pull_plans.pop(ts)  # always reclaim, even on error paths
         errs = self.errors(ts)
         responses = self.take_responses(ts)  # always drain kept state
@@ -1395,7 +1434,10 @@ class KVWorker(Customer):
     def _gate_pause(self, table: str, retry_after: float) -> None:
         cfg = self.table_cfgs[table].consistency
         base = cfg.gate_retry_s if cfg is not None else 0.005
-        time.sleep(max(retry_after, base))
+        with self.tracer.span(
+            "ps.worker.gate_pause", retry_after_ms=1e3 * retry_after
+        ):
+            time.sleep(max(retry_after, base))
 
     def _pull_pairs(self, ts: int, timeout: Optional[float]) -> tuple:
         """Resolve pull ``ts`` into ``(plan, [(positions, rows, sver,
@@ -1546,16 +1588,24 @@ class KVWorker(Customer):
         """
         plan, pairs = self._pull_pairs(ts, timeout)
         cfg = self.table_cfgs[plan["table"]]
-        sole = self._sole_full_pair(pairs, plan["n_slots"])
-        if sole is not None:
-            # dtype= is a no-op passthrough when the reply already matches
-            # (the normal case); only an off-dtype reply pays a cast copy
-            uniq_rows = np.asarray(sole, dtype=cfg.dtype).reshape(-1, cfg.dim)
-        else:
-            uniq_rows = np.zeros((plan["n_slots"], cfg.dim), dtype=cfg.dtype)
-            for pos, rows, *_meta in pairs:
-                uniq_rows[pos] = np.asarray(rows).reshape(-1, cfg.dim)
-        out = uniq_rows[plan["inverse"]]
+        with self.tracer.span(
+            "ps.worker.assemble", legs=len(pairs), rows=plan["n_slots"]
+        ):
+            sole = self._sole_full_pair(pairs, plan["n_slots"])
+            if sole is not None:
+                # dtype= is a no-op passthrough when the reply already
+                # matches (the normal case); only an off-dtype reply pays a
+                # cast copy
+                uniq_rows = np.asarray(sole, dtype=cfg.dtype).reshape(
+                    -1, cfg.dim
+                )
+            else:
+                uniq_rows = np.zeros(
+                    (plan["n_slots"], cfg.dim), dtype=cfg.dtype
+                )
+                for pos, rows, *_meta in pairs:
+                    uniq_rows[pos] = np.asarray(rows).reshape(-1, cfg.dim)
+            out = uniq_rows[plan["inverse"]]
         if cfg.dim == 1:
             return out.reshape(plan["shape"])
         return out.reshape(plan["shape"] + (cfg.dim,))
@@ -1570,19 +1620,24 @@ class KVWorker(Customer):
         """
         plan, pairs = self._pull_pairs(ts, timeout)
         cfg = self.table_cfgs[plan["table"]]
-        sole = self._sole_full_pair(pairs, plan["n_slots"])
-        # replies from servers on other chips cross to this worker's here
-        dtype = jnp.dtype(cfg.dtype)
-        if sole is not None:
-            uniq = jax.device_put(sole, self.device)
-            uniq = uniq.astype(dtype).reshape(-1, cfg.dim)
-        else:
-            with jax.default_device(self.device):
-                uniq = jnp.zeros((plan["n_slots"], cfg.dim), dtype)
-            for pos, rows, *_meta in pairs:
-                rows = jax.device_put(rows, self.device).reshape(-1, cfg.dim)
-                uniq = uniq.at[jnp.asarray(pos)].set(rows)
-        out = jnp.take(uniq, jnp.asarray(plan["inverse"]), axis=0)
+        with self.tracer.span(
+            "ps.worker.assemble", legs=len(pairs), rows=plan["n_slots"]
+        ):
+            sole = self._sole_full_pair(pairs, plan["n_slots"])
+            # replies from servers on other chips cross to this worker's here
+            dtype = jnp.dtype(cfg.dtype)
+            if sole is not None:
+                uniq = jax.device_put(sole, self.device)
+                uniq = uniq.astype(dtype).reshape(-1, cfg.dim)
+            else:
+                with jax.default_device(self.device):
+                    uniq = jnp.zeros((plan["n_slots"], cfg.dim), dtype)
+                for pos, rows, *_meta in pairs:
+                    rows = jax.device_put(rows, self.device).reshape(
+                        -1, cfg.dim
+                    )
+                    uniq = uniq.at[jnp.asarray(pos)].set(rows)
+            out = jnp.take(uniq, jnp.asarray(plan["inverse"]), axis=0)
         if cfg.dim == 1:
             return out.reshape(plan["shape"])
         return out.reshape(plan["shape"] + (cfg.dim,))
@@ -1590,7 +1645,10 @@ class KVWorker(Customer):
     def pull_sync(
         self, table: str, keys: np.ndarray, timeout: Optional[float] = None
     ) -> np.ndarray:
-        return self.pull_result(self.pull(table, keys), timeout)
+        with self.tracer.span(
+            "ps.worker.pull", table=table, keys=int(keys.size)
+        ):
+            return self.pull_result(self.pull(table, keys), timeout)
 
     # -- read-heavy serving plane (ISSUE 13) ---------------------------------
     def pull_serve(
@@ -1613,7 +1671,9 @@ class KVWorker(Customer):
                 self.pull(table, keys, read_only=True), timeout
             )
         cfg = self.table_cfgs[table]
-        with self.tracer.span("kv.pull_serve", table=table, n=int(keys.size)):
+        with self.tracer.span(
+            "ps.worker.pull_serve", table=table, keys=int(keys.size)
+        ):
             # No dedup/sort on the hit path: ``Localizer.assign`` is
             # elementwise, so probe one slot PER POSITION (duplicates probe
             # twice — vectorized, cheaper than a ``np.unique``) and the
@@ -1687,9 +1747,7 @@ class KVWorker(Customer):
             return None
         keys = np.asarray(keys)
         cfg = self.table_cfgs[table]
-        slots, inverse, _n = localize_to_slots(
-            keys, self.localizers[table], min_bucket=self.min_bucket
-        )
+        slots, inverse = self._localize(table, keys)
         grows = self.routing.tables[table].rows
         rows_out = np.zeros((int(slots.shape[0]), cfg.dim), dtype=cfg.dtype)
         for j, sl in enumerate(np.asarray(slots).tolist()):
@@ -1741,12 +1799,15 @@ class KVWorker(Customer):
         retry to the next member; leader death degrades to this member's
         own direct push within the same step.
         """
-        slots, combined = self._prepare_push(table, keys, values)
-        if self._group is not None:
-            return self._group_push(
-                table, slots, combined, sync=True, timeout=timeout
-            )
-        return self._push_sync_prepared(table, slots, combined, timeout)
+        with self.tracer.span(
+            "ps.worker.push", table=table, keys=int(keys.size)
+        ):
+            slots, combined = self._prepare_push(table, keys, values)
+            if self._group is not None:
+                return self._group_push(
+                    table, slots, combined, sync=True, timeout=timeout
+                )
+            return self._push_sync_prepared(table, slots, combined, timeout)
 
     def _push_sync_prepared(
         self,
@@ -1773,7 +1834,7 @@ class KVWorker(Customer):
             ts, order = self._submit_push(
                 table, slots, combined, positions, keep=True, ungated=ungated
             )
-            if not self.wait(ts, timeout):
+            if not self._wait_traced(ts, len(order), timeout):
                 if not self.retry_on_timeout:
                     raise TimeoutError(f"push ts={ts} timed out")
                 # remote=True: servers that have not applied the original yet
@@ -1787,7 +1848,7 @@ class KVWorker(Customer):
                     table, slots, combined, positions, keep=True,
                     ungated=ungated,
                 )
-                if not self.wait(ts, timeout):
+                if not self._wait_traced(ts, len(order), timeout, retry=1):
                     self.cancel(ts, "push deadline (retry)", remote=True)
                     self.take_responses(ts)
                     raise TimeoutError(f"push ts={ts} timed out after retry")

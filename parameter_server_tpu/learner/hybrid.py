@@ -249,7 +249,7 @@ class HybridLMTrainer:
         if multiproc:
             from parameter_server_tpu.parallel import distributed
 
-            with self.tracer.span("hybrid.pull_wait"):
+            with self.tracer.span("ps.hybrid.pull_wait"):
                 emb_local = self.worker.pull_result(ts, timeout=pull_timeout)
             emb_d = distributed.host_local_batch(
                 self._batch3,
@@ -262,7 +262,7 @@ class HybridLMTrainer:
                 tokens.shape,
             )
         else:
-            with self.tracer.span("hybrid.pull_wait"):
+            with self.tracer.span("ps.hybrid.pull_wait"):
                 emb_in = self.worker.pull_result_device(
                     ts, timeout=pull_timeout
                 )
@@ -277,7 +277,7 @@ class HybridLMTrainer:
         # device step to read g_emb shards, so push/prefetch issue AFTER
         # device compute there (the overlap window is the Van RTT against
         # the NEXT step's host work, not against this body step).
-        with self.tracer.span("hybrid.body_dispatch"):
+        with self.tracer.span("ps.hybrid.body_dispatch"):
             self.params, self.opt_state, loss, g_emb = self._step(
                 self.params, self.opt_state, emb_d, tok_d
             )
@@ -325,7 +325,7 @@ class HybridLMTrainer:
             if not self.worker.wait(old, timeout=self.push_timeout):
                 raise TimeoutError(f"embedding push ts={old} not acked")
         self.step_count += 1
-        with self.tracer.span("hybrid.loss_sync"):
+        with self.tracer.span("ps.hybrid.loss_sync"):
             loss_f = float(loss)
         emb_mb = tokens.size * self.cfg.d_model * 4 * 2 / 1e6  # pull + push
         # one example = one sequence: 6 x body params x seq tokens

@@ -59,17 +59,21 @@ class DLRM(nn.Module):
     @nn.compact
     def __call__(self, dense_feats: jax.Array, emb: jax.Array) -> jax.Array:
         """dense_feats [B, n_dense]; emb [B, n_sparse, emb_dim] -> logits [B]."""
-        bottom = MLP(tuple(self.bottom_mlp) + (self.emb_dim,))(dense_feats)
-        feats = jnp.concatenate([bottom[:, None, :], emb], axis=1)  # [B, F, D]
-        inter = jnp.einsum(
-            "bfd,bgd->bfg", feats, feats, preferred_element_type=jnp.float32
-        )
-        f = feats.shape[1]
-        iu, ju = jnp.triu_indices(f, k=1)
-        inter_flat = inter[:, iu, ju]  # [B, F*(F-1)/2]
-        top_in = jnp.concatenate([bottom, inter_flat], axis=1)
-        logits = MLP(tuple(self.top_mlp) + (1,), final_activation=False)(top_in)
-        return logits[:, 0]
+        with jax.named_scope("ps.model.dlrm"):
+            bottom = MLP(tuple(self.bottom_mlp) + (self.emb_dim,))(dense_feats)
+            feats = jnp.concatenate([bottom[:, None, :], emb], axis=1)  # [B, F, D]
+            inter = jnp.einsum(
+                "bfd,bgd->bfg", feats, feats,
+                preferred_element_type=jnp.float32,
+            )
+            f = feats.shape[1]
+            iu, ju = jnp.triu_indices(f, k=1)
+            inter_flat = inter[:, iu, ju]  # [B, F*(F-1)/2]
+            top_in = jnp.concatenate([bottom, inter_flat], axis=1)
+            logits = MLP(
+                tuple(self.top_mlp) + (1,), final_activation=False
+            )(top_in)
+            return logits[:, 0]
 
 
 def make_dlrm_step(

@@ -50,11 +50,12 @@ def grad_rows(
 
     Returns ``(per_position_grads [B, nnz], bias_grad [], loss [])``.
     """
-    logits = predict_logits(w_pos, 0.0)
-    p = jax.nn.sigmoid(logits)
-    residual = p - labels  # [B]
-    g = jnp.broadcast_to(residual[:, None], w_pos.shape)
-    return g, jnp.mean(residual), logloss(logits, labels)
+    with jax.named_scope("ps.model.linear"):
+        logits = predict_logits(w_pos, 0.0)
+        p = jax.nn.sigmoid(logits)
+        residual = p - labels  # [B]
+        g = jnp.broadcast_to(residual[:, None], w_pos.shape)
+        return g, jnp.mean(residual), logloss(logits, labels)
 
 
 @functools.partial(

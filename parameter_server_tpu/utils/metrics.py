@@ -369,8 +369,8 @@ class Dashboard:
         """Cumulative seconds per span name from the attached tracer.
 
         Trainers record spans named by plane (e.g. ``host.assemble``,
-        ``h2d``, ``device.step``, ``kv.push``); this sums their durations so
-        a step-time budget — where did the wall clock actually go — rides
+        ``h2d``, ``device.step``, ``ps.worker.push``); this sums their durations
+        so a step-time budget — where did the wall clock actually go — rides
         next to the throughput numbers (SURVEY §5 observability).  Uses the
         tracer's O(1) running totals when available (hot-path safe).
         """

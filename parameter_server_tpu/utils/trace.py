@@ -3,25 +3,78 @@
 SURVEY.md §5 tracing plan: the reference has only ad-hoc timing macros and
 ``/proc`` polling (``util/resource_usage.h``, ``system/network_usage.h``
 [U]); the rebuild gets a real tracer — Push/Pull latency histograms on the
-host path, exportable timelines, and a ``jax.profiler`` hook for the device
-side (TensorBoard traces with ICI utilization).
+host path and exportable timelines.
 
-Design: recording a span is two ``perf_counter`` calls and one deque append
-under a lock (~1 microsecond) so the tracer can stay on in production; the
-module-level :data:`NULL_TRACER` short-circuits to nothing for hot loops
-that want zero overhead.
+:meth:`Tracer.span` is the ONE way the package records a span, and it has
+two sinks:
+
+- while a ``jax.profiler`` session is capturing, every span — of an
+  enabled tracer or not — opens a ``jax.profiler.TraceAnnotation``, so it
+  lands in the session's ``.xplane.pb`` on the clock the device operations
+  are on, with its attributes, the thread's CPU time (``cpu_us``) and, by
+  nesting on its thread, its parent.  ``benchmarks/harness/
+  program_spans.py`` reads them (``python3 -m
+  benchmarks.harness.program_spans <file>`` prints the account);
+- an enabled :class:`Tracer` also keeps the span in its own bounded deque
+  and its :class:`LatencyHistogram` (the operator's use: ``Dashboard``,
+  telemetry digests, the chrome-trace export).
+
+With no session and a disabled tracer a span is one check and a shared
+no-op context manager.  Span names are closed over :data:`SPANS`.
+
+Spans of one request share ``req="<sender>/<customer>/<task.time>"`` across
+threads (:func:`req_id`): fields every message already has, so no payload
+key rides the wire for it.
 """
 
 from __future__ import annotations
 
 import collections
-import contextlib
 import json
 import math
 import os
+import sys
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
+
+#: Closed span-name registry.  ``tools/check_wrappers.py`` parses this
+#: frozenset LITERAL by AST (no import) and holds every literal first
+#: argument of a ``span(`` call under the package to it, so keep it a plain
+#: frozenset of plain string constants.  ``PERF.md`` section 3 says which
+#: metric reads which.
+SPANS = frozenset({
+    # worker (kv/worker.py): the roots of a request, then what they nest
+    "ps.worker.pull",
+    "ps.worker.push",
+    "ps.worker.pull_serve",
+    "ps.worker.localize",
+    "ps.worker.combine",
+    "ps.worker.submit",
+    "ps.worker.wait",
+    "ps.worker.gate_pause",
+    "ps.worker.assemble",
+    # van (core/netmon.py)
+    "ps.van.send",
+    "ps.van.deliver",
+    # server (kv/server.py)
+    "ps.server.pull",
+    "ps.server.push",
+    "ps.server.h2d",
+    "ps.server.dispatch",
+    "ps.server.d2h",
+    # hybrid learner (learner/hybrid.py)
+    "ps.hybrid.pull_wait",
+    "ps.hybrid.body_dispatch",
+    "ps.hybrid.loss_sync",
+})
+
+
+def req_id(sender: str, customer: str, ts: int) -> str:
+    """The id the spans of one request share: the requesting node, its
+    customer and the customer timestamp that matches replies to requests."""
+    return f"{sender}/{customer}/{ts}"
+
 
 #: one recorded span: (name, start_s, duration_s, thread_id, attrs)
 Span = Tuple[str, float, float, int, Optional[dict]]
@@ -147,6 +200,78 @@ class LatencyHistogram:
         return h
 
 
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session captures,
+    else ``None``.  Looked up in ``sys.modules``: the transport modules
+    import this file and stay free of jax, and a process that never
+    imported jax has no session."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation
+    return ann if ann.is_enabled() else None
+
+
+class _NullSpan:
+    """What :meth:`Tracer.span` hands out when nothing records."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set(self, **attrs) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """One span in flight: a ``TraceAnnotation`` while a profiler session
+    captures (``ann``), a record in ``tracer`` when that is enabled."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_ann", "_start", "_cpu0")
+
+    def __init__(self, tracer, name: str, attrs: dict, ann) -> None:
+        self._tracer = tracer
+        self._name = name
+        self._attrs = attrs
+        self._ann = ann(name, **attrs) if ann is not None else None
+
+    def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._cpu0 = time.thread_time()
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._start = time.perf_counter()
+        return self
+
+    def set(self, **attrs) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+        if self._tracer is not None:
+            self._attrs.update(attrs)
+
+    def __exit__(self, *exc) -> None:
+        if self._tracer is not None:
+            self._tracer._store(
+                self._name, self._start,
+                time.perf_counter() - self._start, self._attrs,
+            )
+        if self._ann is not None:
+            # wall minus CPU in a span that does no I/O is time the thread
+            # stood without the GIL
+            self._ann.set_metadata(
+                cpu_us=int(1e6 * (time.thread_time() - self._cpu0))
+            )
+            self._ann.__exit__(*exc)
+        return None
+
+
 class Tracer:
     """Thread-safe span recorder: bounded timeline + unbounded histograms.
 
@@ -169,25 +294,24 @@ class Tracer:
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs) -> Iterator[None]:
-        if not self.enabled:
-            yield
-            return
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - start
-            with self._lock:
-                self._spans.append(
-                    (name, start - self._t0, dur, threading.get_ident(),
-                     attrs or None)
-                )
-                h = self._hists.get(name)
-                if h is None:
-                    h = self._hists[name] = LatencyHistogram()
-                h.record(dur)
+    def span(self, name: str, **attrs) -> "_Span":
+        """Context manager recording one span; ``sp.set(**attrs)`` inside
+        the ``with`` adds attributes learned on the way (module docstring)."""
+        ann = _annotation()
+        if ann is None and not self.enabled:
+            return _NULL_SPAN
+        return _Span(self if self.enabled else None, name, attrs, ann)
+
+    def _store(self, name: str, start: float, dur: float, attrs) -> None:
+        with self._lock:
+            self._spans.append(
+                (name, start - self._t0, dur, threading.get_ident(),
+                 attrs or None)
+            )
+            h = self._hists.get(name)
+            if h is None:
+                h = self._hists[name] = LatencyHistogram()
+            h.record(dur)
 
     def record(self, name: str, duration_s: float,
                start_s: Optional[float] = None, **attrs) -> None:
@@ -201,15 +325,7 @@ class Tracer:
             return
         if start_s is None:
             start_s = time.perf_counter() - duration_s
-        with self._lock:
-            self._spans.append(
-                (name, start_s - self._t0, duration_s,
-                 threading.get_ident(), attrs or None)
-            )
-            h = self._hists.get(name)
-            if h is None:
-                h = self._hists[name] = LatencyHistogram()
-            h.record(duration_s)
+        self._store(name, start_s, duration_s, attrs)
 
     def totals(self) -> Dict[str, float]:
         """Cumulative seconds per span name (O(names), never drops spans)."""
@@ -278,36 +394,13 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
 
-    def dump_jsonl(self, path: str) -> None:
-        with open(path, "w") as f:
-            for name, start, dur, tid, attrs in self.spans():
-                f.write(
-                    json.dumps(
-                        {"name": name, "start_s": start, "dur_s": dur,
-                         "tid": tid, "attrs": attrs}
-                    )
-                    + "\n"
-                )
 
-
-#: shared do-nothing tracer for hot paths with tracing off
+#: shared tracer that keeps nothing itself: its spans still reach a
+#: capturing ``jax.profiler`` session
 NULL_TRACER = Tracer(enabled=False)
 
-
-@contextlib.contextmanager
-def jax_profile(logdir: str) -> Iterator[None]:
-    """Device-side profile: wraps ``jax.profiler.trace`` (TensorBoard).
-
-    The host Tracer covers Van/host latency; this captures the XLA timeline
-    (HBM traffic, ICI collectives) for the same window.
-    """
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+#: for code with no tracer handle (``core/netmon.py``)
+span = NULL_TRACER.span
 
 
 def resource_usage() -> dict:

@@ -248,7 +248,7 @@ def test_hybrid_prefetch_hides_pull_latency():
                 if i + 1 < len(batches):
                     _time.sleep(0.3)  # the emulated long body step
             tr.drain()
-            waits = [s[2] for s in tracer.spans("hybrid.pull_wait")]
+            waits = [s[2] for s in tracer.spans("ps.hybrid.pull_wait")]
             # skip step 0 (never prefetched)
             return float(np.mean(waits[1:]))
         finally:

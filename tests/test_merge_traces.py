@@ -72,8 +72,8 @@ def _run_traced_cluster(tmp_path):
 
 def test_merged_timeline_validates_and_stitches(mt, tmp_path):
     """Acceptance (b): the merged doc passes schema validation, every node
-    is its own pid with a process_name, and each worker kv.push trace id
-    reappears on kv.server.push spans of a DIFFERENT pid."""
+    is its own pid with a process_name, and each worker ps.worker.push trace
+    id reappears on ps.server.push spans of a DIFFERENT pid."""
     paths = _run_traced_cluster(tmp_path)
     merged = mt.merge_traces(paths)
     assert mt.validate_chrome_trace(merged) == []
@@ -94,8 +94,8 @@ def test_merged_timeline_validates_and_stitches(mt, tmp_path):
                     out.setdefault(tid, []).append(e)
         return out
 
-    pushes = by_trace("kv.push")
-    server_pushes = by_trace("kv.server.push")
+    pushes = by_trace("ps.worker.push")
+    server_pushes = by_trace("ps.server.push")
     assert pushes and server_pushes
     for tid, worker_evs in pushes.items():
         assert tid in server_pushes, f"trace {tid} has no server-side span"
@@ -182,7 +182,7 @@ def test_flightrec_bundle_bridges_as_instants(mt, tmp_path):
     with open(trace, "w") as f:
         json.dump({
             "traceEvents": [
-                {"name": "kv.push", "ph": "X", "ts": 0.0, "dur": 10.0,
+                {"name": "ps.worker.push", "ph": "X", "ts": 0.0, "dur": 10.0,
                  "pid": 1, "tid": 1}
             ],
             "metadata": {"node": "W0", "clock_t0_s": 100.0},

@@ -10,6 +10,8 @@ import pathlib
 import sys
 import textwrap
 
+import pytest
+
 REPO = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO / "tools"))
 
@@ -459,3 +461,53 @@ def test_apply_event_taxonomy_stays_registered():
     missing = check_wrappers.REQUIRED_EVENTS - flightrec.EVENTS
     assert not missing, f"EVENTS lost required apply kinds: {sorted(missing)}"
     assert check_wrappers.main([]) == 0  # the repo itself stays clean
+
+
+def test_span_registry_loads_and_repo_span_sites_clean():
+    """Every literal ``span(...)`` name in the package starts with ``ps.``
+    and is in the SPANS registry (ISSUE 25 satellite), and the registry the
+    checker parses is the one the module exports."""
+    from parameter_server_tpu.utils import trace
+
+    spans = check_wrappers.load_span_registry(
+        REPO / "parameter_server_tpu" / check_wrappers.TRACE_MODULE
+    )
+    assert spans == trace.SPANS
+    assert all(s.startswith(check_wrappers.SPAN_PREFIX) for s in spans)
+    problems = []
+    for f in sorted((REPO / "parameter_server_tpu").rglob("*.py")):
+        problems.extend(check_wrappers.check_span_names(f, spans))
+    assert problems == [], "\n".join(problems)
+
+
+@pytest.mark.parametrize(
+    "call,bad",
+    [
+        ('self.tracer.span("ps.worker.pul", table=t)', True),  # a typo
+        ('self.tracer.span("kv.push")', True),  # no ps. prefix
+        ('span("ps.van.sent")', True),  # the module-level form
+        ('self.tracer.span("ps.worker.pull", table=t)', False),
+        ('span("ps.van.send", verb=v)', False),
+        ("m.span()", False),  # re.Match.span: no name to check
+        ("m.span(1)", False),
+    ],
+)
+def test_span_name_rule(tmp_path, call, bad):
+    src = tmp_path / "spans.py"
+    src.write_text(f"def f(self, span, m, t, v):\n    return {call}\n")
+    spans = frozenset({"ps.worker.pull", "ps.van.send"})
+    problems = check_wrappers.check_span_names(src, spans)
+    assert len(problems) == (1 if bad else 0)
+    if bad:
+        assert "SPANS" in problems[0]
+
+
+def test_span_registry_load_fails_loudly(tmp_path):
+    computed = tmp_path / "trace.py"
+    computed.write_text('SPANS = frozenset("ps." + n for n in ("a", "b"))\n')
+    with pytest.raises(ValueError):
+        check_wrappers.load_span_registry(computed)
+    missing = tmp_path / "moved.py"
+    missing.write_text("X = 1\n")
+    with pytest.raises(ValueError):
+        check_wrappers.load_span_registry(missing)

@@ -91,6 +91,16 @@ PR 6, nothing enforced:
    are pinned in :data:`REQUIRED_EVENTS` so a registry edit cannot
    silence the plane.
 
+10. **Span names come from the closed registry.**  ``utils/trace.py::span``
+   is the one way the package records a span (ISSUE 25), and the names are
+   what ``benchmarks/harness/program_spans.py`` and its per-layer metrics
+   read from the profiler's trace.  Every literal first argument of a
+   ``.span(...)`` / ``span(...)`` call under the package must start with
+   ``ps.`` and be present in ``utils/trace.py``'s ``SPANS`` frozenset
+   (``check_span_names``; registry parsed by AST via
+   ``load_span_registry``, same loud-failure stance as the event
+   registry).
+
 Pure-AST check (no imports of the checked modules), so it runs in any
 environment and is wired as a tier-1 test (``tests/test_wrapper_contract.py``).
 Exit code 0 = clean; 1 = violations (one line each).
@@ -123,6 +133,11 @@ NO_PICKLE_MODULES = (
 _PICKLE_NAMES = frozenset(
     {"pickle", "cPickle", "_pickle", "dill", "cloudpickle", "marshal"}
 )
+
+#: module holding the closed span-name registry (``SPANS`` frozenset
+#: literal), relative to the package root, and the prefix every name has.
+TRACE_MODULE = "utils/trace.py"
+SPAN_PREFIX = "ps."
 
 #: module holding the closed event-kind registry (``EVENTS`` frozenset
 #: literal), relative to the package root.
@@ -443,6 +458,44 @@ def load_event_registry(path: pathlib.Path) -> frozenset:
         path, tree, "EVENTS",
         "the flight-recorder kind registry moved; update FLIGHTREC_MODULE",
     )
+
+
+def load_span_registry(path: pathlib.Path) -> frozenset:
+    """Extract the ``SPANS`` frozenset literal from ``utils/trace.py``."""
+    tree = ast.parse(path.read_text(), filename=str(path))
+    return _parse_frozenset_literal(
+        path, tree, "SPANS",
+        "the span-name registry moved; update TRACE_MODULE",
+    )
+
+
+def check_span_names(path: pathlib.Path, spans: frozenset) -> List[str]:
+    """Flag ``.span("<name>", ...)`` / ``span("<name>", ...)`` calls whose
+    literal name lacks the ``ps.`` prefix or is absent from ``SPANS``.  A
+    non-literal first argument is not a recorder call this check can see
+    (``re.Match.span()`` takes none or an int) and is left alone."""
+    tree = ast.parse(path.read_text(), filename=str(path))
+    problems: List[str] = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call) or not node.args:
+            continue
+        f = node.func
+        if not (
+            (isinstance(f, ast.Attribute) and f.attr == "span")
+            or (isinstance(f, ast.Name) and f.id == "span")
+        ):
+            continue
+        arg = node.args[0]
+        if not (isinstance(arg, ast.Constant) and isinstance(arg.value, str)):
+            continue
+        if not arg.value.startswith(SPAN_PREFIX) or arg.value not in spans:
+            problems.append(
+                f"{_rel(path)}:{node.lineno}: span name {arg.value!r} "
+                f"must start with {SPAN_PREFIX!r} and be in the SPANS registry "
+                "(utils/trace.py) — add it there (and to PERF.md section 3) "
+                "or fix the typo; the trace's readers find spans by name"
+            )
+    return problems
 
 
 def load_verb_registry(path: pathlib.Path):
@@ -823,6 +876,11 @@ def main(argv: List[str]) -> int:
         )
         return 1
     try:
+        spans = load_span_registry(PKG / TRACE_MODULE)
+    except (OSError, ValueError) as e:
+        print(f"check_wrappers: span registry unreadable: {e}", file=sys.stderr)
+        return 1  # same loud-failure stance as the event registry
+    try:
         verbs, verb_names = load_verb_registry(PKG / MANAGER_MODULE)
     except (OSError, ValueError) as e:
         print(f"check_wrappers: verb registry unreadable: {e}", file=sys.stderr)
@@ -861,6 +919,7 @@ def main(argv: List[str]) -> int:
                 found_trace_gated += 1
                 problems.extend(check_trace_gated(f, TRACE_GATED_FUNCS[rel]))
             problems.extend(check_flightrec_calls(f, events))
+            problems.extend(check_span_names(f, spans))
             problems.extend(check_control_verbs(f, verbs, verb_names))
             text = f.read_text()
             if "VanWrapper" not in text:

@@ -5,8 +5,8 @@ Each node of a cluster run dumps its own timeline
 (``Tracer.dump_chrome_trace(path, process_name=node_id)``).  Loaded alone,
 those files are N disconnected views of one distributed request; merged,
 each node becomes a Perfetto *process* (pid = node index, named via
-``process_name`` metadata events), and the worker-side ``kv.push`` span
-lines up with the serving nodes' ``kv.server.push`` spans — both carry the
+``process_name`` metadata events), and the worker-side ``ps.worker.push``
+span lines up with the serving nodes' ``ps.server.push`` spans — both carry the
 same stitched trace id in ``args.trace`` (stamped into
 ``Task.payload["__trace__"]`` by ``KVWorker._trace_ctx`` and echoed by
 ``KVServer.handle_request``), so clicking one end finds the other.
