@@ -161,6 +161,7 @@ def save_shard(
 
     The trash row (last) is excluded — it is reconstructed on restore.
     """
+    value, state = table.host_planes()
     return save_arrays_shard(
         root,
         step,
@@ -168,8 +169,8 @@ def save_shard(
         server_index,
         num_servers,
         row_offset,
-        np.asarray(table.value)[: table.rows],
-        {k: np.asarray(v)[: table.rows] for k, v in table.state.items()},
+        value[: table.rows],
+        {k: v[: table.rows] for k, v in state.items()},
     )
 
 

@@ -1314,13 +1314,11 @@ class KVServer(Customer):
         — including optimizer accumulators, which the wire protocol never
         carries (only the chain forwarding replays them).
         """
-        return {
-            t: {
-                "value": np.asarray(table.value),
-                "state": {k: np.asarray(v) for k, v in table.state.items()},
-            }
-            for t, table in self.tables.items()
-        }
+        shard = {}
+        for t, table in self.tables.items():
+            value, state = table.host_planes()
+            shard[t] = {"value": value, "state": state}
+        return shard
 
     def import_shard(self, shard: Dict[str, dict]) -> None:
         """Adopt an :meth:`export_shard` snapshot wholesale.
@@ -1343,9 +1341,8 @@ class KVServer(Customer):
             raise ValueError(
                 f"export of un-owned rows of {table!r} on {self.post.node_id}"
             )
-        value = np.asarray(tbl.value)[local]
-        state = {k: np.asarray(v)[local] for k, v in tbl.state.items()}
-        return value, state
+        value, state = tbl.host_planes()
+        return value[local], {k: v[local] for k, v in state.items()}
 
     def export_range(
         self, table: str, lo: int, hi: int
@@ -1451,8 +1448,7 @@ class KVServer(Customer):
             np.concatenate(parts) if parts else np.empty(0, dtype=np.int64)
         )
         n = int(gids.shape[0])
-        old_v = np.asarray(tbl.value)
-        old_s = {k: np.asarray(v) for k, v in tbl.state.items()}
+        old_v, old_s = tbl.host_planes()
         value = np.empty((n + 1, tbl.dim), dtype=old_v.dtype)
         state = {
             k: np.empty((n + 1, tbl.dim), dtype=old_v.dtype) for k in old_s

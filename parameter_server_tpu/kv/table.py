@@ -3,9 +3,8 @@
 The TPU inversion of the reference server's storage (SURVEY.md §7): where the
 reference keeps a sorted key array + value array per channel and merges pushes
 with ``ParallelOrderedMatch`` (``src/parameter/kv_vector.h`` [U]), here the
-table is a fixed ``[rows + 1, dim]`` ``jax.Array`` in HBM (last row = trash
-row for padding), the host supplies dense unique row ids, and push/pull are
-jit-compiled steps:
+table is a fixed ``jax.Array`` in HBM (last row = trash row for padding), the
+host supplies dense unique row ids, and push/pull are jit-compiled steps:
 
 - ``push``: segment-combine duplicate positions -> gather value+state rows ->
   optimizer ``apply`` -> scatter rows back.  Buffers are donated, so the
@@ -14,12 +13,24 @@ jit-compiled steps:
 
 Shapes are bucket-padded by the host (``utils.keys``), so each table compiles
 one kernel per (bucket, batch) shape pair.
+
+**Plane shapes.**  ``value`` and every ``state[k]`` are ``[rows + 1, dim]`` on
+the device, except that a table of dim 1 holds flat ``[rows + 1]`` planes:
+the layout its gather and scatter work in, so that no program of the table
+passes over a whole plane (``ops/scatter.py``: a rank-1 plane is a dim-1
+table).  The form is read from ``cfg.dim`` here and from the plane's rank in
+the kernels; nothing selects it.  Rows cross every method as ``[n, dim]``
+(``pull`` of a dim-1 table returns ``[n, 1]``), and every host form
+(:meth:`host_planes`, :meth:`weights`, :meth:`set_value`,
+:meth:`install_rows`, :meth:`resize`, checkpoints, the server's hand-over) is
+``[rows(+1), dim]`` NumPy whatever the dim: the conversion is a host
+``reshape`` and lives in this class only.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,18 +72,18 @@ class KVTable:
         self.dim = cfg.dim
         dtype = jnp.dtype(cfg.dtype)
         self.optimizer: ServerOptimizer = make_optimizer(cfg.optimizer)
+        shape = self._plane_shape(self.rows)
         with jax.default_device(device):  # allocate in place, then commit
             if cfg.init_scale > 0.0:
+                # the draw depends on the element count alone, so a flat
+                # plane holds the numbers its [rows + 1, 1] form held
                 key = jax.random.PRNGKey(seed)
-                value = (
-                    jax.random.normal(key, (self.rows + 1, self.dim), dtype)
-                    * cfg.init_scale
-                )
+                value = jax.random.normal(key, shape, dtype) * cfg.init_scale
                 value = value.at[self.rows].set(0.0)
             else:
-                value = jnp.zeros((self.rows + 1, self.dim), dtype)
+                value = jnp.zeros(shape, dtype)
             state = {
-                name: jnp.full((self.rows + 1, self.dim), fill, dtype)
+                name: jnp.full(shape, fill, dtype)
                 for name, fill in self.optimizer.state_shapes().items()
             }
         self.value: jax.Array = self._place(value)
@@ -108,6 +119,11 @@ class KVTable:
             self._push_combined_impl, donate_argnums=(0, 1)
         )
 
+    def _plane_shape(self, rows: int) -> Tuple[int, ...]:
+        """Device shape of one plane of ``rows`` rows and the trash row: flat
+        for a dim-1 table (module docstring)."""
+        return (rows + 1,) if self.dim == 1 else (rows + 1, self.dim)
+
     def _place(self, x, dtype=None) -> jax.Array:
         """``x`` as an array on this table's device (committed if one is set)."""
         if dtype is not None and x.dtype != dtype:
@@ -115,6 +131,10 @@ class KVTable:
         if self.device is None:
             return jnp.asarray(x)
         return jax.device_put(x, self.device)
+
+    def _place_plane(self, x, dtype) -> jax.Array:
+        """The ``[rows + 1, dim]`` host form ``x`` as a plane on the device."""
+        return self._place(x.reshape(self._plane_shape(x.shape[0] - 1)), dtype)
 
     @property
     def nominal_bytes(self) -> int:
@@ -258,16 +278,26 @@ class KVTable:
         return self._pull_fn(self.value, self.state, ids)
 
     # -- direct row access (checkpoint, tests, model eval) ------------------
-    def weights(self) -> jax.Array:
-        """Full servable weight table (excluding the trash row)."""
-        return self.optimizer.pull_weights(self.value, self.state)[: self.rows]
+    def host_planes(self) -> Tuple[np.ndarray, Dict[str, np.ndarray]]:
+        """``(value, state)`` as ``[rows + 1, dim]`` NumPy arrays, trash row
+        last: the host form of the shard, whatever the planes' device shape."""
+        shape = (self.rows + 1, self.dim)
+        return np.asarray(self.value).reshape(shape), {
+            k: np.asarray(v).reshape(shape) for k, v in self.state.items()
+        }
+
+    def weights(self) -> np.ndarray:
+        """Full servable weight table (excluding the trash row), on the host
+        as ``[rows, dim]``."""
+        w = self.optimizer.pull_weights(self.value, self.state)
+        return np.asarray(w).reshape(self.rows + 1, self.dim)[: self.rows]
 
     def set_value(self, value: np.ndarray | jax.Array) -> None:
         if value.shape != (self.rows + 1, self.dim):
             raise ValueError(
                 f"expected {(self.rows + 1, self.dim)}, got {value.shape}"
             )
-        self.value = self._place(value, self.value.dtype)
+        self.value = self._place_plane(value, self.value.dtype)
 
     def install_rows(
         self, value: np.ndarray, state: Dict[str, np.ndarray]
@@ -313,8 +343,8 @@ class KVTable:
             )
         dtype = self.value.dtype
         self.rows = int(value.shape[0]) - 1
-        self.value = self._place(value, dtype)
-        self.state = {k: self._place(v, dtype) for k, v in state.items()}
+        self.value = self._place_plane(value, dtype)
+        self.state = {k: self._place_plane(v, dtype) for k, v in state.items()}
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows",))

@@ -80,7 +80,7 @@ class LocalFMTrainer:
             self.dashboard.record(self.step_count, loss, examples=labels.shape[0])
 
     def eval_auc(self, batch_fn, num_batches: int) -> float:
-        weights = np.asarray(self.table.weights())
+        weights = self.table.weights()
         bias = float(
             np.asarray(self.optimizer.pull_weights(self.bias, self.bias_state))[0, 0]
         )

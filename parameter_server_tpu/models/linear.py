@@ -77,7 +77,8 @@ def fused_train_step(
     """One full LR step on the device-resident table.
 
     Args:
-      value/state: the table arrays (donated, updated in place).
+      value/state: the table planes, flat ``[rows + 1]`` (``KVTable``'s for
+        dim 1) or ``[rows + 1, 1]`` (donated, updated in place).
       bias/bias_state: scalar bias row ``[1, 1]`` and its optimizer state.
       ids: unique row slots ``[num_rows]`` (bucket-padded, pads -> trash row).
       inverse: position -> slot-row map ``[B * nnz]``.
@@ -144,14 +145,18 @@ def dense_fused_impl(
     (Criteo LR at 2^25 rows x 4B = 128 MB -> ~0.2 ms at v5e bandwidth).
     """
     batch = labels.shape[0]
+    # a rank-1 plane is a dim-1 table (KVTable's); ``[N, 1]`` planes
+    # (parallel/lr_spmd.py's own) index their one column
+    pos = slots_pos.reshape(-1)
+    idx = pos if value.ndim == 1 else (pos, 0)
     w_table = optimizer.pull_weights(value, state)  # elementwise transform
-    w_pos = w_table[slots_pos.reshape(-1), 0].reshape(batch, -1)
+    w_pos = w_table[idx].reshape(batch, -1)
     bias_w = optimizer.pull_weights(bias, bias_state)
     logits = predict_logits(w_pos, bias_w[0, 0])
     loss = logloss(logits, labels)
     residual = (jax.nn.sigmoid(logits) - labels) / batch
     g_pos = jnp.broadcast_to(residual[:, None], w_pos.shape).reshape(-1)
-    grad_buf = jnp.zeros_like(value).at[slots_pos.reshape(-1), 0].add(g_pos)
+    grad_buf = jnp.zeros_like(value).at[idx].add(g_pos)
     # drop PAD contributions; trash_row is the PAD slot of the localizer
     # (== capacity); -1 only coincides with it for unpadded [rows+1] tables
     grad_buf = grad_buf.at[trash_row].set(0.0)

@@ -7,6 +7,18 @@ public layout]).  The host has already localized global keys to dense row ids
 (:mod:`parameter_server_tpu.utils.keys`), so the device only sees fixed-shape
 ``int32`` row-id vectors.
 
+**Plane shapes.**  A table plane (the value, or one optimizer-state array) is
+``[rows + 1, dim]`` with the trash row last, EXCEPT that **a rank-1 plane is
+a dim-1 table**: ``[rows + 1]``.  On the TPU an ``[N, 1]`` array lives in the
+``T(1,128)`` layout while gather and scatter work on the flat ``T(1024)``
+one, so every program over an ``[N, 1]`` plane began and ended with passes
+over the whole plane (``PERF.md`` section 6, PR 26); a flat plane is in the
+layout the gather and the scatter work in.  The XLA entry points below take
+either and read the form from the plane's rank; rows cross every interface
+as ``[n, dim]`` (``[n, 1]`` for a flat plane), so the only reshapes are of
+``n``-row operands, never of a plane.  The Pallas kernels need ``dim == 128``
+or ``dim % 1024 == 0`` and refuse a flat plane like any other dim-1 table.
+
 Two implementations:
 
 - **XLA** (default): ``jnp.take`` / ``.at[].add``.  Differentiable, handles
@@ -75,15 +87,23 @@ def segment_combine(values: jax.Array, inverse: jax.Array, num_rows: int) -> jax
 
 
 def gather_rows_xla(table: jax.Array, ids: jax.Array) -> jax.Array:
-    return jnp.take(table, ids, axis=0)
+    """``[n, dim]`` rows of ``table`` (``[n, 1]`` of a flat plane)."""
+    rows = jnp.take(table, ids, axis=0)
+    return rows[:, None] if table.ndim == 1 else rows
+
+
+def _rows_for(table: jax.Array, ids: jax.Array, rows: jax.Array) -> jax.Array:
+    """``[n, dim]`` rows in the form ``table``'s scatter takes: ``[n]`` for a
+    flat plane (a reshape of ``n`` elements, refused unless ``dim == 1``)."""
+    return rows.reshape(ids.shape) if table.ndim == 1 else rows
 
 
 def scatter_add_rows_xla(table: jax.Array, ids: jax.Array, rows: jax.Array) -> jax.Array:
-    return table.at[ids].add(rows)
+    return table.at[ids].add(_rows_for(table, ids, rows))
 
 
 def scatter_update_rows_xla(table: jax.Array, ids: jax.Array, rows: jax.Array) -> jax.Array:
-    return table.at[ids].set(rows)
+    return table.at[ids].set(_rows_for(table, ids, rows))
 
 
 # ---------------------------------------------------------------------------
@@ -143,12 +163,13 @@ def _chunks(dim: int) -> int:
 
 
 def _check_pallas_args(table: jax.Array, ids: jax.Array) -> None:
-    if table.ndim != 2 or table.dtype != jnp.float32:
+    if table.ndim not in (1, 2) or table.dtype != jnp.float32:
         raise ValueError(
             f"pallas path requires a 2-D float32 table, got "
             f"{table.shape} {table.dtype}; use impl='xla'"
         )
-    _chunks(table.shape[1])
+    # a flat plane is a dim-1 table: refused by its dim like any other
+    _chunks(1 if table.ndim == 1 else table.shape[1])
 
 
 def _copy_rows(src_ref, src_row, dst_ref, dst_row, sem, c):

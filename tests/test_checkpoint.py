@@ -308,3 +308,52 @@ def test_eval_reconstructs_manifest_localizer(tmp_path):
         assert bad["auc"] < good["auc"]
     finally:
         van.close()
+
+
+def test_dim1_checkpoint_arrays_keep_their_shapes(tmp_path):
+    """A dim-1 table holds flat planes on the device (PR 26) and its
+    checkpoint is what it was: ``[rows, 1]`` arrays, no trash row.  So a
+    checkpoint written before the planes were flat restores now, and one
+    written now restores there."""
+    van = LoopbackVan()
+    try:
+        cfgs = _cfgs(rows=600, dim=1)
+        loc = {"w": HashLocalizer(600)}
+        servers, worker = _cluster(van, cfgs, 2, localizers=loc)
+        keys = np.arange(0, 64, dtype=np.uint64) * 7919
+        grads = np.random.RandomState(0).randn(64, 1).astype(np.float32)
+        worker.wait(worker.push("w", keys, grads), timeout=10)
+        before = worker.pull_sync("w", keys, timeout=10)
+        assert np.abs(before).max() > 0
+        planes = [s.tables["w"].host_planes() for s in servers]
+        worker.save_model(str(tmp_path), step=1)
+    finally:
+        van.close()
+    for i, (value, state) in enumerate(planes):
+        saved = checkpoint.load_arrays_shard(str(tmp_path), 1, "w", i, 2)
+        assert saved["value"].shape == (300, 1)
+        assert saved["state.sum_sq"].shape == (300, 1)
+        np.testing.assert_array_equal(saved["value"], value[:300])
+        np.testing.assert_array_equal(saved["state.sum_sq"], state["sum_sq"][:300])
+
+    # the shard files as a writer with [rows + 1, 1] planes made them
+    old = tmp_path / "old"
+    for i, (value, state) in enumerate(planes):
+        checkpoint.save_arrays_shard(
+            str(old), 2, "w", i, 2, 300 * i, value[:300],
+            {k: v[:300] for k, v in state.items()},
+        )
+    checkpoint.finalize(str(old), 2, 2, {"w": 600})
+    for root, step in ((str(tmp_path), 1), (str(old), 2)):
+        van2 = LoopbackVan()
+        try:
+            servers2, worker2 = _cluster(van2, cfgs, 3, localizers=loc)
+            worker2.load_model(root, step=step)
+            for s in servers2:
+                t = s.tables["w"]
+                assert t.value.shape == (t.rows + 1,)  # flat on the device
+                assert t.state["sum_sq"].shape == (t.rows + 1,)
+            after = worker2.pull_sync("w", keys, timeout=10)
+            np.testing.assert_array_equal(after, before)
+        finally:
+            van2.close()

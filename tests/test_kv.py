@@ -1,6 +1,9 @@
+import re
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from parameter_server_tpu.config import OptimizerConfig, TableConfig
@@ -198,3 +201,241 @@ def test_worker_multi_worker_consistency(cluster):
     worker.wait(ts, timeout=10)
     w = worker2.pull_sync("w", keys, timeout=10)
     np.testing.assert_allclose(w[0], -3.0, rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# The layout rule (PR 26): a table of dim 1 holds flat [rows + 1] planes, every
+# other table [rows + 1, dim]; rows cross every method as [n, dim]; host forms
+# are [rows(+1), dim] NumPy whatever the dim.
+# ---------------------------------------------------------------------------
+
+_KINDS = ["sgd", "adagrad", "adam", "ftrl"]
+_D1_ROWS, _D1_N, _D1_REAL = 50, 16, 11
+
+
+def _dim1_table(kind, fused=True, **kw):
+    return KVTable(
+        TableConfig(
+            name="w", rows=_D1_ROWS, dim=1, fused_apply=fused,
+            optimizer=OptimizerConfig(kind=kind, learning_rate=0.1), **kw,
+        ),
+        seed=5,
+    )
+
+
+def _padded_ids(rng):
+    """Unique row ids, the bucket's tail padded with the trash row."""
+    ids = np.full(_D1_N, _D1_ROWS, np.int32)
+    ids[:_D1_REAL] = rng.permutation(_D1_ROWS)[:_D1_REAL]
+    return ids
+
+
+def _column_apply(opt):
+    """``(apply, pull)`` on [rows + 1, 1] arrays through the XLA entry points,
+    the apply with its trash reset: what a dim-1 table ran before its planes
+    were flat."""
+    from parameter_server_tpu.ops import scatter
+
+    def step(value, state, ids, grads):
+        value, state = scatter._apply_rows_xla(
+            value, state, ids, grads, opt.apply
+        )
+        fills = opt.state_shapes()
+        return value.at[-1].set(0.0), {
+            k: state[k].at[-1].set(fills[k]) for k in state
+        }
+
+    def pull(value, state, ids):
+        return opt.pull_weights(
+            scatter.gather_rows_xla(value, ids),
+            {k: scatter.gather_rows_xla(v, ids) for k, v in state.items()},
+        )
+
+    return jax.jit(step), jax.jit(pull)
+
+
+@pytest.mark.parametrize("op", ["push", "push_batch", "push_combined"])
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "threepass"])
+@pytest.mark.parametrize("kind", _KINDS)
+def test_dim1_table_matches_column_planes_bitwise(kind, fused, op):
+    from parameter_server_tpu.ops import scatter
+
+    t = _dim1_table(kind, fused, init_scale=0.1)
+    opt, fills = t.optimizer, t.optimizer.state_shapes()
+    assert t.value.shape == (_D1_ROWS + 1,)
+    assert all(s.shape == (_D1_ROWS + 1,) for s in t.state.values())
+    ref_v = jnp.asarray(np.asarray(t.value)[:, None])
+    ref_s = {k: jnp.asarray(np.asarray(v)[:, None]) for k, v in t.state.items()}
+    column_apply, column_pull = _column_apply(opt)
+    rng = np.random.default_rng(11)
+    for _ in range(2):
+        ids = _padded_ids(rng)
+        if op == "push":
+            # pads carry REAL gradients: the trash reset has work to do
+            grads = rng.normal(size=(_D1_N, 1)).astype(np.float32)
+            t.push(jnp.asarray(ids), jnp.asarray(grads))
+        else:
+            vals = rng.normal(size=(2, _D1_N // 2, 1)).astype(np.float32)
+            flat = vals.reshape(-1, 1)
+            if op == "push_batch":
+                positions = np.full(_D1_N, _D1_N, np.int32)  # the zero row
+                positions[:_D1_REAL] = rng.permutation(_D1_N)[:_D1_REAL]
+                grads = np.concatenate([flat, np.zeros((1, 1), np.float32)])[
+                    positions
+                ]
+                t.push_batch(
+                    jnp.asarray(ids), jnp.asarray(positions), jnp.asarray(vals)
+                )
+            else:
+                inverse = rng.integers(0, _D1_REAL, _D1_N).astype(np.int32)
+                grads = np.asarray(
+                    scatter.segment_combine(jnp.asarray(flat), inverse, _D1_N)
+                )
+                t.push_combined(
+                    jnp.asarray(ids), jnp.asarray(inverse), jnp.asarray(vals)
+                )
+        ref_v, ref_s = column_apply(ref_v, ref_s, ids, jnp.asarray(grads))
+        assert t.value.shape == (_D1_ROWS + 1,)
+        np.testing.assert_array_equal(np.asarray(t.value), np.asarray(ref_v)[:, 0])
+        for k in fills:
+            assert t.state[k].shape == (_D1_ROWS + 1,)
+            np.testing.assert_array_equal(
+                np.asarray(t.state[k]), np.asarray(ref_s[k])[:, 0]
+            )
+            assert float(t.state[k][-1]) == fills[k]  # trash row reset
+        assert float(t.value[-1]) == 0.0
+        pulled = t.pull(jnp.asarray(ids))
+        assert pulled.shape == (_D1_N, 1)
+        want = column_pull(ref_v, ref_s, ids)
+        np.testing.assert_array_equal(np.asarray(pulled), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(pulled)[_D1_REAL:], 0.0)
+    assert not np.array_equal(np.asarray(t.value)[:-1], 0.0)
+
+
+@pytest.mark.parametrize("dim", [1, 3, 16, 128])
+def test_plane_rank_follows_dim(dim):
+    t = KVTable(
+        TableConfig(
+            name="w", rows=24, dim=dim,
+            optimizer=OptimizerConfig(kind="adam"),
+        )
+    )
+    want = (25,) if dim == 1 else (25, dim)
+    assert t.value.shape == want
+    assert {k: v.shape for k, v in t.state.items()} == dict.fromkeys("mvt", want)
+    assert t.nominal_bytes == 25 * dim * 4 * 4
+    ids = jnp.asarray([3, 7, 24, 24], dtype=jnp.int32)
+    t.push(ids, jnp.ones((4, dim), jnp.float32))
+    assert t.value.shape == want  # the apply keeps the form it was given
+    assert t.pull(ids).shape == (4, dim)
+    value, state = t.host_planes()
+    assert value.shape == (25, dim) and isinstance(value, np.ndarray)
+    assert all(v.shape == (25, dim) for v in state.values())
+    assert t.weights().shape == (24, dim)
+
+
+@pytest.mark.parametrize("dim", [1, 3])
+def test_init_scale_draws_the_rows_it_drew(dim):
+    """The flat plane of a dim-1 table holds the numbers its [rows + 1, 1]
+    form held: restarts and reference runs see the same initial rows."""
+    t = KVTable(TableConfig(name="w", rows=100, dim=dim, init_scale=0.1), seed=7)
+    want = np.array(
+        jax.random.normal(jax.random.PRNGKey(7), (101, dim), jnp.float32) * 0.1
+    )
+    want[100] = 0.0
+    value, _ = t.host_planes()
+    np.testing.assert_array_equal(value, want)
+    assert np.abs(value[:100]).min() > 0.0
+
+
+def _plane_sized_relayouts(text, n):
+    """Lines of a lowered program that reshape, reduce, broadcast or
+    transpose something of ``n`` elements."""
+    bad = []
+    for line in text.splitlines():
+        if not re.search(
+            r"stablehlo\.(reshape|reduce|broadcast|broadcast_in_dim|"
+            r"transpose|dynamic_reshape)\b",
+            line,
+        ):
+            continue
+        for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]\w*>", line):
+            if np.prod([int(d) for d in dims.split("x") if d]) == n:
+                bad.append(line.strip())
+                break
+    return bad
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "threepass"])
+@pytest.mark.parametrize("kind", ["adagrad", "ftrl"])
+@pytest.mark.parametrize("prog", ["push", "push_batch", "push_combined", "pull"])
+def test_dim1_programs_hold_no_pass_over_a_plane(prog, kind, fused):
+    """What keeps a later edit from bringing the relayout back unseen on the
+    CPU: the programs of a dim-1 table take rank-1 planes, donate them to
+    the scatter, and no reshape, reduce, broadcast or transpose in them has
+    an operand or a result of ``rows + 1`` elements (``PERF.md`` section 6,
+    PR 26: on the TPU an ``[N, 1]`` plane cost two to five passes over 2 GiB
+    in every program)."""
+    rows, n = 1000, 64  # 1001 = 7 x 11 x 13: no n-row operand has that size
+    t = KVTable(
+        TableConfig(
+            name="w", rows=rows, dim=1, fused_apply=fused,
+            optimizer=OptimizerConfig(kind=kind),
+        )
+    )
+    ids = jnp.zeros((n,), jnp.int32)
+    vals = jnp.zeros((2, n // 2, 1), jnp.float32)
+    fn, args = {
+        "push": (t._push_fn, (ids, jnp.zeros((n, 1), jnp.float32))),
+        "push_batch": (t._push_batch_fn, (ids, ids, vals)),
+        "push_combined": (t._push_combined_fn, (ids, ids, vals)),
+        "pull": (t._pull_fn, (ids,)),
+    }[prog]
+    text = fn.lower(t.value, t.state, *args).as_text()
+    assert _plane_sized_relayouts(text, rows + 1) == []
+    main = re.search(r"func\.func public @main\((.*?)\) ->", text).group(1)
+    planes = re.findall(r"(%arg\d+): tensor<([\dx]+)xf32>( \{[^}]*\})?", main)
+    flat = [p for p in planes if p[1] == str(rows + 1)]
+    # the value plane and, where the program reads it, the state plane
+    assert len(flat) >= 1 and not re.search(rf"tensor<{rows + 1}x1xf32>", text)
+    if prog != "pull":
+        assert len(flat) == 1 + len(t.state)
+        assert all("tf.aliasing_output" in p[2] for p in flat), main
+    # the checker sees a plane-sized reshape where there is one
+    col = jnp.zeros((rows + 1, 1), jnp.float32)
+    bad = jax.jit(lambda v: v.reshape(-1)).lower(col).as_text()
+    assert _plane_sized_relayouts(bad, rows + 1)
+
+
+def test_dim1_host_forms_round_trip():
+    """``set_value``, ``install_rows``, ``resize``, ``host_planes`` and
+    ``weights`` give and take ``[rows(+1), 1]`` NumPy arrays; the planes on
+    the device stay flat through all of them."""
+    t = _dim1_table("adagrad")
+    rng = np.random.default_rng(3)
+    buf = rng.normal(size=(_D1_ROWS + 1, 1)).astype(np.float32)
+    t.set_value(buf)
+    assert t.value.shape == (_D1_ROWS + 1,)
+    np.testing.assert_array_equal(t.host_planes()[0], buf)
+    with pytest.raises(ValueError, match="expected"):
+        t.set_value(buf[:, 0])  # the host form is [rows + 1, dim], not flat
+    rows = rng.normal(size=(30, 1)).astype(np.float32)
+    acc = rng.uniform(size=(30, 1)).astype(np.float32)
+    t.install_rows(rows, {"sum_sq": acc})  # another row count, no trash row
+    assert t.rows == 30 and t.value.shape == (31,)
+    assert t.state["sum_sq"].shape == (31,)
+    value, state = t.host_planes()
+    np.testing.assert_array_equal(value[:30], rows)
+    np.testing.assert_array_equal(state["sum_sq"][:30], acc)
+    assert value[30, 0] == 0.0 and state["sum_sq"][30, 0] == 0.0
+    np.testing.assert_array_equal(t.weights(), rows)
+    grown = np.concatenate([value, value])  # [62, 1], trash row included
+    t.resize(grown, {"sum_sq": np.concatenate([state["sum_sq"]] * 2)})
+    assert t.rows == 61 and t.value.shape == (62,)
+    np.testing.assert_array_equal(t.host_planes()[0], grown)
+    with pytest.raises(ValueError, match="bad resize value shape"):
+        t.resize(grown[:, 0], {"sum_sq": grown[:, 0]})
+    # the resized shard still applies and serves
+    ids = jnp.asarray([0, 60, 61, 61], dtype=jnp.int32)
+    t.push(ids, jnp.ones((4, 1), jnp.float32))
+    assert t.pull(ids).shape == (4, 1) and t.value.shape == (62,)

@@ -127,3 +127,64 @@ def test_bsp_matches_single_process_reference():
     # the van path has no bias term; losses still must track closely since
     # bias-free gradients dominate — compare weight-driven loss decrease
     np.testing.assert_allclose(van_losses, local_losses, atol=0.05)
+
+
+@pytest.mark.parametrize("mode", ["rows", "dense", "dense_block"])
+def test_local_trainer_steps_flat_planes_like_column_planes(mode):
+    """``LocalLRTrainer`` hands ``KVTable``'s planes to ``models/linear.py``'s
+    own jitted steps.  A dim-1 table's planes are flat (PR 26): every step
+    keeps them flat, and gives the loss and the rows the same step gives on
+    ``[rows + 1, 1]`` planes (``parallel/lr_spmd.py`` still holds such)."""
+    import jax.numpy as jnp
+
+    from parameter_server_tpu.models import linear
+    from parameter_server_tpu.utils.keys import localize_to_slots
+
+    rows = 2048
+    cfg = _table_cfg(rows=rows)
+    tr = LocalLRTrainer(
+        cfg, mode="rows" if mode == "rows" else "dense",
+        device_hash=mode == "dense_block",
+    )
+    t, opt = tr.table, tr.optimizer
+    assert t.value.shape == (rows + 1,)
+    col_v = jnp.zeros((rows + 1, 1), jnp.float32)
+    col_s = {"sum_sq": jnp.zeros((rows + 1, 1), jnp.float32)}
+    bias = jnp.zeros((1, 1), jnp.float32)
+    bias_s = {"sum_sq": jnp.zeros((1, 1), jnp.float32)}
+    rng = np.random.default_rng(2)
+    keys = rng.integers(0, 1 << 20, size=(2, 64, 8), dtype=np.uint64)
+    labels = rng.integers(0, 2, size=(2, 64)).astype(np.float32)
+    if mode == "dense_block":
+        losses = np.asarray(tr.step_block(keys, labels))
+        col_v, col_s, bias, bias_s, want = linear.dense_scan_train_step(
+            col_v, col_s, bias, bias_s, jnp.asarray(keys.astype(np.uint32)),
+            jnp.asarray(labels), opt, rows, tr.localizer.seed,
+        )
+    else:
+        losses, want = [], []
+        for k, y in zip(keys, labels):
+            losses.append(tr.step(k, y))
+            if mode == "dense":
+                col_v, col_s, bias, bias_s, loss = linear.dense_fused_train_step(
+                    col_v, col_s, bias, bias_s,
+                    jnp.asarray(tr.localizer.assign(k)), jnp.asarray(y), opt, rows,
+                )
+            else:
+                slots, inverse, _n = localize_to_slots(
+                    k, tr.localizer, min_bucket=tr.min_bucket
+                )
+                col_v, col_s, bias, bias_s, loss = linear.fused_train_step(
+                    col_v, col_s, bias, bias_s, jnp.asarray(slots),
+                    jnp.asarray(inverse), jnp.asarray(y), opt, slots.shape[0],
+                )
+            want.append(float(loss))
+    np.testing.assert_allclose(losses, np.asarray(want), rtol=1e-6)
+    assert t.value.shape == (rows + 1,) and t.state["sum_sq"].shape == (rows + 1,)
+    assert col_v.shape == (rows + 1, 1)
+    value, state = t.host_planes()
+    np.testing.assert_allclose(value, np.asarray(col_v), rtol=1e-6, atol=1e-8)
+    np.testing.assert_allclose(
+        state["sum_sq"], np.asarray(col_s["sum_sq"]), rtol=1e-6, atol=1e-8
+    )
+    assert np.abs(value).max() > 0 and value[-1, 0] == 0.0

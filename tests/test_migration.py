@@ -581,3 +581,64 @@ def test_migration_counters_merge_into_dashboard_group():
         assert got["migration_freeze_s"] > 0.0
     finally:
         van.close()
+
+
+# ------------------------- 7. hand-over of a dim-1 shard keeps its host forms
+
+
+def test_dim1_hand_over_gives_and_takes_column_rows():
+    """The table of this file is dim 1, so its planes are flat on the device
+    (PR 26); the snapshot, the row hand-over and the migration still give and
+    take ``[rows(+1), 1]`` NumPy arrays, and the planes stay flat through a
+    live migration that resizes both shards."""
+    van = LoopbackVan()
+    try:
+        servers = {
+            s: KVServer(Postoffice(f"S{s}", van), _table_cfgs(), s, NUM_SERVERS)
+            for s in range(NUM_SERVERS)
+        }
+        worker = KVWorker(Postoffice("W0", van), _table_cfgs(), NUM_SERVERS)
+        _train(worker, _batches()[:3])
+
+        def flat(srv):
+            t = srv.tables["w"]
+            return [t.value.shape, t.state["sum_sq"].shape] == [(t.rows + 1,)] * 2
+
+        assert all(flat(s) for s in servers.values())
+        snap = servers[1].export_shard()["w"]
+        assert snap["value"].shape == (513, 1)
+        assert snap["state"]["sum_sq"].shape == (513, 1)
+        v, st = servers[1].export_range("w", 600, 640)
+        assert v.shape == (40, 1) and st["sum_sq"].shape == (40, 1)
+        np.testing.assert_array_equal(v, snap["value"][600 - 512 : 640 - 512])
+        assert np.abs(snap["value"]).max() > 0 and snap["state"]["sum_sq"].max() > 0
+
+        # a twin adopts the snapshot wholesale and is bit-identical, flat
+        van2 = LoopbackVan()
+        try:
+            twin = KVServer(Postoffice("S1", van2), _table_cfgs(), 1, NUM_SERVERS)
+            twin.import_shard({"w": snap})
+            assert flat(twin)
+            again = twin.export_shard()["w"]
+            np.testing.assert_array_equal(again["value"], snap["value"])
+            np.testing.assert_array_equal(
+                again["state"]["sum_sq"], snap["state"]["sum_sq"]
+            )
+        finally:
+            van2.close()
+
+        before, before_s = _assemble(worker.routing, servers)
+        assert before.shape == (ROWS, 1)
+        mig = ShardMigrator(Postoffice("M0", van), chunk_rows=128)
+        routing = mig.migrate(worker.routing, "w", 768, ROWS, 0)
+        assert worker.adopt_routing(routing)
+        assert servers[0].tables["w"].rows == 768
+        assert servers[1].tables["w"].rows == 256
+        assert all(flat(s) for s in servers.values())
+        after, after_s = _assemble(routing, servers)
+        np.testing.assert_array_equal(after, before)
+        np.testing.assert_array_equal(after_s["sum_sq"], before_s["sum_sq"])
+        _train(worker, _batches()[3:5])  # the resized shards apply and serve
+        assert all(flat(s) for s in servers.values())
+    finally:
+        van.close()

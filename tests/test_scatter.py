@@ -252,3 +252,109 @@ def test_pallas_rejects_unsupported_dim():
     # and auto mode silently falls back to XLA
     out = scatter.gather_rows(t, jnp.arange(8, dtype=jnp.int32), impl="auto")
     assert out.shape == (8, 256)
+
+
+# ---------------------------------------------------------------------------
+# A rank-1 plane is a dim-1 table (PR 26): the XLA entry points take a flat
+# ``[rows + 1]`` plane and give and take ``[n, 1]`` rows, bit for bit what
+# they do on the ``[rows + 1, 1]`` column form.
+# ---------------------------------------------------------------------------
+
+_FLAT_ROWS = 40
+
+
+def _flat_case(seed=0):
+    """(flat plane, its column form, unique ids with pads at the trash row,
+    [n, 1] rows whose pad rows are zero)."""
+    rng = np.random.default_rng(seed)
+    plane = rng.normal(size=_FLAT_ROWS + 1).astype(np.float32)
+    plane[-1] = 0.0
+    ids = np.full(16, _FLAT_ROWS, np.int32)
+    ids[:11] = rng.permutation(_FLAT_ROWS)[:11]
+    rows = rng.normal(size=(16, 1)).astype(np.float32)
+    rows[11:] = 0.0
+    return (
+        jnp.asarray(plane), jnp.asarray(plane[:, None]), jnp.asarray(ids),
+        jnp.asarray(rows),
+    )
+
+
+@pytest.mark.parametrize("op", ["gather", "scatter_update", "scatter_add"])
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+def test_flat_plane_matches_column_plane_bitwise(op, jit):
+    flat, col, ids, rows = _flat_case()
+    if op == "gather":
+        fn, args = scatter.gather_rows, ()
+    else:
+        fn = {
+            "scatter_update": scatter.scatter_update_rows,
+            "scatter_add": scatter.scatter_add_rows,
+        }[op]
+        args = (rows,)
+    if jit:
+        fn = jax.jit(fn)
+    got, want = fn(flat, ids, *args), fn(col, ids, *args)
+    if op == "gather":
+        assert got.shape == (16, 1)  # rows cross the interface as [n, dim]
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        assert got.shape == (_FLAT_ROWS + 1,)  # the plane keeps its form
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want)[:, 0])
+
+
+@pytest.mark.parametrize("kind", ["sgd", "adagrad", "adam", "ftrl"])
+@pytest.mark.parametrize("jit,l2", [(False, 0.01), (True, 0.0)], ids=["eager-l2", "jit"])
+def test_apply_rows_flat_plane_matches_column_plane_bitwise(kind, jit, l2):
+    """Same gathers, same row function on ``[n, 1]`` blocks, same
+    write-backs.  Op by op (eager) that is bit for bit under every rule,
+    penalties included; under ``jit`` the CPU compiler contracts
+    ``grad + l2 * value`` into the next product differently in an ``[n]`` and
+    an ``[n, 1]`` fusion (AdaGrad's ``sum_sq``, one ulp in one row), so the
+    compiled case runs the rules without the penalty."""
+    from parameter_server_tpu.config import OptimizerConfig
+    from parameter_server_tpu.kv.optim import make_optimizer
+
+    opt = make_optimizer(OptimizerConfig(kind=kind, learning_rate=0.1, l2=l2))
+    flat, col, ids, grads = _flat_case(seed=1)
+    fills = opt.state_shapes()
+    apply = scatter.apply_rows
+    if jit:
+        apply = jax.jit(apply, static_argnames=("row_fn",))
+    fv, fs = flat, {k: jnp.full_like(flat, f) for k, f in fills.items()}
+    cv, cs = col, {k: jnp.full_like(col, f) for k, f in fills.items()}
+    for _ in range(3):  # state planes move too
+        fv, fs = apply(fv, fs, ids, grads, row_fn=opt.apply)
+        cv, cs = apply(cv, cs, ids, grads, row_fn=opt.apply)
+    assert fv.shape == (_FLAT_ROWS + 1,) and cv.shape == (_FLAT_ROWS + 1, 1)
+    np.testing.assert_array_equal(np.asarray(fv), np.asarray(cv)[:, 0])
+    for k in fills:
+        assert fs[k].shape == (_FLAT_ROWS + 1,)
+        np.testing.assert_array_equal(np.asarray(fs[k]), np.asarray(cs[k])[:, 0])
+    assert not np.array_equal(np.asarray(fv), np.asarray(flat))
+
+
+def test_flat_plane_refuses_rows_wider_than_one():
+    flat, _col, ids, _rows = _flat_case()
+    with pytest.raises(TypeError, match="reshape"):
+        scatter.scatter_update_rows(flat, ids, jnp.ones((16, 2)))
+
+
+@pytest.mark.parametrize(
+    "op", ["gather", "scatter_update", "scatter_add", "apply"]
+)
+def test_pallas_refuses_flat_plane_by_its_dim(op):
+    """``scatter_impl="pallas"`` refused dim 1 as ``[N, 1]``; the flat plane
+    is refused for the same reason, in the same words."""
+    flat, _col, ids, rows = _flat_case()
+    with pytest.raises(ValueError, match=r"dim == 128 or dim % 1024 == 0, got 1"):
+        if op == "gather":
+            scatter.gather_rows(flat, ids, impl="pallas", interpret=True)
+        elif op == "apply":
+            scatter.apply_rows(
+                flat, {}, ids, rows, lambda v, s, g: (v - g, s),
+                impl="pallas", interpret=True,
+            )
+        else:
+            getattr(scatter, op + "_rows")(
+                flat, ids, rows, impl="pallas", interpret=True
+            )
