@@ -105,9 +105,30 @@ def resolve(bench: dict, workload: str, *, seed: int, seconds: float,
     )
 
 
+def reports(bench: dict, cell: str, metric: str) -> bool:
+    """Whether ``cell`` reports the end-to-end metric ``metric``: an entry
+    without ``workloads`` is every cell's, one with the key only theirs."""
+    return any(
+        e["name"] == metric and ("workloads" not in e or cell in e["workloads"])
+        for e in bench["end_to_end"]
+    )
+
+
 def layer_metrics_for(run: Run) -> list:
-    """The per-layer entries of ``BENCHMARK.json`` this cell reports."""
+    """The per-layer entries of ``BENCHMARK.json`` this cell reports: those
+    that list it under ``workloads``, and of those without the key the ones
+    whose ``moves`` is an end-to-end metric the cell reports."""
     return [
         m for m in run.bench["per_layer"]
-        if "workloads" not in m or run.name in m["workloads"]
+        if (run.name in m["workloads"] if "workloads" in m
+            else reports(run.bench, run.name, m["moves"]))
     ]
+
+
+def base_reader(path: str):
+    """The reader ``<metric>.py`` beside ``<metric>.<split>.py``: a quantity
+    split because its cells report different end-to-end metrics (its
+    ``moves`` differs) is read by one piece of code."""
+    here, name = os.path.split(os.path.abspath(path))
+    base = name[: -len(".py")].rsplit(".", 1)[0]
+    return load_module(os.path.basename(here), base, os.path.dirname(here))

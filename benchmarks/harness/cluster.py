@@ -3,7 +3,8 @@
 servers + workers over ``MeteredVan(LoopbackVan())`` from
 ``launch_local_cluster``, a ``FleetMonitor`` on the scheduler, server ``i``
 and worker ``i`` on local device ``i % n`` (``utils.platform.role_device``),
-keys hashed by ``HashLocalizer``.  Sizes and counts come from the caller."""
+keys localised as the configuration's ``table.localizer`` says
+(``harness/keys.py``).  Sizes and counts come from the caller."""
 
 from __future__ import annotations
 
@@ -21,6 +22,9 @@ class Cluster:
     workers: Dict[str, object]
     table: object  # TableConfig
     placement: Dict[str, dict]
+    #: the key-to-row map the workers were built with (``harness/keys.py``):
+    #: the reference check and the byte model find rows by it
+    localizer: str
 
     def close(self) -> None:
         for srv in self.servers.values():
@@ -54,7 +58,20 @@ def table_config(spec: dict, rows: int):
     )
 
 
-def build_cluster(table, *, workers: int, servers: int) -> Cluster:
+def program_localizer(kind: str, rows: int):
+    """The program's localizer of that kind, for ``KVWorker``."""
+    from parameter_server_tpu.utils import keys as program_keys
+
+    cls = {"hash": program_keys.HashLocalizer,
+           "identity": program_keys.IdentityLocalizer}[kind]
+    return cls(rows)
+
+
+def build_cluster(table, *, workers: int, servers: int, localizer: str,
+                  **server_kwargs) -> Cluster:
+    """``localizer``: what ``keys.localizer_of`` read from the configuration.
+    ``server_kwargs``: keyword arguments of ``KVServer`` a driver needs (the
+    hybrid path's ``device_replies``); none by default."""
     import jax
 
     from parameter_server_tpu.core.fleet import FleetMonitor
@@ -64,7 +81,6 @@ def build_cluster(table, *, workers: int, servers: int) -> Cluster:
     from parameter_server_tpu.core.van import LoopbackVan
     from parameter_server_tpu.kv.server import KVServer
     from parameter_server_tpu.kv.worker import KVWorker
-    from parameter_server_tpu.utils.keys import HashLocalizer
 
     van = MeteredVan(LoopbackVan())
     try:
@@ -73,10 +89,12 @@ def build_cluster(table, *, workers: int, servers: int) -> Cluster:
         )
         sched.fleet = FleetMonitor()
         tables = {table.name: table}
-        loc = {table.name: HashLocalizer(table.rows)}
+        loc = {table.name: program_localizer(localizer, table.rows)}
         srvs, placement = {}, {}
         for i in range(servers):
-            srv = KVServer(posts[server_id(i)], tables, i, servers)
+            srv = KVServer(
+                posts[server_id(i)], tables, i, servers, **server_kwargs
+            )
             tbl = srv.tables[table.name]
             jax.block_until_ready((tbl.value, tbl.state))
             srvs[server_id(i)] = srv
@@ -94,7 +112,8 @@ def build_cluster(table, *, workers: int, servers: int) -> Cluster:
     except BaseException:
         van.close()
         raise
-    return Cluster(van, sched, managers, srvs, wrks, table, placement)
+    return Cluster(van, sched, managers, srvs, wrks, table, placement,
+                   localizer)
 
 
 def in_background(fn, *args, **kwargs):
@@ -120,14 +139,21 @@ def in_background(fn, *args, **kwargs):
     return result
 
 
-def cluster_and_batches(run):
+def cluster_and_batches(run, **server_kwargs):
     """What every driver's set-up starts with: the configuration's table,
-    the cluster at the cell's topology, and the cell's batches from its
-    generator.  NumPy makes the batches on a thread while the tables are
-    made on the device.  Returns ``(table, cluster, batches, keys_of)``."""
+    the cluster at the cell's topology with the workers' localizer the
+    configuration states (read here, once), and the cell's batches from its
+    generator.  ``server_kwargs`` go to every ``KVServer`` (``build_cluster``).
+    NumPy makes the batches on a thread while the tables are made on the
+    device.  Returns ``(table, cluster, batches, keys_of)``."""
     from benchmarks.harness.cell import load_module
+    from benchmarks.harness.keys import localizer_of
 
     sz = run.sizes
+    cfg_file = next(
+        c["file"] for c in run.bench["configs"] if c["name"] == run.config_name
+    )
+    localizer = localizer_of(run.config["table"], cfg_file)
     table = table_config(run.config["table"], sz["rows"])
     gen = load_module("generators", run.config["generator"], run.bench_dir)
     making = in_background(
@@ -135,7 +161,10 @@ def cluster_and_batches(run):
         run.traffic, seed=run.seed, n_workers=sz["workers"],
         cycle=sz["cycle"], batch=sz["batch"],
     )
-    cluster = build_cluster(table, workers=sz["workers"], servers=sz["servers"])
+    cluster = build_cluster(
+        table, workers=sz["workers"], servers=sz["servers"],
+        localizer=localizer, **server_kwargs,
+    )
     try:
         return table, cluster, making(), gen.keys_of
     except BaseException:

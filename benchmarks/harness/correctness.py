@@ -12,11 +12,36 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.harness.keys import hash_slots
+from benchmarks.harness.keys import draw_check_keys, rows_of
 from benchmarks.reference.adagrad import AdaGradRows
 
 REF_RTOL, REF_ATOL = 1e-5, 1e-5
 TIMEOUT = 120.0
+
+
+def check_draws(seed: int, *, n_keys: int, rows: int, dim: int, pushes: int,
+                localizer: str):
+    """What :func:`push_pull_check` pushes: first the distinct keys, drawn
+    from the localizer's domain, then for each push ``(idx, grads)``: most
+    keys once, a quarter twice, a tenth four times, shuffled (a count that
+    makes the bucket pad), and a gradient row per position.  Keys and
+    duplicates are the same in every run (the same shapes, so nothing
+    compiles for a new seed); the gradients come from the seed."""
+    fixed = np.random.default_rng(0x2EF)
+    rng = np.random.default_rng([int(seed), 0x2EF])
+    distinct = draw_check_keys(fixed, n_keys, rows, localizer)
+    yield distinct
+    n = distinct.size
+    for _ in range(pushes):
+        idx = np.concatenate([
+            fixed.permutation(n)[: (5 * n) // 6],
+            fixed.integers(0, n // 4, size=n // 4),
+            np.repeat(fixed.integers(0, n, size=n // 10), 3),
+        ])
+        fixed.shuffle(idx)
+        yield idx, (0.1 * rng.standard_normal((idx.size, dim))).astype(
+            np.float32
+        )
 
 
 def push_pull_check(cluster, seed: int, *, n_keys: int = 3000, rounds: int = 2):
@@ -24,23 +49,26 @@ def push_pull_check(cluster, seed: int, *, n_keys: int = 3000, rounds: int = 2):
     with duplicate keys (and a key count that makes the bucket pad) through
     ``push_sync``; then the touched keys are pulled back from every shard
     that owns one, and the rows must agree with NumPy AdaGrad on the touched
-    rows.  In turn, because the servers' gate counts every worker's pushes:
-    one worker pushing alone would start the run ``rounds`` steps ahead of
-    its peers and be held at the gate for good.  Returns ``(failures,
-    info)``; ``info["pushes"]`` is what the servers' push counts owe."""
+    rows.  The keys come from the domain of the localizer the cluster was
+    built with, and the reference finds their rows by it.  In turn, because
+    the servers' gate counts every worker's pushes: one worker pushing alone
+    would start the run ``rounds`` steps ahead of its peers and be held at
+    the gate for good.  Returns ``(failures, info)``; ``info["pushes"]`` is
+    what the servers' push counts owe."""
     table = cluster.table
     opt = table.optimizer
     if opt.kind != "adagrad" or opt.l1:
         return [f"no plain reference for optimizer {opt.kind!r}"], {}
     fails = []
-    # keys and duplicates are the same in every run (the same shapes, so
-    # nothing compiles for a new seed); the gradients come from the seed
-    fixed = np.random.default_rng(0x2EF)
-    rng = np.random.default_rng([int(seed), 0x2EF])
     workers = list(cluster.workers.values())
     worker = workers[0]
-    distinct = fixed.integers(1, 1 << 62, size=n_keys, dtype=np.uint64)
-    slots = hash_slots(distinct, table.rows)
+    pushes = rounds * len(workers)
+    draws = check_draws(
+        seed, n_keys=n_keys, rows=table.rows, dim=table.dim, pushes=pushes,
+        localizer=cluster.localizer,
+    )
+    distinct = next(draws)
+    slots = rows_of(distinct, table.rows, cluster.localizer)
     ns = len(cluster.servers)
     owners = np.unique(slots * ns // table.rows)
     if owners.size != ns:
@@ -48,18 +76,7 @@ def push_pull_check(cluster, seed: int, *, n_keys: int = 3000, rounds: int = 2):
     ref = AdaGradRows(table.dim, opt.learning_rate, opt.eps, opt.l2)
     init = worker.pull_sync(table.name, distinct, timeout=TIMEOUT)
     ref.seed_rows(slots, init)
-    pushes = rounds * len(workers)
-    for i in range(pushes):
-        # most keys once, a quarter twice, a tenth four times, shuffled
-        idx = np.concatenate([
-            fixed.permutation(n_keys)[: (5 * n_keys) // 6],
-            fixed.integers(0, n_keys // 4, size=n_keys // 4),
-            np.repeat(fixed.integers(0, n_keys, size=n_keys // 10), 3),
-        ])
-        fixed.shuffle(idx)
-        grads = (0.1 * rng.standard_normal((idx.size, table.dim))).astype(
-            np.float32
-        )
+    for i, (idx, grads) in enumerate(draws):
         workers[i % len(workers)].push_sync(
             table.name, distinct[idx], grads, timeout=TIMEOUT
         )
@@ -73,13 +90,25 @@ def push_pull_check(cluster, seed: int, *, n_keys: int = 3000, rounds: int = 2):
     bad = err > REF_ATOL + REF_RTOL * np.abs(want)
     if bad.any():
         fails.append(
-            f"reference check: {int(bad.any(axis=-1).sum())} of {n_keys} rows "
-            f"differ from NumPy AdaGrad (max abs error {float(err.max()):.3e})"
+            f"reference check: {int(bad.any(axis=-1).sum())} of {distinct.size} "
+            f"rows differ from NumPy AdaGrad (max abs error {float(err.max()):.3e})"
         )
     if np.array_equal(got, np.asarray(init).reshape(want.shape)):
         fails.append("reference check: the pushes changed no row")
     return fails, {"pushes": pushes, "max_abs_err": float(err.max()),
-                   "rows": n_keys}
+                   "rows": int(distinct.size), "localizer": cluster.localizer}
+
+
+def unique_rows_per_step(cluster, batches, keys_of) -> float:
+    """Mean unique rows a step touches, over the pool every worker cycles
+    through: the byte model's input, from the window's own batches, by the
+    localizer the cluster was built with."""
+    table = cluster.table
+    counts = [
+        np.unique(rows_of(keys_of(b), table.rows, cluster.localizer)).size
+        for b in batches
+    ]
+    return float(np.mean(counts))
 
 
 def compare_grads(got, want, what, examples, *, median, worst):

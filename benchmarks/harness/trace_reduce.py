@@ -1,8 +1,8 @@
 """From a profiler trace (``.xplane.pb``) to device busy / idle time, the
 device operations that took most time, and the idle gaps by what the host
 was doing.  Reads the file with ``jax.profiler.ProfileData`` and nothing
-else.  This is the reader ``parameter_server_tpu/utils/trace.py::jax_profile``
-never had.
+else.  The program's own ``ps.`` spans and scopes in the same file are
+``program_spans.py``'s to read.
 
 - Device planes are those named ``/device:<PLATFORM>:<n>``.  On each, the
   operations are the events of the line named ``XLA Ops``; a plane without

@@ -80,21 +80,6 @@ def _trace_thread(clock, logdir, seconds, out):
     out["t_stopped"] = clock.now()
 
 
-def unique_rows_per_step(run, driver):
-    """Mean unique rows a step touches, over the pool every worker cycles
-    through: the byte model's input, from the window's own batches
-    (``hash_slots`` is the configurations' hashing trick)."""
-    import numpy as np
-
-    from benchmarks.harness.keys import hash_slots
-
-    counts = [
-        np.unique(hash_slots(driver.keys_of(b), run.sizes["rows"])).size
-        for b in driver.batches[0]
-    ]
-    return float(np.mean(counts))
-
-
 def main(argv=None, bench_dir=BENCH_DIR) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -278,7 +263,9 @@ def _run(args, bench_dir, result_out) -> int:
                                 "idle_gaps": red["idle_gaps"]}
             if not args.dry_run and not red["busy_s"] > 0:
                 fails.append("no operation ran on the device in the trace")
-        run.unique_rows_per_step = unique_rows_per_step(run, driver)
+        run.unique_rows_per_step = correctness.unique_rows_per_step(
+            cluster, driver.batches[0], driver.keys_of
+        )
         for entry in cell_lib.layer_metrics_for(run):
             mod = cell_lib.load_module("layer_metrics", entry["name"], bench_dir)
             value = mod.read(run)

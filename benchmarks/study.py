@@ -76,7 +76,9 @@ def summary(rows):
         if len(vals) >= 3:
             q1, _, q3 = statistics.quantiles(vals, n=4)
             med = statistics.median(vals)
-            line += f" | median {med:.6g} spread {(q3 - q1) / med:.4f}"
+            line += f" | median {med:.6g}"
+            if med:  # a count that is 0 in every run has no spread
+                line += f" spread {(q3 - q1) / med:.4f}"
         print(line)
 
 
