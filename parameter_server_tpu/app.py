@@ -251,6 +251,60 @@ def _build_fm(cfg: AppConfig) -> Callable[[], dict]:
     return run
 
 
+def _run_hybrid(cfg: AppConfig, model_cfg) -> dict:
+    """What the registered hybrid apps run: ``model_cfg``'s body under
+    ``learner/hybrid.py::HybridLMTrainer``, its embedding table on
+    ``cfg.topology.num_servers`` ``KVServer``s over a loopback Van."""
+    import numpy as np
+
+    from parameter_server_tpu.core.postoffice import Postoffice
+    from parameter_server_tpu.core.van import LoopbackVan
+    from parameter_server_tpu.kv.server import KVServer
+    from parameter_server_tpu.kv.worker import KVWorker
+    from parameter_server_tpu.learner import hybrid
+    from parameter_server_tpu.parallel import mesh as mesh_lib
+
+    ns = cfg.topology.num_servers
+    van = LoopbackVan()
+    try:
+        table = dataclasses.replace(
+            hybrid.embedding_table_cfg(model_cfg),
+            optimizer=cfg.table.optimizer,
+        )
+        tables = {"emb": table}
+        _servers = [
+            KVServer(Postoffice(f"S{i}", van), tables, i, ns)
+            for i in range(ns)
+        ]
+        worker = KVWorker(
+            Postoffice("W0", van), tables, ns,
+            localizers=hybrid.embedding_localizers(model_cfg),
+        )
+        import jax
+
+        n_dev = len(jax.devices())
+        trainer = hybrid.HybridLMTrainer(
+            model_cfg,
+            mesh_lib.make_mesh((n_dev, 1)),
+            worker,
+            max_delay=cfg.consistency.max_delay,
+        )
+        rng = np.random.default_rng(cfg.data.seed)
+        B, S = 2 * n_dev, 32  # batch divisible by the data axis
+        losses = []
+        for _ in range(cfg.steps):
+            base = rng.integers(0, model_cfg.vocab_size, size=(B, 1))
+            tokens = (base + np.arange(S)[None]) % model_cfg.vocab_size
+            losses.append(trainer.step(tokens.astype(np.int32)))
+        trainer.drain()
+        out = {"losses": losses, "steps": cfg.steps}
+        if trainer.counters:
+            out["counters"] = dict(trainer.counters)
+        return out
+    finally:
+        van.close()
+
+
 @register_app("llama_hybrid")
 def _build_llama_hybrid(cfg: AppConfig) -> Callable[[], dict]:
     """BASELINE config #5: PS-served embedding table over the Van + sync
@@ -260,56 +314,30 @@ def _build_llama_hybrid(cfg: AppConfig) -> Callable[[], dict]:
     in-flight embedding pushes (SSP)."""
 
     def run() -> dict:
-        import numpy as np
-
-        from parameter_server_tpu.core.postoffice import Postoffice
-        from parameter_server_tpu.core.van import LoopbackVan
-        from parameter_server_tpu.kv.server import KVServer
-        from parameter_server_tpu.kv.worker import KVWorker
-        from parameter_server_tpu.learner import hybrid
         from parameter_server_tpu.models import transformer as tfm
-        from parameter_server_tpu.parallel import mesh as mesh_lib
 
-        ns = cfg.topology.num_servers
-        model_cfg = tfm.tiny_config(
+        return _run_hybrid(cfg, tfm.tiny_config(
             causal=True, tie_embeddings=False,
             vocab_size=min(cfg.data.key_space, 1 << 16),
-        )
-        van = LoopbackVan()
-        try:
-            table = dataclasses.replace(
-                hybrid.embedding_table_cfg(model_cfg),
-                optimizer=cfg.table.optimizer,
-            )
-            tables = {"emb": table}
-            _servers = [
-                KVServer(Postoffice(f"S{i}", van), tables, i, ns)
-                for i in range(ns)
-            ]
-            worker = KVWorker(
-                Postoffice("W0", van), tables, ns,
-                localizers=hybrid.embedding_localizers(model_cfg),
-            )
-            import jax
+        ))
 
-            n_dev = len(jax.devices())
-            trainer = hybrid.HybridLMTrainer(
-                model_cfg,
-                mesh_lib.make_mesh((n_dev, 1)),
-                worker,
-                max_delay=cfg.consistency.max_delay,
-            )
-            rng = np.random.default_rng(cfg.data.seed)
-            B, S = 2 * n_dev, 32  # batch divisible by the data axis
-            losses = []
-            for _ in range(cfg.steps):
-                base = rng.integers(0, model_cfg.vocab_size, size=(B, 1))
-                tokens = (base + np.arange(S)[None]) % model_cfg.vocab_size
-                losses.append(trainer.step(tokens.astype(np.int32)))
-            trainer.drain()
-            return {"losses": losses, "steps": cfg.steps}
-        finally:
-            van.close()
+    return run
+
+
+@register_app("kimi_linear_hybrid")
+def _build_kimi_linear_hybrid(cfg: AppConfig) -> Callable[[], dict]:
+    """The same hybrid path under a body with a layer pattern
+    (``models/kimi_linear.py``: delta-rule and latent-attention mixers, a
+    dense MLP and a held share of routed experts), tiny sizes by default so
+    the app runs anywhere; the benchmark's ``kimi_linear_a3b`` configuration
+    runs the published widths through the same trainer."""
+
+    def run() -> dict:
+        from parameter_server_tpu.models import kimi_linear
+
+        return _run_hybrid(cfg, kimi_linear.tiny_config(
+            vocab_size=min(cfg.data.key_space, 1 << 16),
+        ))
 
     return run
 

@@ -58,7 +58,7 @@ from parameter_server_tpu.kv.routing import (
     RoutingTable,
 )
 from parameter_server_tpu.kv.table import KVTable
-from parameter_server_tpu.utils.keys import bucket_size
+from parameter_server_tpu.utils.keys import leg_bucket as _bucket
 from parameter_server_tpu.utils.platform import role_device
 from parameter_server_tpu.utils.trace import (
     NULL_TRACER,
@@ -66,11 +66,6 @@ from parameter_server_tpu.utils.trace import (
     Tracer,
     req_id,
 )
-
-
-def _bucket(n: int) -> int:
-    """Server-side id bucket: next power of two, >= 8 (pallas block floor)."""
-    return bucket_size(max(n, 1), min_bucket=8)
 
 
 class KVServer(Customer):
@@ -490,7 +485,8 @@ class KVServer(Customer):
             task=Task(TaskKind.PUSH, self._fwd.name, payload={"table": tname}),
             recver=self.replica,
             keys=np.asarray(msg.keys),
-            values=[np.asarray(msg.values[0])],
+            # a device plane arrives padded past its keys (``push_device``)
+            values=[np.asarray(msg.values[0])[: len(msg.keys)]],
         )
         ts = self._fwd.submit([fwd])
         if self.replica_sync:
@@ -778,9 +774,12 @@ class KVServer(Customer):
         # frombuffer view of the received frame) feeds the device transfer
         # as-is — no intermediate padded host copy.  A device plane pushed
         # by a worker on another chip crosses here, once.
+        # (``push_device`` hands its planes over already padded to ``b``
+        # with zeros: nothing to do, and no program a leg size.)
         vals = self._put(vals if isinstance(vals, jax.Array) else np.asarray(vals))
-        if b != n:  # pad on device (exact zeros: bitwise-neutral)
-            vals = jnp.pad(vals, ((0, b - n),) + ((0, 0),) * (vals.ndim - 1))
+        have = int(vals.shape[0])
+        if b != have:  # pad on device (exact zeros: bitwise-neutral)
+            vals = jnp.pad(vals, ((0, b - have),) + ((0, 0),) * (vals.ndim - 1))
         return vals
 
     def _stack_planes(
@@ -954,6 +953,19 @@ class KVServer(Customer):
         with self.tracer.span("ps.server.d2h", bytes=nbytes):
             return jax.device_get(rows) if bundle else np.asarray(rows)
 
+    def _device_reply(self, rows):
+        """What a pull's reply carries under ``device_replies``: the
+        bucket-padded gather whole (rows past the leg's count are the trash
+        row's; the worker drops them: ``rows[:n]`` on the device is one
+        compiled program a leg size).  Device arrays are handed over by
+        reference, inside one process (``core/resender.py``: they never ride
+        a wire buffer); a plane that is serialised all the same goes out at
+        its bucket's size.  The reply's D2H stage keeps its span, ``bytes``
+        0, so that a reader of the stage finds it and reads that it costs
+        nothing here (a few microseconds: the span's own cost)."""
+        with self.tracer.span("ps.server.d2h", bytes=0):
+            return rows
+
     def _pull_device(
         self, tname: str, ids_np: np.ndarray, segs: np.ndarray, sp
     ) -> Tuple[jax.Array, int, int]:
@@ -1005,14 +1017,16 @@ class KVServer(Customer):
                 t0 = time.perf_counter()
                 rows, n, sver = self._pull_ro_device(tname, ids_np, segs, sp)
                 if self.device_replies:
-                    vals = [rows[:n]]
+                    vals = [self._device_reply(rows)]
                 else:
                     vals = [self._to_host(rows)[:n]]
                 self.ro_hist[tname].record(time.perf_counter() - t0)
                 return self._stamp_version(msg, msg.reply(values=vals), sver)
             rows, n, sver = self._pull_device(tname, ids_np, segs, sp)
             if self.device_replies:
-                return self._stamp_version(msg, msg.reply(values=[rows[:n]]), sver)
+                return self._stamp_version(
+                    msg, msg.reply(values=[self._device_reply(rows)]), sver
+                )
             return self._stamp_version(
                 msg, msg.reply(values=[self._to_host(rows)[:n]]), sver
             )
@@ -1150,7 +1164,7 @@ class KVServer(Customer):
         if self.device_replies:
             for i, m, rows, n, sver in pulls:
                 replies[i] = self._stamp_version(
-                    m, m.reply(values=[rows[:n]]), sver
+                    m, m.reply(values=[self._device_reply(rows)]), sver
                 )
             return
         host = self._to_host([rows for _, _, rows, _, _ in pulls])
@@ -1166,7 +1180,7 @@ class KVServer(Customer):
         if self.device_replies:
             for i, m, tname, rows, n, sver, t0 in ro:
                 replies[i] = self._stamp_version(
-                    m, m.reply(values=[rows[:n]]), sver
+                    m, m.reply(values=[self._device_reply(rows)]), sver
                 )
                 self.ro_hist[tname].record(time.perf_counter() - t0)
             return

@@ -72,7 +72,11 @@ from parameter_server_tpu.kv.routing import (
     WorkerGroup,
 )
 from parameter_server_tpu.ops import scatter
-from parameter_server_tpu.utils.keys import HashLocalizer, localize_to_slots
+from parameter_server_tpu.utils.keys import (
+    HashLocalizer,
+    leg_bucket,
+    localize_to_slots,
+)
 from parameter_server_tpu.utils.platform import role_device
 from parameter_server_tpu.utils.trace import (
     NULL_TRACER,
@@ -86,6 +90,33 @@ from parameter_server_tpu.utils.trace import (
 def _segment_combine(inverse, values, num_rows: int):
     with jax.named_scope("ps.worker.combine"):
         return scatter.segment_combine(values, inverse, num_rows)
+
+
+@functools.partial(jax.jit, static_argnames=("n_slots", "dim", "dtype"))
+def _assemble_device(positions, rows, inverse, *, n_slots: int, dim: int, dtype):
+    """Rows of a pull's legs -> one row a requested position, on device:
+    every leg's rows scattered to their slots, then gathered by
+    ``inverse``.  A leg's rows arrive padded to its bucket; its positions
+    are padded with ``n_slots``, which the scatter drops."""
+    uniq = jnp.zeros((n_slots, dim), dtype)
+    for pos, leg in zip(positions, rows):
+        uniq = uniq.at[pos].set(
+            leg.astype(dtype).reshape(-1, dim), mode="drop"
+        )
+    return jnp.take(uniq, inverse, axis=0)
+
+
+@jax.jit
+def _take_rows(plane, idx):
+    """``plane[idx]`` on device, zeros where ``idx`` is past the plane: a
+    leg of a device push, padded to its bucket."""
+    return jnp.take(plane, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _pad_index(idx: np.ndarray, size: int, fill: int) -> np.ndarray:
+    out = np.full(size, fill, np.int32)
+    out[: idx.shape[0]] = idx
+    return out
 
 
 class KVWorker(Customer):
@@ -1142,7 +1173,7 @@ class KVWorker(Customer):
                         task=Task(TaskKind.PUSH, self.name, payload=payload),
                         recver=server_id(s),
                         keys=ids.astype(np.int32),
-                        values=[combined[abs_pos]],
+                        values=[self._leg_rows(combined, abs_pos)],
                     )
                 )
             # register the span tree BEFORE the wire submit: replies race
@@ -1157,6 +1188,18 @@ class KVWorker(Customer):
                 ts = self.submit(msgs, keep_responses=keep)
             sp.set(req=self._req(ts), legs=len(msgs))
         return ts, order
+
+    @staticmethod
+    def _leg_rows(combined, abs_pos: np.ndarray):
+        """One server's rows of a combined plane.  A device plane
+        (``push_device``) is handed over padded with zero rows to the leg's
+        bucket, the size the server pads to anyway: ``combined[abs_pos]`` on
+        the device is half a dozen compiled programs a leg size."""
+        if not isinstance(combined, jax.Array):
+            return combined[abs_pos]
+        return _take_rows(combined, _pad_index(
+            abs_pos, leg_bucket(abs_pos.shape[0]), combined.shape[0]
+        ))
 
     def _prepare_push(self, table: str, keys, values):
         """Host half of a push: localize + device duplicate pre-combine."""
@@ -1598,13 +1641,16 @@ class KVWorker(Customer):
                 # cast copy
                 uniq_rows = np.asarray(sole, dtype=cfg.dtype).reshape(
                     -1, cfg.dim
-                )
+                )[: plan["n_slots"]]
             else:
                 uniq_rows = np.zeros(
                     (plan["n_slots"], cfg.dim), dtype=cfg.dtype
                 )
                 for pos, rows, *_meta in pairs:
-                    uniq_rows[pos] = np.asarray(rows).reshape(-1, cfg.dim)
+                    # a device reply is padded to its leg's bucket
+                    uniq_rows[pos] = np.asarray(rows).reshape(
+                        -1, cfg.dim
+                    )[: len(pos)]
             out = uniq_rows[plan["inverse"]]
         if cfg.dim == 1:
             return out.reshape(plan["shape"])
@@ -1629,15 +1675,27 @@ class KVWorker(Customer):
             if sole is not None:
                 uniq = jax.device_put(sole, self.device)
                 uniq = uniq.astype(dtype).reshape(-1, cfg.dim)
+                out = jnp.take(uniq, jnp.asarray(plan["inverse"]), axis=0)
             else:
-                with jax.default_device(self.device):
-                    uniq = jnp.zeros((plan["n_slots"], cfg.dim), dtype)
-                for pos, rows, *_meta in pairs:
-                    rows = jax.device_put(rows, self.device).reshape(
-                        -1, cfg.dim
-                    )
-                    uniq = uniq.at[jnp.asarray(pos)].set(rows)
-            out = jnp.take(uniq, jnp.asarray(plan["inverse"]), axis=0)
+                # one compiled program a (leg buckets, slots) shape, the legs
+                # in the order of their positions whichever reply came
+                # first.  A device reply's rows are padded to the leg's
+                # bucket; its positions are padded to match with an index
+                # the scatter drops.
+                legs = sorted(
+                    ((np.asarray(pos, np.int32), rows) for pos, rows, *_m in pairs),
+                    key=lambda leg: int(leg[0][0]) if leg[0].size else -1,
+                )
+                out = _assemble_device(
+                    tuple(
+                        _pad_index(pos, max(rows.shape[0], pos.shape[0]),
+                                   plan["n_slots"])
+                        for pos, rows in legs
+                    ),
+                    tuple(jax.device_put(rows, self.device) for _pos, rows in legs),
+                    np.asarray(plan["inverse"], np.int32),
+                    n_slots=plan["n_slots"], dim=cfg.dim, dtype=dtype,
+                )
         if cfg.dim == 1:
             return out.reshape(plan["shape"])
         return out.reshape(plan["shape"] + (cfg.dim,))
@@ -1722,7 +1780,7 @@ class KVWorker(Customer):
                 for p, rows, sver, sender in pairs:
                     rows = np.asarray(rows, dtype=cfg.dtype).reshape(
                         -1, cfg.dim
-                    )
+                    )[: len(p)]
                     rows_out[p] = rows
                     ids = slots[p]
                     realm = ids < grows

@@ -33,10 +33,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from parameter_server_tpu.config import OptimizerConfig, TableConfig
 from parameter_server_tpu.kv.worker import KVWorker
-from parameter_server_tpu.models import transformer as tfm
 from parameter_server_tpu.parallel import mesh as mesh_lib
 from parameter_server_tpu.parallel.tp import place_params
 from parameter_server_tpu.utils import metrics as metrics_lib
@@ -45,7 +45,7 @@ from parameter_server_tpu.utils.trace import NULL_TRACER
 
 
 def embedding_table_cfg(
-    cfg: tfm.TransformerConfig,
+    cfg,
     *,
     learning_rate: float = 0.05,
     optimizer: str = "adagrad",
@@ -60,13 +60,18 @@ def embedding_table_cfg(
     )
 
 
-def embedding_localizers(cfg: tfm.TransformerConfig) -> Dict[str, object]:
+def embedding_localizers(cfg) -> Dict[str, object]:
     """Localizer map for :class:`KVWorker`: identity (token id == row)."""
     return {"emb": IdentityLocalizer(cfg.vocab_size)}
 
 
 class HybridLMTrainer:
     """One step = Van pull (rows) -> GSPMD body fwd/bwd -> Van push (grads).
+
+    ``cfg`` is the body's model config, whose ``hybrid_body(seed,
+    loss_chunk)`` builds what is trained: a ``TransformerConfig`` (one kind
+    of block) or a ``KimiLinearConfig`` (a layer pattern of delta-rule and
+    latent-attention mixers, dense and expert MLPs).
 
     ``max_delay``: how many embedding pushes may be in flight before the
     next step blocks on the oldest ack (τ of SSP; 0 = BSP, every push
@@ -75,12 +80,13 @@ class HybridLMTrainer:
 
     def __init__(
         self,
-        cfg: tfm.TransformerConfig,
+        cfg,
         mesh,
         worker: KVWorker,
         *,
         table: str = "emb",
         learning_rate: float = 1e-3,
+        warmup_steps: int = 0,
         max_delay: int = 0,
         seed: int = 0,
         dashboard: Optional[metrics_lib.Dashboard] = None,
@@ -92,7 +98,11 @@ class HybridLMTrainer:
         chunked loss (``chunked_causal_lm_loss``): the f32 [B, S, vocab]
         logits never materialize — one of the three knobs (with
         ``cfg.scan_blocks`` and ``cfg.remat``) that fit the 8B body on a
-        v5e-16 (see ``parallel/feasibility.py``)."""
+        v5e-16 (see ``parallel/feasibility.py``).
+
+        ``warmup_steps > 1``: the body's rate rises linearly, step ``t``
+        (from 0) taking ``learning_rate * (t + 1) / warmup_steps`` until it
+        stands at ``learning_rate``."""
         if cfg.tie_embeddings:
             raise ValueError(
                 "hybrid requires untied embeddings: the lm_head is dense "
@@ -107,12 +117,31 @@ class HybridLMTrainer:
         self.dashboard = metrics_lib.trainer_dashboard(
             dashboard, mesh.devices.size
         )
-        self.body = tfm.TransformerBody(cfg)
+        if warmup_steps > 1:
+            learning_rate = optax.linear_schedule(
+                learning_rate / warmup_steps, learning_rate, warmup_steps - 1
+            )
         self.tx = optax.adamw(learning_rate)
-        x0 = jnp.zeros((1, 8, cfg.d_model), jnp.float32)
-        params = self.body.init(jax.random.PRNGKey(seed), x0)["params"]
+        params, loss_fn, self._logits, self.n_active_params, scope = (
+            cfg.hybrid_body(seed, loss_chunk)
+        )
         self.params = place_params(params, mesh)
+        del params
+        # the optimizer state placed like the parameters it shadows (what has
+        # no mesh sharding yet, the step count, replicated), and the step's
+        # outputs pinned to the same shardings: a step's outputs are the
+        # next step's inputs, and left to the compiler they came back under
+        # other specs than ``place_params`` gave, so the second call
+        # compiled the whole step a second time
+        replicated = NamedSharding(mesh, PartitionSpec())
+        param_sh = jax.tree.map(lambda x: x.sharding, self.params)
         self.opt_state = self.tx.init(self.params)
+        opt_sh = jax.tree.map(
+            lambda x: x.sharding if isinstance(x.sharding, NamedSharding)
+            else replicated,
+            self.opt_state,
+        )
+        self.opt_state = jax.device_put(self.opt_state, opt_sh)
         self._batch3 = mesh_lib.batch_sharding(mesh, 3)
         self._batch2 = mesh_lib.batch_sharding(mesh, 2)
         self._inflight: collections.deque[int] = collections.deque()
@@ -120,51 +149,44 @@ class HybridLMTrainer:
         self._prefetch: Optional[tuple] = None
         self.tracer = tracer or NULL_TRACER
         self.step_count = 0
-        body, tx = self.body, self.tx
-
-        if loss_chunk > 0:
-            trunk = tfm.TransformerTrunk(cfg)
-
-            def loss_fn(params, emb_in, targets):
-                hidden = trunk.apply(
-                    {
-                        "params": {
-                            k: v for k, v in params.items() if k != "lm_head"
-                        }
-                    },
-                    emb_in,
-                )
-                return tfm.chunked_causal_lm_loss(
-                    hidden, params["lm_head"]["kernel"], targets, loss_chunk
-                )
-
-        else:
-
-            def loss_fn(params, emb_in, targets):
-                logits = body.apply({"params": params}, emb_in)
-                return tfm.causal_lm_loss(logits, targets)
-
+        #: what the last step counted beside its loss (``kimi_linear.COUNTERS`` of
+        #: a body with experts: held, dropped and the fullest expert's token
+        #: slots; empty otherwise), as ints
+        self.counters: Dict[str, int] = {}
+        #: the body's loss: ``loss_fn(params, emb_in, targets) -> (loss,
+        #: counters)``; what ``step`` differentiates
+        self.loss_fn = loss_fn
+        tx = self.tx
         batch3 = self._batch3
 
         def step_fn(params, opt_state, emb_in, targets):
             # grads w.r.t. (params, emb_in): the emb_in gradient is what
             # flows back to the PS table as per-position row updates
-            (loss, grads) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
-                params, emb_in, targets
-            )
-            g_params, g_emb = grads
-            # pin the embedding gradient to the batch sharding: each pod
-            # host then extracts exactly ITS batch rows from addressable
-            # shards for the local Van push (no cross-host gather)
-            g_emb = jax.lax.with_sharding_constraint(g_emb, batch3)
-            updates, opt_state = tx.update(g_params, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            return params, opt_state, loss, g_emb
+            # one device scope holds the whole step (the body's own scopes
+            # and the optimizer's nest in it), so that an operation the
+            # compiler made is still the step's in a trace
+            with jax.named_scope(scope):
+                (loss, counters), grads = jax.value_and_grad(
+                    loss_fn, argnums=(0, 1), has_aux=True
+                )(params, emb_in, targets)
+                g_params, g_emb = grads
+                # pin the embedding gradient to the batch sharding: each pod
+                # host then extracts exactly ITS batch rows from addressable
+                # shards for the local Van push (no cross-host gather)
+                g_emb = jax.lax.with_sharding_constraint(g_emb, batch3)
+                with jax.named_scope("ps.model.optimizer"):
+                    updates, opt_state = tx.update(g_params, opt_state, params)
+                    params = optax.apply_updates(params, updates)
+            return params, opt_state, loss, g_emb, counters
 
-        self._step = jax.jit(step_fn, donate_argnums=(0, 1))
-        #: body parameter count for the MFU column (6ND rule: fwd+bwd train
-        #: FLOPs ~ 6 x params x tokens; set per step since the sequence
-        #: length rides the batch).  Public: bench --hybrid reuses it so the
+        self._step = jax.jit(
+            step_fn, donate_argnums=(0, 1),
+            out_shardings=(param_sh, opt_sh, None, None, None),
+        )
+        #: body parameter count (held here).  ``n_active_params`` is what the
+        #: MFU column's 6ND rule takes (fwd+bwd train FLOPs ~ 6 x params a
+        #: token multiplies with x tokens): equal to it for a dense body, less
+        #: for one with experts.  Public: bench --hybrid reuses them so the
         #: two MFU computations cannot drift.
         self.n_body_params = sum(
             int(np.prod(p.shape)) for p in jax.tree.leaves(self.params)
@@ -216,6 +238,12 @@ class HybridLMTrainer:
         hides ack latency (pulls get the same overlap pushes have).
         """
         tokens = np.asarray(tokens)
+        with self.tracer.span("ps.hybrid.step", tokens=int(tokens.size)) as sp:
+            if sp.recording:  # nothing to count for when nothing records
+                sp.set(unique=int(np.unique(tokens).size))
+            return self._step_traced(tokens, next_tokens, pull_timeout)
+
+    def _step_traced(self, tokens, next_tokens, pull_timeout) -> float:
         # Dual-plane pod shape (VERDICT r3 #2): when the GSPMD mesh spans OS
         # processes, each process owns its local_batch_slice of the global
         # batch end to end — pulls only its rows' embeddings over ITS Van
@@ -278,7 +306,7 @@ class HybridLMTrainer:
         # device compute there (the overlap window is the Van RTT against
         # the NEXT step's host work, not against this body step).
         with self.tracer.span("ps.hybrid.body_dispatch"):
-            self.params, self.opt_state, loss, g_emb = self._step(
+            self.params, self.opt_state, loss, g_emb, counters = self._step(
                 self.params, self.opt_state, emb_d, tok_d
             )
         # 3) PS plane: push per-position embedding gradients device-to-device
@@ -287,19 +315,20 @@ class HybridLMTrainer:
         # submits, and per-link FIFO then guarantees the prefetched rows
         # include this step's update (pull-before-push would silently hand
         # back one-update-stale rows even at max_delay=0).
-        if multiproc:
-            g_local = self._local_batch_rows(g_emb, sl)
-            ts = self.worker.push(
-                self.table,
-                tokens_feed.reshape(-1),
-                g_local.reshape(-1, self.cfg.d_model),
-            )
-        else:
-            ts = self.worker.push_device(
-                self.table,
-                tokens.reshape(-1),
-                g_emb.reshape(-1, self.cfg.d_model),
-            )
+        with self.tracer.span("ps.hybrid.push_submit"):
+            if multiproc:
+                g_local = self._local_batch_rows(g_emb, sl)
+                ts = self.worker.push(
+                    self.table,
+                    tokens_feed.reshape(-1),
+                    g_local.reshape(-1, self.cfg.d_model),
+                )
+            else:
+                ts = self.worker.push_device(
+                    self.table,
+                    tokens.reshape(-1),
+                    g_emb.reshape(-1, self.cfg.d_model),
+                )
         # 4) prefetch the NEXT batch's rows while the body computes
         if next_tokens is not None:
             next_tokens = np.asarray(next_tokens)
@@ -315,10 +344,11 @@ class HybridLMTrainer:
                 )
             else:
                 nsl = slice(0, next_tokens.shape[0])
-            self._prefetch = (
-                self.worker.pull(self.table, next_tokens[nsl]),
-                next_tokens,
-            )
+            with self.tracer.span("ps.hybrid.prefetch"):
+                self._prefetch = (
+                    self.worker.pull(self.table, next_tokens[nsl]),
+                    next_tokens,
+                )
         self._inflight.append(ts)
         while len(self._inflight) > self.max_delay:
             old = self._inflight.popleft()
@@ -327,10 +357,11 @@ class HybridLMTrainer:
         self.step_count += 1
         with self.tracer.span("ps.hybrid.loss_sync"):
             loss_f = float(loss)
+            self.counters = {k: int(v) for k, v in counters.items()}
         emb_mb = tokens.size * self.cfg.d_model * 4 * 2 / 1e6  # pull + push
-        # one example = one sequence: 6 x body params x seq tokens
+        # one example = one sequence: 6 x active body params x seq tokens
         self.dashboard.flops_per_example = (
-            6.0 * self.n_body_params * tokens.shape[1]
+            6.0 * self.n_active_params * tokens.shape[1]
         )
         self.dashboard.record(
             self.step_count,
@@ -340,6 +371,14 @@ class HybridLMTrainer:
         )
         return loss_f
 
+    def wait_pushes(self) -> None:
+        """Block until every in-flight embedding push is acked; an announced
+        prefetch stays announced (the next ``step`` still finds it)."""
+        while self._inflight:
+            old = self._inflight.popleft()
+            if not self.worker.wait(old, timeout=self.push_timeout):
+                raise TimeoutError(f"embedding push ts={old} not acked")
+
     def drain(self) -> None:
         """Block until every in-flight embedding push is acked (epoch end).
 
@@ -347,10 +386,7 @@ class HybridLMTrainer:
         responses (full embedding-row arrays under ``device_replies``) stay
         pinned in the Customer for the process lifetime.
         """
-        while self._inflight:
-            old = self._inflight.popleft()
-            if not self.worker.wait(old, timeout=self.push_timeout):
-                raise TimeoutError(f"embedding push ts={old} not acked")
+        self.wait_pushes()
         if self._prefetch is not None:
             pts, _ptok = self._prefetch
             self._prefetch = None
@@ -425,7 +461,5 @@ class HybridLMTrainer:
         tokens = np.asarray(tokens)
         emb_in = self.worker.pull_sync(self.table, tokens, timeout=pull_timeout)
         return np.asarray(
-            self.body.apply(
-                {"params": self.params}, jnp.asarray(emb_in, jnp.float32)
-            )
+            self._logits(self.params, jnp.asarray(emb_in, jnp.float32))
         )

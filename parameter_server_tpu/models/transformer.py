@@ -74,6 +74,11 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def hybrid_body(self, seed: int, loss_chunk: int):
+        """What ``learner/hybrid.py::HybridLMTrainer`` trains: see
+        :func:`hybrid_body`."""
+        return hybrid_body(self, seed, loss_chunk)
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
@@ -437,3 +442,34 @@ def mlm_loss(logits: jax.Array, targets: jax.Array, mask: jax.Array) -> jax.Arra
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     denom = jnp.maximum(jnp.sum(mask), 1.0)
     return jnp.sum(nll * mask) / denom
+
+
+def hybrid_body(cfg: TransformerConfig, seed: int, loss_chunk: int):
+    """The one-kind block stack as the hybrid trainer takes a body:
+    ``(params, loss_fn(params, emb_in, targets) -> (loss, counters),
+    logits_fn(params, emb_in), active parameter count, device scope of the
+    step)``.  ``loss_chunk > 0`` fuses the head into the chunked loss."""
+    body = TransformerBody(cfg)
+    x0 = jnp.zeros((1, 8, cfg.d_model), jnp.float32)
+    params = body.init(jax.random.PRNGKey(seed), x0)["params"]
+    if loss_chunk > 0:
+        trunk = TransformerTrunk(cfg)
+
+        def loss_fn(params, emb_in, targets):
+            hidden = trunk.apply(
+                {"params": {k: v for k, v in params.items() if k != "lm_head"}},
+                emb_in,
+            )
+            return chunked_causal_lm_loss(
+                hidden, params["lm_head"]["kernel"], targets, loss_chunk
+            ), {}
+
+    else:
+
+        def loss_fn(params, emb_in, targets):
+            logits = body.apply({"params": params}, emb_in)
+            return causal_lm_loss(logits, targets), {}
+
+    n = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
+    return (params, loss_fn, lambda p, e: body.apply({"params": p}, e), n,
+            "ps.model.transformer")

@@ -1,0 +1,199 @@
+"""Causal softmax attention blocked over queries, in plain XLA operations.
+
+A block of ``block`` queries attends to the keys up to the last position of
+its band (a few blocks that share one key prefix) and no further: the scores
+of one block are ``[B, H, block, end]`` float32, and no ``[S, S]`` tensor of
+all heads is ever live (at 8,192 tokens and 32 heads that tensor is 8.6 GB a
+sequence).  The work is the causal half plus half a band's width of masked
+scores a block.
+
+A key may have a part all heads share (latent attention's positional part,
+``k_shared [B, S, Dr]`` against ``q_shared [B, S, H, Dr]``): it is scored on
+its own, so it is never copied out per head.
+
+**The backward is written out** (a ``custom_vjp``, the blockwise recurrence
+``ops/ring_attention.py`` uses): the forward keeps ``q, k, v``, the output
+and the log-sum-exp of every query; the backward recomputes one block's
+probabilities at a time and adds its part of ``dk`` and ``dv`` into one
+accumulator a band, the band's into one for the sequence.  Differentiating
+the blocked forward instead leaves one cotangent per block for every prefix
+``k[:, :end]`` and ``v[:, :end]`` it sliced, 8 GB of them at 32 blocks of
+8,192 tokens (found by compiling the step for the chip's memory, PR 28).
+
+Scores, softmax and log-sum-exp are float32; the products run at jax's
+default precision.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+
+def _scores(start, scale, q, k, q_shared, k_shared):
+    """Masked, scaled scores ``[B, H, bq, end]`` of the queries ``start ..``."""
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) + jnp.einsum(
+        "bqhd,bkd->bhqk", q_shared, k_shared, preferred_element_type=jnp.float32
+    )
+    q_ids = start + jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
+    k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
+    return jnp.where(k_ids <= q_ids, s * scale, -jnp.inf)
+
+
+def _after(x, done):
+    """The arrays ``x``, not to be computed before ``done`` is: the bands share
+    no data, and a scheduler free to run them side by side keeps every
+    band's scores live at once (4 GB at 32 blocks, found by compiling for
+    the chip's memory, PR 28)."""
+    return x if done is None else jax.lax.optimization_barrier((x, done))[0]
+
+
+def _bands(S, block, band):
+    """``[(first query, queries, keys), ...]``: a band is ``band`` blocks of
+    ``block`` queries that share one key prefix, the one its last query
+    needs.  One loop (``lax.scan``) a band: a band is one piece of code
+    whatever its blocks, where a block of its own length each was 96 pieces
+    at 32 blocks (a 420 MB program that took 12 minutes to compile on the
+    chip's host, PR 28); the price is the masked scores between a block's
+    own prefix and its band's (a sixteenth more at 8 bands of 4)."""
+    padded = -(-S // block) * block
+    width = band * block
+    return [(a, min(width, padded - a), min(a + width, S))
+            for a in range(0, padded, width)]
+
+
+def _pad_queries(x, padded):
+    extra = padded - x.shape[1]
+    if not extra:
+        return x
+    return jnp.pad(x, ((0, 0), (0, extra)) + ((0, 0),) * (x.ndim - 2))
+
+
+def _split(x, block):
+    """``[B, n * block, ...]`` -> ``[n, B, block, ...]`` for a scan."""
+    B, n = x.shape[0], x.shape[1] // block
+    return jnp.moveaxis(x.reshape(B, n, block, *x.shape[2:]), 1, 0)
+
+
+def _join(x):
+    """``[n, B, block, ...]`` -> ``[B, n * block, ...]``."""
+    x = jnp.moveaxis(x, 0, 1)
+    return x.reshape(x.shape[0], x.shape[1] * x.shape[2], *x.shape[3:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _attention(block, band, scale, q, k, v, q_shared, k_shared):
+    return _forward(block, band, scale, q, k, v, q_shared, k_shared)[0]
+
+
+def _forward(block, band, scale, q, k, v, q_shared, k_shared):
+    S = q.shape[1]
+    padded = -(-S // block) * block
+    q, q_shared = _pad_queries(q, padded), _pad_queries(q_shared, padded)
+    outs, lses = [], []
+    for a, n, end in _bands(S, block, band):
+        qa, qsa = _after(
+            (q[:, a:a + n], q_shared[:, a:a + n]), outs[-1] if outs else None
+        )
+        kb, vb, ksb = k[:, :end], v[:, :end], k_shared[:, :end]
+
+        def one(_, x, a=a, kb=kb, vb=vb, ksb=ksb):
+            i, qb, qsb = x
+            s = _scores(a + i * block, scale, qb, kb, qsb, ksb)
+            m = jnp.max(s, axis=-1, keepdims=True)  # finite: a row holds itself
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)  # noqa: E741
+            out = jnp.einsum(
+                "bhqk,bkhd->bqhd", p / l, vb, preferred_element_type=jnp.float32
+            )
+            return None, (out, (m + jnp.log(l))[..., 0])
+
+        _, (out, lse) = jax.lax.scan(
+            one, None, (jnp.arange(n // block), _split(qa, block), _split(qsa, block))
+        )
+        outs.append(_join(out))
+        lses.append(jnp.moveaxis(_join(jnp.moveaxis(lse, 3, 2)), 1, 2))
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    lse = lses[0] if len(lses) == 1 else jnp.concatenate(lses, axis=2)
+    return out[:, :S], lse[:, :, :S]
+
+
+def _fwd(block, band, scale, q, k, v, q_shared, k_shared):
+    out, lse = _forward(block, band, scale, q, k, v, q_shared, k_shared)
+    return out, (q, k, v, q_shared, k_shared, out, lse)
+
+
+def _bwd(block, band, scale, res, d_out):
+    q, k, v, q_shared, k_shared, out, lse = res
+    f32 = jnp.float32
+    S = q.shape[1]
+    padded = -(-S // block) * block
+    # rowsum(dO * O) = rowsum(P * dP): the softmax's own term
+    delta = jnp.sum(d_out * out, axis=-1)  # [B, S, H]
+    q, q_shared, d_out, delta = (
+        _pad_queries(x, padded) for x in (q, q_shared, d_out, delta)
+    )
+    # a query of the padding has no row of its own: its probabilities are 0
+    lse = _pad_queries(jnp.moveaxis(lse, 1, 2), padded)  # [B, S, H]
+    if padded > S:
+        lse = lse.at[:, S:].set(jnp.inf)
+    dk, dv, dks = jnp.zeros_like(k), jnp.zeros_like(v), jnp.zeros_like(k_shared)
+    dq, dqs = [], []
+    for a, n, end in _bands(S, block, band):
+        xs = _after(
+            tuple(x[:, a:a + n] for x in (q, q_shared, d_out, delta, lse)),
+            (dq[-1], dqs[-1], dk, dv, dks) if dq else None,
+        )
+        kb, vb, ksb = k[:, :end], v[:, :end], k_shared[:, :end]
+
+        def one(carry, x, a=a, kb=kb, vb=vb, ksb=ksb):
+            dkb, dvb, dksb = carry
+            i, qb, qsb, do, dl, ls = x
+            s = _scores(a + i * block, scale, qb, kb, qsb, ksb)
+            p = jnp.exp(s - jnp.moveaxis(ls, 1, 2)[..., None])
+            dvb = dvb + jnp.einsum(
+                "bhqk,bqhd->bkhd", p, do, preferred_element_type=f32
+            )
+            dp = jnp.einsum("bqhd,bkhd->bhqk", do, vb, preferred_element_type=f32)
+            ds = p * (dp - jnp.moveaxis(dl, 1, 2)[..., None]) * scale
+            dqb = jnp.einsum("bhqk,bkhd->bqhd", ds, kb, preferred_element_type=f32)
+            dqsb = jnp.einsum("bhqk,bkd->bqhd", ds, ksb, preferred_element_type=f32)
+            dkb = dkb + jnp.einsum(
+                "bhqk,bqhd->bkhd", ds, qb, preferred_element_type=f32
+            )
+            dksb = dksb + jnp.einsum(
+                "bhqk,bqhd->bkd", ds, qsb, preferred_element_type=f32
+            )
+            return (dkb, dvb, dksb), (dqb, dqsb)
+
+        (dkb, dvb, dksb), (dqa, dqsa) = jax.lax.scan(
+            one, (jnp.zeros_like(kb), jnp.zeros_like(vb), jnp.zeros_like(ksb)),
+            (jnp.arange(n // block), *(_split(x, block) for x in xs)),
+        )
+        dk, dv, dks = (
+            acc.at[:, :end].add(part)
+            for acc, part in ((dk, dkb), (dv, dvb), (dks, dksb))
+        )
+        dq.append(_join(dqa))
+        dqs.append(_join(dqsa))
+    cat = lambda xs: (xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=1))[:, :S]  # noqa: E731
+    return cat(dq), dk, dv, cat(dqs), dks
+
+
+_attention.defvjp(_fwd, _bwd)
+
+
+def blocked_causal_attention(q, k, v, *, block: int, scale: float,
+                             band: int = 4, q_shared=None, k_shared=None):
+    """``q, k [B, S, H, D]``, ``v [B, S, H, Dv]`` -> ``[B, S, H, Dv]``
+    float32.  ``band``: blocks of ``block`` queries that share a key
+    prefix."""
+    B, S, H, _ = q.shape
+    if q_shared is None:  # a shared part of width 0 scores 0
+        q_shared = jnp.zeros((B, S, H, 0), q.dtype)
+        k_shared = jnp.zeros((B, S, 0), k.dtype)
+    return _attention(block, band, float(scale), q, k, v, q_shared, k_shared)

@@ -11,6 +11,15 @@ Rules (matching ``models/transformer.py`` param naming):
 - MLP up/gate sharded over d_ff, down over d_ff (Megatron-style pairing:
   column- then row-parallel, one allreduce per block);
 - norms, biases of row-parallel layers, and positional embeddings replicated.
+
+The layer-pattern body (``models/kimi_linear.py``) adds: a held share of
+routed experts ``[E, ...]`` sharded over ``model`` along the expert axis (the
+router and the shared expert's norms stay whole; the shared expert and the
+dense MLP pair as above); the delta-rule mixer's per-head parameters
+(convolutions, the low-rank projections' second halves, decay rates and
+biases, the ``b`` gate) over their head axis; latent attention's ``kv_b``
+over its heads, ``kv_a`` and the latent's norm whole (every head reads the
+whole latent).
 """
 
 from __future__ import annotations
@@ -33,6 +42,20 @@ def _spec_for(path: tuple[str, ...], value: Any) -> P:
         return P(MODEL_AXIS, None)  # vocab-row sharded (PS table scheme)
     if leaf == "pos_embedding":
         return P()
+    if parent == "experts":  # [E, d_model, width] / [E, width, d_model]
+        return P(MODEL_AXIS, None, None)
+    if leaf.startswith("conv_"):  # depthwise [width, heads, head_dim]
+        return P(None, MODEL_AXIS, None)
+    if leaf == "A_log":  # [heads]
+        return P(MODEL_AXIS)
+    if leaf == "dt_bias":  # [heads, head_dim]
+        return P(MODEL_AXIS, None)
+    if parent in ("f_b", "g_b", "kv_b"):
+        if leaf == "kernel":  # [rank, heads, head_dim]
+            return P(None, MODEL_AXIS, None)
+        return P(MODEL_AXIS, None)  # bias [heads, head_dim]
+    if parent == "b":  # [d_model, heads]
+        return P(None, MODEL_AXIS)
     if parent in ("q", "k", "v"):
         if leaf == "kernel":  # [d_model, heads, head_dim]
             return P(None, MODEL_AXIS, None)

@@ -115,6 +115,13 @@ def bucket_size(n: int, *, min_bucket: int = 256) -> int:
     return 1 << int(np.ceil(np.log2(n)))
 
 
+def leg_bucket(n: int) -> int:
+    """Bucket of one server's leg of a request (``n`` ids): next power of
+    two, >= 8 (the Pallas block floor).  The server pads a leg's ids and
+    values to it; a worker that hands device arrays over pads to the same."""
+    return bucket_size(max(n, 1), min_bucket=8)
+
+
 def localize_batch(
     keys: np.ndarray, *, pad_to_bucket: bool = True, min_bucket: int = 256
 ) -> Tuple[np.ndarray, np.ndarray, int]:

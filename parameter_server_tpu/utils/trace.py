@@ -63,9 +63,12 @@ SPANS = frozenset({
     "ps.server.h2d",
     "ps.server.dispatch",
     "ps.server.d2h",
-    # hybrid learner (learner/hybrid.py)
+    # hybrid learner (learner/hybrid.py): the root of a step, then its parts
+    "ps.hybrid.step",
     "ps.hybrid.pull_wait",
     "ps.hybrid.body_dispatch",
+    "ps.hybrid.push_submit",
+    "ps.hybrid.prefetch",
     "ps.hybrid.loss_sync",
 })
 
@@ -216,6 +219,9 @@ class _NullSpan:
     """What :meth:`Tracer.span` hands out when nothing records."""
 
     __slots__ = ()
+    #: whether anything keeps what :meth:`set` is given (an attribute that
+    #: costs something to compute is computed only then)
+    recording = False
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -235,6 +241,7 @@ class _Span:
     captures (``ann``), a record in ``tracer`` when that is enabled."""
 
     __slots__ = ("_tracer", "_name", "_attrs", "_ann", "_start", "_cpu0")
+    recording = True
 
     def __init__(self, tracer, name: str, attrs: dict, ann) -> None:
         self._tracer = tracer
