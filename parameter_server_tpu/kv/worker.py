@@ -107,6 +107,15 @@ def _assemble_device(positions, rows, inverse, *, n_slots: int, dim: int, dtype)
 
 
 @jax.jit
+def _gather_rows(plane, inverse):
+    """``plane[inverse]`` on device: a pull's unique rows -> one row a
+    requested position, in ``inverse``'s shape.  One program a (bucketed
+    plane, key shape) pair: the plane is never a leg's true row count."""
+    with jax.named_scope("ps.worker.assemble"):
+        return jnp.take(plane, inverse, axis=0, mode="clip")
+
+
+@jax.jit
 def _take_rows(plane, idx):
     """``plane[idx]`` on device, zeros where ``idx`` is past the plane: a
     leg of a device push, padded to its bucket."""
@@ -188,6 +197,17 @@ class KVWorker(Customer):
         }
         #: per-timestamp reassembly info for pulls
         self._pull_plans: Dict[int, dict] = {}
+        #: (n_slots, dim, dtype) -> [host plane, device arrays that last read
+        #: it]: the staging plane host replies are laid into before their
+        #: one upload (:meth:`_assemble_host_replies`), reused across pulls
+        #: of a bucket so no pull faults fresh megabytes in
+        self._stage: Dict[tuple, list] = {}
+        self._stage_lock = threading.Lock()
+        #: where pulls were assembled / how many pushes were combined from a
+        #: gradient that arrived on the chip (:meth:`counters`)
+        self.pull_assembled_device = 0
+        self.pull_assembled_host = 0
+        self.push_combined_from_device = 0
         #: deadline-retry counters (surfaced next to transport counters)
         self.pull_retries = 0
         self.push_retries = 0
@@ -366,6 +386,9 @@ class KVWorker(Customer):
             "busy_hints": self.busy_hints,
             "trace_samples": self.trace_samples,
             "trace_closed": self.trace_closed,
+            "pull_assembled_device": self.pull_assembled_device,
+            "pull_assembled_host": self.pull_assembled_host,
+            "push_combined_from_device": self.push_combined_from_device,
         }
         if self._group is not None:
             out.update(
@@ -1202,18 +1225,43 @@ class KVWorker(Customer):
         ))
 
     def _prepare_push(self, table: str, keys, values):
-        """Host half of a push: localize + device duplicate pre-combine."""
+        """Worker half of a push: localize, then combine duplicates on
+        ``self.device``; the combined ``[slots, dim]`` plane comes back to
+        the host for the wire.
+
+        Where the gradient is combined from is read off ``values``: a
+        ``jax.Array`` (a step's output) is placed on ``self.device`` (a
+        no-op when it was computed there), cast and reshaped there, and
+        never crosses to the host whole; anything else is made a NumPy
+        array and uploaded.  The combine program and its operand shapes are
+        the same either way, so the plane is bit-identical."""
         cfg = self.table_cfgs[table]
-        vals = np.asarray(values, dtype=cfg.dtype).reshape(keys.size, cfg.dim)
+        on_device = isinstance(values, jax.Array)
+        if on_device:
+            vals = jax.device_put(values, self.device)
+            vals = vals.astype(cfg.dtype).reshape(keys.size, cfg.dim)
+        else:
+            vals = np.asarray(values, dtype=cfg.dtype).reshape(
+                keys.size, cfg.dim
+            )
         slots, inverse = self._localize(table, keys)
-        with self.tracer.span("ps.worker.combine", unique=slots.shape[0]):
+        with self.tracer.span(
+            "ps.worker.combine", unique=slots.shape[0],
+            where="device" if on_device else "host",
+        ) as sp:
             combined = np.asarray(
                 _segment_combine(
                     jax.device_put(inverse, self.device),
-                    jax.device_put(vals, self.device),
+                    vals if on_device else jax.device_put(vals, self.device),
                     slots.shape[0],
                 )
             )
+            sp.set(
+                h2d_bytes=inverse.nbytes + (0 if on_device else vals.nbytes),
+                d2h_bytes=combined.nbytes,
+            )
+        if on_device:
+            self.push_combined_from_device += 1
         return slots, combined
 
     def _localize(self, table: str, keys) -> Tuple[np.ndarray, np.ndarray]:
@@ -1244,8 +1292,10 @@ class KVWorker(Customer):
         """Push per-position gradient rows for ``keys``.  Returns timestamp.
 
         ``values`` has shape ``[len(keys), dim]`` (or ``[len(keys)]`` for
-        dim=1 tables).  Fire-and-forget: cannot observe routing fences —
-        under live migration use :meth:`push_sync`.
+        dim=1 tables), as a NumPy array or as a ``jax.Array``: a device
+        array is combined where it is and only the combined plane crosses to
+        the host (:meth:`_prepare_push`).  Fire-and-forget: cannot observe
+        routing fences — under live migration use :meth:`push_sync`.
 
         With a :class:`~parameter_server_tpu.kv.routing.WorkerGroup` the
         push routes through the group pre-reduction instead (ISSUE 15):
@@ -1284,10 +1334,14 @@ class KVWorker(Customer):
             cfg = self.table_cfgs[table]
             vals = values.reshape(keys.size, cfg.dim)
             slots, inverse = self._localize(table, keys)
-            with self.tracer.span("ps.worker.combine", unique=slots.shape[0]):
+            with self.tracer.span(
+                "ps.worker.combine", unique=slots.shape[0], where="device",
+                h2d_bytes=inverse.nbytes, d2h_bytes=0,
+            ):
                 combined = _segment_combine(
                     jnp.asarray(inverse), vals, slots.shape[0]
                 )
+            self.push_combined_from_device += 1
             ts, _ = self._submit_push(table, slots, combined, tctx=tctx)
             return ts
 
@@ -1623,41 +1677,30 @@ class KVWorker(Customer):
             return rows
         return None
 
-    def pull_result(self, ts: int, timeout: Optional[float] = None) -> np.ndarray:
+    def pull_result(self, ts: int, timeout: Optional[float] = None):
         """Block for pull ``ts`` and reassemble per-position weight rows.
 
         Output shape: ``keys.shape + (dim,)`` for dim>1 tables, ``keys.shape``
         for dim=1.
+
+        What comes back is decided by the table's row width.  Rows of a
+        dim>1 table are a ``jax.Array`` on ``self.device``, the chip the
+        step that consumes them runs on: the replies are uploaded once as
+        the bucketed plane of unique rows and gathered to positions there
+        (:meth:`_assemble`), so ``jax.device_put(rows, kv.device)`` is a
+        no-op and ``np.asarray(rows)`` reads them on the host.  Scalar rows
+        (dim 1) are assembled on the host and come back as a NumPy array:
+        the chip gathers single floats more slowly than the host does
+        (``PERF.md`` section 6, PR 29).  The values are the same bit for
+        bit either way.
         """
         plan, pairs = self._pull_pairs(ts, timeout)
-        cfg = self.table_cfgs[plan["table"]]
-        with self.tracer.span(
-            "ps.worker.assemble", legs=len(pairs), rows=plan["n_slots"]
-        ):
-            sole = self._sole_full_pair(pairs, plan["n_slots"])
-            if sole is not None:
-                # dtype= is a no-op passthrough when the reply already
-                # matches (the normal case); only an off-dtype reply pays a
-                # cast copy
-                uniq_rows = np.asarray(sole, dtype=cfg.dtype).reshape(
-                    -1, cfg.dim
-                )[: plan["n_slots"]]
-            else:
-                uniq_rows = np.zeros(
-                    (plan["n_slots"], cfg.dim), dtype=cfg.dtype
-                )
-                for pos, rows, *_meta in pairs:
-                    # a device reply is padded to its leg's bucket
-                    uniq_rows[pos] = np.asarray(rows).reshape(
-                        -1, cfg.dim
-                    )[: len(pos)]
-            out = uniq_rows[plan["inverse"]]
-        if cfg.dim == 1:
-            return out.reshape(plan["shape"])
-        return out.reshape(plan["shape"] + (cfg.dim,))
+        on_host = self.table_cfgs[plan["table"]].dim == 1
+        return self._assemble(plan, pairs, on_host=on_host)
 
     def pull_result_device(self, ts: int, timeout: Optional[float] = None):
-        """Like :meth:`pull_result` but assembles rows ON DEVICE.
+        """Like :meth:`pull_result` but assembles rows ON DEVICE whatever
+        the row width.
 
         Servers replying with device arrays (``KVServer(device_replies=
         True)``) never touch host memory; numpy replies are uploaded once.
@@ -1665,44 +1708,154 @@ class KVWorker(Customer):
         ``keys.shape`` for dim=1).
         """
         plan, pairs = self._pull_pairs(ts, timeout)
+        return self._assemble(plan, pairs, on_host=False)
+
+    def _assemble(self, plan: dict, pairs: list, *, on_host: bool):
+        """A pull's replies -> one row a requested position.
+
+        ``on_host``: today's NumPy assembly (scalar rows).  Otherwise the
+        result is a ``jax.Array`` on ``self.device``: device replies are
+        scattered and gathered there (:meth:`_assemble_device_replies`),
+        host replies are uploaded as one bucketed plane and gathered by
+        ``inverse`` (:meth:`_assemble_host_replies`).  The span's
+        ``h2d_bytes`` / ``d2h_bytes`` count the rows and the inverse that
+        cross, not the legs' few position indices."""
         cfg = self.table_cfgs[plan["table"]]
+        shape = plan["shape"] if cfg.dim == 1 else plan["shape"] + (cfg.dim,)
+        inverse = np.asarray(plan["inverse"], np.int32)
+        on_chip = sum(
+            rows.nbytes for _p, rows, *_m in pairs if isinstance(rows, jax.Array)
+        )
         with self.tracer.span(
-            "ps.worker.assemble", legs=len(pairs), rows=plan["n_slots"]
-        ):
-            sole = self._sole_full_pair(pairs, plan["n_slots"])
-            # replies from servers on other chips cross to this worker's here
-            dtype = jnp.dtype(cfg.dtype)
-            if sole is not None:
-                uniq = jax.device_put(sole, self.device)
-                uniq = uniq.astype(dtype).reshape(-1, cfg.dim)
-                out = jnp.take(uniq, jnp.asarray(plan["inverse"]), axis=0)
+            "ps.worker.assemble", legs=len(pairs), rows=plan["n_slots"],
+            where="host" if on_host else "device",
+        ) as sp:
+            if on_host:
+                self.pull_assembled_host += 1
+                out = self._assemble_host(plan, pairs, cfg)
+                sp.set(h2d_bytes=0, d2h_bytes=on_chip)
+            elif on_chip:
+                self.pull_assembled_device += 1
+                out = self._assemble_device_replies(plan, pairs, cfg, inverse)
+                from_host = sum(
+                    rows.nbytes for _p, rows, *_m in pairs
+                    if not isinstance(rows, jax.Array)
+                )
+                sp.set(h2d_bytes=from_host + inverse.nbytes, d2h_bytes=0)
             else:
-                # one compiled program a (leg buckets, slots) shape, the legs
-                # in the order of their positions whichever reply came
-                # first.  A device reply's rows are padded to the leg's
-                # bucket; its positions are padded to match with an index
-                # the scatter drops.
-                legs = sorted(
-                    ((np.asarray(pos, np.int32), rows) for pos, rows, *_m in pairs),
-                    key=lambda leg: int(leg[0][0]) if leg[0].size else -1,
-                )
-                out = _assemble_device(
-                    tuple(
-                        _pad_index(pos, max(rows.shape[0], pos.shape[0]),
-                                   plan["n_slots"])
-                        for pos, rows in legs
-                    ),
-                    tuple(jax.device_put(rows, self.device) for _pos, rows in legs),
-                    np.asarray(plan["inverse"], np.int32),
-                    n_slots=plan["n_slots"], dim=cfg.dim, dtype=dtype,
-                )
-        if cfg.dim == 1:
-            return out.reshape(plan["shape"])
-        return out.reshape(plan["shape"] + (cfg.dim,))
+                self.pull_assembled_device += 1
+                out = self._assemble_host_replies(plan, pairs, cfg, inverse)
+                plane = plan["n_slots"] * cfg.dim * out.dtype.itemsize
+                sp.set(h2d_bytes=plane + inverse.nbytes, d2h_bytes=0)
+        return out.reshape(shape)  # a no-op where the gather wrote ``shape``
+
+    def _assemble_host(self, plan: dict, pairs: list, cfg) -> np.ndarray:
+        """``uniq[inverse]`` in NumPy."""
+        sole = self._sole_full_pair(pairs, plan["n_slots"])
+        if sole is not None:
+            # dtype= is a no-op passthrough when the reply already matches
+            # (the normal case); only an off-dtype reply pays a cast copy
+            uniq_rows = np.asarray(sole, dtype=cfg.dtype).reshape(
+                -1, cfg.dim
+            )[: plan["n_slots"]]
+        else:
+            uniq_rows = np.zeros((plan["n_slots"], cfg.dim), dtype=cfg.dtype)
+            for pos, rows, *_meta in pairs:
+                # a device reply is padded to its leg's bucket
+                uniq_rows[pos] = np.asarray(rows).reshape(
+                    -1, cfg.dim
+                )[: len(pos)]
+        return uniq_rows[plan["inverse"]]
+
+    def _assemble_device_replies(
+        self, plan: dict, pairs: list, cfg, inverse: np.ndarray
+    ):
+        """Device replies -> ``[keys, dim]`` rows on ``self.device``."""
+        sole = self._sole_full_pair(pairs, plan["n_slots"])
+        # replies from servers on other chips cross to this worker's here
+        dtype = jnp.dtype(cfg.dtype)
+        if sole is not None:
+            uniq = jax.device_put(sole, self.device)
+            uniq = uniq.astype(dtype).reshape(-1, cfg.dim)
+            return jnp.take(uniq, jnp.asarray(inverse), axis=0)
+        # one compiled program a (leg buckets, slots) shape, the legs in the
+        # order of their positions whichever reply came first.  A device
+        # reply's rows are padded to the leg's bucket; its positions are
+        # padded to match with an index the scatter drops.
+        legs = sorted(
+            ((np.asarray(pos, np.int32), rows) for pos, rows, *_m in pairs),
+            key=lambda leg: int(leg[0][0]) if leg[0].size else -1,
+        )
+        return _assemble_device(
+            tuple(
+                _pad_index(pos, max(rows.shape[0], pos.shape[0]),
+                           plan["n_slots"])
+                for pos, rows in legs
+            ),
+            tuple(jax.device_put(rows, self.device) for _pos, rows in legs),
+            inverse,
+            n_slots=plan["n_slots"], dim=cfg.dim, dtype=dtype,
+        )
+
+    def _assemble_host_replies(
+        self, plan: dict, pairs: list, cfg, inverse: np.ndarray
+    ):
+        """Host replies -> per-position rows on ``self.device``, in the
+        caller's shape.
+
+        The replies are uploaded once, as the bucketed plane of unique rows
+        (``[n_slots, dim]``, ``[n_slots]`` for scalar rows) whatever the
+        legs' true row counts, and gathered by ``inverse`` there.  Slots are
+        sorted and range-partitioned and the pads sit at the end, so a
+        leg's positions are one ascending run and its rows a slice copy
+        into the staging plane; a leg that is not one run (a server owning
+        several ranges, a fence retry's subset, a cache shed) is assigned by
+        index.  Positions no reply covers read zero, as on the host.  The
+        staging plane is reused across pulls of a bucket and rewritten only
+        once the transfer that read it, and the gather that read the
+        uploaded plane (a backend may alias host memory), have completed."""
+        n, dim = plan["n_slots"], cfg.dim
+        flat = (n,) if dim == 1 else (n, dim)
+        # the gather writes the caller's shape: no reshape program after it
+        inverse = jax.device_put(inverse.reshape(plan["shape"]), self.device)
+        sole = self._sole_full_pair(pairs, n)
+        if sole is not None:
+            # the reply's rows are the plane: no staging copy
+            host = np.asarray(sole, dtype=cfg.dtype).reshape(-1, dim)[:n]
+            plane = jax.device_put(host.reshape(flat), self.device)
+            return _gather_rows(plane, inverse)
+        with self._stage_lock:
+            stage = self._stage.setdefault(
+                (n, dim, np.dtype(cfg.dtype).str),
+                [np.empty((n, dim), dtype=cfg.dtype), ()],
+            )
+            host, readers = stage
+            for arr in readers:
+                if not arr.is_deleted():
+                    arr.block_until_ready()
+            if sum(len(pos) for pos, *_r in pairs) < n:
+                host[:] = 0
+            for pos, rows, *_meta in pairs:
+                k = len(pos)
+                if not k:
+                    continue
+                rows = np.asarray(rows, dtype=cfg.dtype).reshape(-1, dim)[:k]
+                lo = int(pos[0])
+                if np.array_equal(pos, np.arange(lo, lo + k)):
+                    host[lo:lo + k] = rows
+                else:
+                    host[pos] = rows
+            plane = jax.device_put(host.reshape(flat), self.device)
+            out = _gather_rows(plane, inverse)
+            stage[1] = (plane, out)
+        return out
 
     def pull_sync(
         self, table: str, keys: np.ndarray, timeout: Optional[float] = None
-    ) -> np.ndarray:
+    ):
+        """:meth:`pull` + :meth:`pull_result`: rows of a dim>1 table come
+        back as a ``jax.Array`` on ``self.device``, scalar rows (dim 1) as a
+        NumPy array; ``np.asarray`` reads either."""
         with self.tracer.span(
             "ps.worker.pull", table=table, keys=int(keys.size)
         ):
@@ -1829,6 +1982,11 @@ class KVWorker(Customer):
     ) -> int:
         """Push and block for all server acks, retrying once on deadline and
         looping on routing fences.
+
+        ``values`` may be a NumPy array or the step's ``jax.Array``: a
+        device array is combined on ``self.device`` as it stands and only
+        the combined plane is brought to the host for the wire
+        (:meth:`_prepare_push`); the wire bytes are the same either way.
 
         The deadline path mirrors :meth:`pull_result`: the stuck task is
         cancelled (no leaked ``_pending`` state) and the push re-issued

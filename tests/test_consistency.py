@@ -221,9 +221,15 @@ def test_ssp_gate_parks_fast_worker_until_release():
 
         th = threading.Thread(target=fast, daemon=True)
         th.start()
-        time.sleep(0.5)
-        assert not done.is_set(), "worker A outran the bound ungated"
+        # A's first steps compile: wait for the gate's first defer, not for
+        # a stretch of wall clock that a loaded host outlasts
+        deadline = time.monotonic() + 30
+        while wa.consist_waits == 0 and time.monotonic() < deadline:
+            assert not done.is_set(), "worker A outran the bound ungated"
+            time.sleep(0.01)
         assert wa.consist_waits > 0
+        time.sleep(0.2)  # parked, not merely deferred once
+        assert not done.is_set(), "worker A outran the bound ungated"
         _step(wb, KEYS, GRADS)  # the straggler commits: fleet_min -> 1
         assert done.wait(10), "gate never released after the fleet advanced"
         th.join(timeout=5)
