@@ -480,8 +480,7 @@ class TraceConfig:
     #: master switch; False stamps no contexts at all (the predicate the
     #: hot path is gated behind — see tools/check_wrappers.py).
     enabled: bool = True
-    #: trace 1-in-N requests.  1 = every request (tests), 0 = never;
-    #: 1024 is the default the bench gate holds to ≤3% overhead.
+    #: trace 1-in-N requests.  1 = every request (tests), 0 = never.
     sample_every: int = 1024
     #: seed folded into the sampling hash; replays with the same seed
     #: sample the same trace ids.

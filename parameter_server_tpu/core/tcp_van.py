@@ -872,7 +872,7 @@ class TcpVan(Van):
 
     # Payload egress/ingress regardless of medium: socket bytes PLUS frames
     # that rode a colocated shm ring.  Byte-accounting flows (launch result
-    # JSON, bench plane-overlap arm) must use these — with shm negotiated,
+    # JSON) must use these — with shm negotiated,
     # bytes_sent() alone reads near zero because data frames bypass the
     # socket entirely, while wire filters still compress ring frames.
     def payload_bytes_sent(self) -> int:

@@ -186,8 +186,7 @@ class HybridLMTrainer:
         #: body parameter count (held here).  ``n_active_params`` is what the
         #: MFU column's 6ND rule takes (fwd+bwd train FLOPs ~ 6 x params a
         #: token multiplies with x tokens): equal to it for a dense body, less
-        #: for one with experts.  Public: bench --hybrid reuses them so the
-        #: two MFU computations cannot drift.
+        #: for one with experts.
         self.n_body_params = sum(
             int(np.prod(p.shape)) for p in jax.tree.leaves(self.params)
         )

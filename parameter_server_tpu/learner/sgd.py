@@ -7,7 +7,7 @@ Two drivers over the same model math (``models/linear.py``):
 
 - :class:`LocalLRTrainer` — single-process fast path: the table lives on the
   local device and each step is one fused XLA program (BASELINE config
-  #1; what ``bench.py``'s default mode times).
+  #1).
 - :class:`AsyncLRLearner` — the classic PS topology over the Van: N worker
   threads pull/push through :class:`~parameter_server_tpu.kv.worker.KVWorker`
   under a :class:`~parameter_server_tpu.core.clock.ConsistencyController`

@@ -10,8 +10,8 @@ materialized, so a 7B-param program analyzes fine on a dev box — and read
 XLA's own ``memory_analysis()`` for the per-device argument/temp/output
 budget.
 
-Run as a module for the out-of-process entry the bench uses (a 16-device
-virtual CPU topology must be fixed before jax initializes):
+Run as a module, out of process (a 16-device virtual CPU topology must be
+fixed before jax initializes):
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=16 \
       python -m parameter_server_tpu.parallel.feasibility --preset llama3-8b

@@ -85,8 +85,8 @@ def _add_fsdp_axis(spec: P, shape, data_n: int, axis: str) -> P:
     split over the ``data`` axis instead of being replicated per data
     replica; XLA all-gathers them at use and reduce-scatters the gradient.
     The scaling-book recipe for fitting an 8B train state on a v5e-16 —
-    TP-8 alone leaves params+moments+grads at ~15 GB/device (measured,
-    BASELINE.md), over the 16 GB HBM.
+    TP-8 alone leaves params+moments+grads at ~15 GB/device (compiled:
+    ``parallel/feasibility.py``), over the 16 GB HBM.
     """
     parts = list(spec) + [None] * (len(shape) - len(spec))
     for i, (p, s) in enumerate(zip(parts, shape)):

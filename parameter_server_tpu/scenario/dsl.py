@@ -383,7 +383,7 @@ def smoke_scenario(seed: int = 0) -> Scenario:
 
 
 def reference_scenario(seed: int = 0) -> Scenario:
-    """The BASELINE.md reference drill: 50 nodes, flash crowd + one gray
+    """The reference drill: 50 nodes, flash crowd + one gray
     failure + one partition-then-heal (the ISSUE 19 acceptance shape)."""
     return Scenario(
         name="reference-50",

@@ -8,8 +8,8 @@ accounting, so out-of-order frames and clock offsets are already
 handled), plus the ground-truth totals the availability number alone
 hides — bytes migrated, requests shed, fence rejects, frames the
 partitions ate.  Serialize with :func:`scorecard_json` — key-sorted,
-rounded — so two same-seed runs emit byte-identical JSON (the
-``bench.py --wargame`` reproducibility gate diffs exactly that string).
+rounded — so two same-seed runs emit byte-identical JSON
+(``tests/test_scenario.py`` diffs exactly that string).
 
 :func:`render_report` is the human half: a worked incident report that
 finds the WORST breach window and auto-attaches (a) the flight-recorder

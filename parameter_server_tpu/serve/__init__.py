@@ -4,7 +4,7 @@ Layers a model-serving surface over the training substrate: hot-row
 caching with version-clock invalidation lives in ``kv/cache.py`` (it is a
 KV concern), while this package holds what is serving-specific —
 SLO-driven admission control (:mod:`.admission`) and the open-loop
-synthetic load generator (:mod:`.loadgen`) behind ``bench.py --serve``.
+synthetic load generator (:mod:`.loadgen`).
 """
 
 from parameter_server_tpu.serve.admission import AdmissionController, ShedError

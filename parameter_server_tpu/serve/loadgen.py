@@ -38,7 +38,7 @@ from parameter_server_tpu.utils.trace import LatencyHistogram
 
 @dataclasses.dataclass
 class LoadReport:
-    """One run's serving scorecard (the ``bench.py --serve`` record body)."""
+    """One run's serving scorecard."""
 
     pulls: int
     served: int

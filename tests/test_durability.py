@@ -111,9 +111,13 @@ def _push_keys(worker, keys, *, seed, dim=DIM):
 # ------------------------------------------------- 1. reshard-restore parity
 
 
+@pytest.mark.parametrize("incremental", [False, True], ids=["full", "chain"])
 def test_rebalanced_snapshot_restores_to_any_fleet_shape(
-    tmp_path, record_property
+    tmp_path, record_property, incremental
 ):
+    """``chain``: the restore point is an incremental snapshot that carries
+    the untouched segments' files from its base by reference, and another
+    fleet shape reads them through that reference."""
     record_property("chaos_seed", SEED)
     van = LoopbackVan()
     try:
@@ -126,10 +130,17 @@ def test_rebalanced_snapshot_restores_to_any_fleet_shape(
         assert worker.adopt_routing(new_routing)
         _push(worker, seed=SEED + 1)
 
-        summary = worker.save_snapshot(str(tmp_path), 7)
-        assert summary["segments"] == len(
-            worker.routing.tables["w"].segments()
+        segments = worker.routing.tables["w"].segments()
+        if incremental:
+            worker.save_snapshot(str(tmp_path), 6)
+            # writes confined to one segment: the others' clocks stand still
+            hot = _keys_hashing_into(segments[0][0], segments[0][1], 24)
+            _push_keys(worker, hot, seed=SEED + 3)
+        summary = worker.save_snapshot(
+            str(tmp_path), 7, base_step=6 if incremental else None
         )
+        assert summary["segments"] == len(segments)
+        assert summary["carried"] == (len(segments) - 1 if incremental else 0)
         ref = np.asarray(worker.pull_sync("w", keys, timeout=30))
 
         extra = np.random.RandomState(SEED + 2).randn(

@@ -1,8 +1,8 @@
 """Memory-feasibility machinery: chunked loss, trunk seam, FSDP shardings.
 
-The 8B numbers themselves are recorded by ``bench.py --llama8b`` (minutes of
-XLA compile); these tests prove the machinery at toy scale on the 8-device
-mesh so regressions can't silently invalidate the recorded table.
+The 8B numbers themselves come from ``python -m
+parameter_server_tpu.parallel.feasibility`` (minutes of XLA compile); these
+tests prove the machinery at toy scale on the 8-device mesh.
 """
 
 import numpy as np

@@ -568,6 +568,39 @@ def test_frame_nbytes_is_exact():
         assert overhead == frame.peek(buf).overhead
 
 
+_STAMPS = {
+    resender_mod.SEQ_KEY: 123457, INCARNATION_KEY: 2,
+    resender_mod.CRC_KEY: 0xDEADBEEF,
+}
+
+
+@pytest.mark.parametrize(
+    "msg,overhead",
+    [
+        (_msg(task=Task(TaskKind.PUSH, "kv", payload={"table": "w", **_STAMPS}),
+              keys=np.arange(128, dtype=np.uint64),
+              values=[np.zeros((128, 8), np.float32)]), 153),
+        (_msg(task=Task(TaskKind.PULL, "kv", payload={"table": "w", **_STAMPS}),
+              keys=np.arange(1024, dtype=np.uint64), values=[]), 128),
+        (_msg(task=Task(TaskKind.CONTROL, "__resender__",
+                        payload={resender_mod.ACK_KEY: 123457,
+                                 INCARNATION_KEY: 2}),
+              sender="S0", recver="W0", keys=None, values=[],
+              is_request=False), 128),
+    ],
+    ids=["stamped_push", "stamped_pull_request", "resender_ack"],
+)
+def test_per_message_overhead_bytes(msg, overhead):
+    """What a message costs on the wire beyond its planes (the README's
+    Wire format section quotes these): the 52-byte header, the meta
+    section, one (dtype, shape) record a plane; the resender's stamps ride
+    the header and cost nothing."""
+    buf = frame.encode(msg)
+    info = frame.peek(buf)
+    assert info.overhead == overhead == len(buf) - info.planes_len
+    assert info.overhead == frame.HEADER_SIZE + info.meta_len
+
+
 def test_payload_crc32_matches_header_plane_crc_for_plain_arrays():
     """Same bytes, two vantage points: the resender's zero-copy end-to-end
     CRC over (keys, values) equals the header's plane CRC when no filter

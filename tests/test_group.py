@@ -226,20 +226,22 @@ def _inbound_push(metered):
     return tot
 
 
-def test_group_push_applies_sum_once_with_fewer_requests():
+@pytest.mark.parametrize("size", [2, 4])
+def test_group_push_applies_sum_once_with_fewer_requests(size):
     cfgs = _cfgs()
     keys = np.array([1, 5, 9, ROWS + 7], dtype=np.int64)
     # integer-valued grads: float addition is exact, so the group arm's
     # summed apply must match the direct arm's sequential applies BITWISE
     grads = [
-        np.full((keys.size, 2), 1.0, np.float32),
-        np.full((keys.size, 2), 2.0, np.float32),
+        np.full((keys.size, 2), float(i + 1), np.float32) for i in range(size)
     ]
 
     def run(grouped):
-        names = ("W0", "W1")
+        names = tuple(f"W{i}" for i in range(size))
         group = WorkerGroup(members=names) if grouped else None
-        gcfg = GroupConfig(size=2, fallback_timeout=10.0) if grouped else None
+        gcfg = (
+            GroupConfig(size=size, fallback_timeout=10.0) if grouped else None
+        )
         van, metered, servers, workers = _cluster(
             cfgs, names, group=group, group_cfg=gcfg
         )
@@ -262,14 +264,16 @@ def test_group_push_applies_sum_once_with_fewer_requests():
     grouped = run(True)
     # parity: sgd lr=1 applied the exact gradient sum either way
     np.testing.assert_array_equal(direct["delta"], grouped["delta"])
-    np.testing.assert_array_equal(grouped["delta"], -3.0 * np.ones((4, 2)))
+    np.testing.assert_array_equal(
+        grouped["delta"], -sum(range(1, size + 1)) * np.ones((4, 2))
+    )
     # one logical apply for the whole group, booked with its fan-in
     assert grouped["pushes"] == grouped["group_pushes"]
-    assert grouped["group_members"] == 2 * grouped["group_pushes"]
+    assert grouped["group_members"] == size * grouped["group_pushes"]
     assert direct["group_pushes"] == 0
-    # the wire saw HALF the PUSH requests (and bytes, same keys)
-    assert grouped["push"]["msgs"] * 2 == direct["push"]["msgs"]
-    assert grouped["push"]["bytes"] * 2 == direct["push"]["bytes"]
+    # the wire saw 1/size of the PUSH requests (and bytes, same keys)
+    assert grouped["push"]["msgs"] * size == direct["push"]["msgs"]
+    assert grouped["push"]["bytes"] * size == direct["push"]["bytes"]
     # clean path: nobody degraded
     assert all(
         c.get("group_fallbacks", 0) == 0

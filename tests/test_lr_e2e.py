@@ -1,5 +1,7 @@
 """End-to-end sparse LR convergence tests (SURVEY.md §4 golden-convergence)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -93,6 +95,68 @@ def test_async_learner_all_modes_converge(mode, delay):
         losses = learner.run([d.next_batch for d in data], steps_per_worker=20)
         assert len(losses) == 40
         assert np.mean(losses[-8:]) < np.mean(losses[:8]) - 0.03
+    finally:
+        van.close()
+
+
+@pytest.mark.parametrize(
+    "mode,delay", [(ConsistencyMode.BSP, 0), (ConsistencyMode.SSP, 2)]
+)
+def test_four_jittered_workers_reach_heldout_auc(mode, delay):
+    """Four workers whose batches arrive late now and then (a seeded 2 % of
+    steps sleep 5 ms) train two AdaGrad shards under the gate; a fifth
+    worker that never trains pulls held-out keys and must rank them."""
+    n_workers, n_servers, steps, batch = 4, 2, 60, 256
+    cfgs = {"w": _table_cfg(rows=1 << 17, lr=0.1)}
+
+    def stream(seed, batch_size=batch):
+        return SyntheticCTR(
+            key_space=1 << 18, nnz=16, batch_size=batch_size, seed=seed,
+            informative=0.3,
+        )
+
+    van = LoopbackVan()
+    try:
+        for s in range(n_servers):
+            KVServer(Postoffice(f"S{s}", van), cfgs, s, n_servers)
+        workers = [
+            KVWorker(Postoffice(f"W{i}", van), cfgs, n_servers)
+            for i in range(n_workers)
+        ]
+        eval_kv = KVWorker(Postoffice("WE", van), cfgs, n_servers)
+        streams = [stream(100 + i) for i in range(n_workers)]
+        jitter = [np.random.default_rng(1000 + i) for i in range(n_workers)]
+
+        def batch_fn(i):
+            def fn():
+                if jitter[i].random() < 0.02:
+                    time.sleep(0.005)
+                return streams[i].next_batch()
+
+            return fn
+
+        held_out = stream(9999, batch_size=2048)
+        eval_batches = [held_out.next_batch() for _ in range(4)]
+
+        def heldout_auc():
+            scores, ys = [], []
+            for keys, labels in eval_batches:
+                w_pos = np.asarray(eval_kv.pull_sync("w", keys, timeout=60))
+                scores.append(w_pos.reshape(keys.shape).sum(axis=1))
+                ys.append(labels)
+            return auc(np.concatenate(ys), np.concatenate(scores))
+
+        before = heldout_auc()
+        learner = AsyncLRLearner(
+            workers, ConsistencyConfig(mode=mode, max_delay=delay)
+        )
+        losses = learner.run(
+            [batch_fn(i) for i in range(n_workers)], steps, timeout=120.0
+        )
+        assert len(losses) == n_workers * steps
+        assert np.all(np.isfinite(losses))
+        after = heldout_auc()
+        assert after > 0.70 and after > before, (before, after)
     finally:
         van.close()
 

@@ -13,6 +13,7 @@ from parameter_server_tpu.config import (
 )
 from parameter_server_tpu.core.postoffice import Postoffice
 from parameter_server_tpu.core.van import LoopbackVan
+from parameter_server_tpu.data.synthetic import SyntheticImages
 from parameter_server_tpu.kv.dense import (
     DenseKVServer,
     DenseKVWorker,
@@ -108,5 +109,68 @@ def test_async_dense_learner_bsp():
         losses = learner.run(data, steps_per_worker=8)
         assert len(losses) == 16
         assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.1
+    finally:
+        van.close()
+
+
+def test_async_dense_learner_classifies_heldout_images():
+    """Four workers train a norm-free CNN over the dense async plane from
+    their own image streams; a fifth worker that never trains pulls the
+    servers' current parameters, which must classify held-out images."""
+    import flax.linen as nn
+    import jax.numpy as jnp
+
+    class TinyCNN(nn.Module):
+        @nn.compact
+        def __call__(self, x, train=False):
+            x = nn.relu(nn.Conv(16, (3, 3), strides=2)(x))
+            x = nn.relu(nn.Conv(32, (3, 3), strides=2)(x))
+            return nn.Dense(10)(x.mean(axis=(1, 2)))
+
+    n_workers, n_servers, steps = 4, 2, 40
+    model = TinyCNN()
+    held_out = SyntheticImages(seed=9999, noise=0.8)
+    images, labels = zip(*[held_out.next_batch() for _ in range(4)])
+    images, labels = jnp.asarray(np.concatenate(images)), np.concatenate(labels)
+    streams = [
+        SyntheticImages(seed=100 + i, noise=0.8, batch_size=64)
+        for i in range(n_workers)
+    ]
+    van = LoopbackVan()
+    try:
+        example = streams[0].next_batch()
+        variables = model.init(
+            jax.random.PRNGKey(0), jnp.asarray(example[0][:1]), train=False
+        )
+        total = PytreeCodec(variables["params"]).total
+        workers = [
+            DenseKVWorker(Postoffice(f"W{i}", van), {"model": total}, n_servers)
+            for i in range(n_workers)
+        ]
+        learner = AsyncDenseLearner(
+            model, workers, ConsistencyConfig(mode=ConsistencyMode.BSP),
+            example, seed=0,
+        )
+        for s in range(n_servers):
+            DenseKVServer(
+                Postoffice(f"S{s}", van),
+                {"model": (total, OptimizerConfig(kind="sgd", learning_rate=0.3))},
+                s, n_servers,
+                init_vectors={"model": learner.initial_vector()},
+            )
+        eval_kv = DenseKVWorker(Postoffice("WE", van), {"model": total}, n_servers)
+
+        def heldout_accuracy():
+            params = learner.codec.unflatten(eval_kv.pull_sync("model", 60))
+            out = model.apply({"params": params}, images, train=False)
+            return float(np.mean(np.argmax(np.asarray(out), -1) == labels))
+
+        before = heldout_accuracy()
+        losses = learner.run(
+            [s.next_batch for s in streams], steps, timeout=120.0
+        )
+        assert len(losses) == n_workers * steps
+        after = heldout_accuracy()
+        assert after > 0.5 and after > before, (before, after)
     finally:
         van.close()

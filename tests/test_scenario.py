@@ -6,8 +6,7 @@ Acceptance anchors:
    same spec + seed => byte-identical event lists — and rejects malformed
    specs loudly;
 2. a seeded run is BIT-reproducible: two same-seed runs produce identical
-   canonical scorecard JSON (the ``bench.py --wargame`` gate diffs the
-   same string);
+   canonical scorecard JSON;
 3. the closed loop earns its keep: autoscaler-on accumulates strictly
    fewer SLO-breach-minutes than autoscaler-off on the same scenario;
 4. the observability surface lights up: ``scenario.*`` flight-recorder

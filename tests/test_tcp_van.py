@@ -18,12 +18,20 @@ from parameter_server_tpu import native
 if native.load("tcpvan") is None:  # pragma: no cover
     pytest.skip("no native toolchain for tcpvan", allow_module_level=True)
 
+import jax
+
+from parameter_server_tpu.config import OptimizerConfig, TableConfig
+from parameter_server_tpu.core.filters import make_chain
 from parameter_server_tpu.core.messages import Message, Task, TaskKind
+from parameter_server_tpu.core.postoffice import Postoffice
 from parameter_server_tpu.core.tcp_van import (
     TcpVan,
     deserialize_message,
     serialize_message,
 )
+from parameter_server_tpu.kv.server import KVServer
+from parameter_server_tpu.kv.worker import KVWorker
+from parameter_server_tpu.utils.keys import IdentityLocalizer
 
 
 def _msg(recver="S0", sender="W0", time_=3, values=None, keys=None):
@@ -231,3 +239,65 @@ def test_multiprocess_push_pull():
                 proc.wait()
     finally:
         van.close()
+
+
+def test_filtered_device_reply_plane_three_overlapped_steps():
+    """The embedding plane as a hybrid trainer drives it, on sockets: a
+    ``key_caching+int8`` chain on every link, servers that reply device
+    arrays, the next step's pull in flight while this step's rows are used,
+    one push in flight.  The bytes that crossed are counted, and fewer than
+    the float32 rows both ways would be."""
+    vocab, dim, steps, n_servers = 16384, 256, 3, 2
+    # rows start non-zero: a zero table quantizes to nothing on the wire
+    cfgs = {
+        "emb": TableConfig(
+            name="emb", rows=vocab, dim=dim, init_scale=0.02,
+            optimizer=OptimizerConfig(kind="adagrad", learning_rate=0.05),
+        )
+    }
+    vans = [
+        TcpVan(filter_chain=make_chain("key_caching+int8"))
+        for _ in range(n_servers + 1)
+    ]
+    van_w, van_s = vans[0], vans[1:]
+    try:
+        for s in range(n_servers):
+            KVServer(
+                Postoffice(f"S{s}", van_s[s]), cfgs, s, n_servers,
+                device_replies=True,
+            )
+            van_w.add_route(f"S{s}", van_s[s].address)
+            van_s[s].add_route("W0", van_w.address)
+        worker = KVWorker(
+            Postoffice("W0", van_w), cfgs, n_servers,
+            localizers={"emb": IdentityLocalizer(vocab)},
+        )
+        rng = np.random.default_rng(0)
+        toks = [
+            (rng.zipf(1.2, size=(8, 256)) % vocab).astype(np.int64)
+            for _ in range(steps + 1)
+        ]
+        sent0, recv0 = van_w.payload_bytes_sent(), van_w.payload_bytes_recv()
+        ts_cur, push_prev = worker.pull("emb", toks[0]), None
+        for i in range(steps):
+            ts_next = worker.pull("emb", toks[i + 1])
+            rows = worker.pull_result_device(ts_cur, timeout=120)
+            assert isinstance(rows, jax.Array) and rows.shape == (8, 256, dim)
+            assert np.all(np.isfinite(np.asarray(rows)))
+            if push_prev is not None:
+                assert worker.wait(push_prev, 120), "push not acknowledged"
+            push_prev = worker.push_device(
+                "emb", toks[i].reshape(-1), rows.reshape(-1, dim) * 0.01
+            )
+            ts_cur = ts_next
+        assert worker.wait(push_prev, 120)
+        worker.pull_result_device(ts_cur, timeout=120)
+        wire = (
+            van_w.payload_bytes_sent() - sent0
+            + van_w.payload_bytes_recv() - recv0
+        )
+        raw_rows = sum(len(np.unique(t)) for t in toks[:steps]) * dim * 4
+        assert 0 < wire < 2 * raw_rows, (wire, raw_rows)
+    finally:
+        for v in vans:
+            v.close()

@@ -46,8 +46,7 @@ Usage::
 
 The report prints a worked per-request transcript (``--requests`` many,
 default 3) and a per-plane p50/p99 attribution table; ``--json`` emits
-the same data machine-readable (``bench.py --traceplane`` and the e2e
-tests consume it).  The live complements of this offline view are the
+the same data machine-readable (the e2e tests consume it).  The live complements of this offline view are the
 ``trace.wire`` / ``trace.sq`` / ``trace.apply`` / ``trace.e2e``
 telemetry digests (pstop's WIREus/SQus/APLY%% columns and the
 ``tracing_plane_specs`` SLO read those).
