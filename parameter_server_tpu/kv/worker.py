@@ -9,6 +9,9 @@ Pipeline per call (SURVEY.md §3.2 hot path, TPU mapping):
 
 1. host: ``localize_to_slots`` — dedup keys, map to unique row slots
    (deterministic ``HashLocalizer`` for multi-worker consistency).
+   Computed once a batch: :meth:`KVWorker._localize` keeps the newest
+   localization of each table and hands it back when it is asked for the
+   same keys again, so a step's push reuses what its pull computed.
 2. device: ``segment_combine`` duplicate positions (push only) — the
    worker-side pre-reduction.  With a :class:`~parameter_server_tpu.kv.
    routing.WorkerGroup` (ISSUE 15) this is also where the GROUP
@@ -38,7 +41,7 @@ import functools
 import itertools
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +77,7 @@ from parameter_server_tpu.kv.routing import (
 from parameter_server_tpu.ops import scatter
 from parameter_server_tpu.utils.keys import (
     HashLocalizer,
+    flat_keys,
     leg_bucket,
     localize_to_slots,
 )
@@ -126,6 +130,17 @@ def _pad_index(idx: np.ndarray, size: int, fill: int) -> np.ndarray:
     out = np.full(size, fill, np.int32)
     out[: idx.shape[0]] = idx
     return out
+
+
+class _Localized(NamedTuple):
+    """What :meth:`KVWorker._localize` computed last for a table, and from
+    what: a private copy of the flat keys, the localizer, ``min_bucket``."""
+
+    keys: np.ndarray
+    localizer: object
+    min_bucket: int
+    slots: np.ndarray
+    inverse: np.ndarray
 
 
 class KVWorker(Customer):
@@ -195,6 +210,13 @@ class KVWorker(Customer):
         self.localizers = localizers or {
             t: HashLocalizer(cfg.rows) for t, cfg in table_cfgs.items()
         }
+        #: the newest localization computed for each table
+        #: (:meth:`_localize`).  One entry a table, swapped whole: a second
+        #: thread sees the old or the new
+        self._localized: Dict[str, _Localized] = {}
+        #: localizations computed / handed back from ``_localized``
+        self.localize_computed = 0
+        self.localize_reused = 0
         #: per-timestamp reassembly info for pulls
         self._pull_plans: Dict[int, dict] = {}
         #: (n_slots, dim, dtype) -> [host plane, device arrays that last read
@@ -389,6 +411,8 @@ class KVWorker(Customer):
             "pull_assembled_device": self.pull_assembled_device,
             "pull_assembled_host": self.pull_assembled_host,
             "push_combined_from_device": self.push_combined_from_device,
+            "localize_computed": self.localize_computed,
+            "localize_reused": self.localize_reused,
         }
         if self._group is not None:
             out.update(
@@ -1224,8 +1248,9 @@ class KVWorker(Customer):
             abs_pos, leg_bucket(abs_pos.shape[0]), combined.shape[0]
         ))
 
-    def _prepare_push(self, table: str, keys, values):
-        """Worker half of a push: localize, then combine duplicates on
+    def _prepare_push(self, table: str, keys, values, root=None):
+        """Worker half of a push: localize (:meth:`_localize`: reused where
+        the keys are the last pull's), then combine duplicates on
         ``self.device``; the combined ``[slots, dim]`` plane comes back to
         the host for the wire.
 
@@ -1244,7 +1269,7 @@ class KVWorker(Customer):
             vals = np.asarray(values, dtype=cfg.dtype).reshape(
                 keys.size, cfg.dim
             )
-        slots, inverse = self._localize(table, keys)
+        slots, inverse = self._localize(table, keys, root)
         with self.tracer.span(
             "ps.worker.combine", unique=slots.shape[0],
             where="device" if on_device else "host",
@@ -1264,16 +1289,55 @@ class KVWorker(Customer):
             self.push_combined_from_device += 1
         return slots, combined
 
-    def _localize(self, table: str, keys) -> Tuple[np.ndarray, np.ndarray]:
-        """``localize_to_slots`` under its span: ``(slots, inverse)``."""
-        with self.tracer.span(
-            "ps.worker.localize", keys=int(keys.size)
-        ) as sp:
-            slots, inverse, n = localize_to_slots(
-                keys, self.localizers[table], min_bucket=self.min_bucket
+    def _localize(
+        self, table: str, keys, root=None
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """``(slots, inverse)`` of ``keys``: computed by
+        ``localize_to_slots`` under ``ps.worker.localize``, or the table's
+        newest computed pair handed back.
+
+        A localization is a pure function of the keys (flattened, as
+        ``uint64``: ``utils/keys.py::flat_keys``), the table's
+        localizer and ``min_bucket``, and a localizer maps a key it has
+        seen to the same row for ever.  So the call reuses the kept pair
+        when all three are what it was computed from (the localizer by
+        identity, the keys by content against a private copy: an equal
+        array hits, a buffer refilled in place misses) and computes
+        otherwise; what it computes replaces the table's entry.  One entry
+        a table: a step's push reuses its pull's, and no batch is
+        remembered past the next.  The pair is shared between a pull's
+        plan and the push, so it is not writable.  ``root``, the request's
+        root span, is told which it was (``localize="computed"`` /
+        ``"reused"``)."""
+        loc = self.localizers[table]
+        flat = flat_keys(keys)
+        kept = self._localized.get(table)
+        if (
+            kept is not None
+            and kept.localizer is loc
+            and kept.min_bucket == self.min_bucket
+            and np.array_equal(kept.keys, flat)
+        ):
+            self.localize_reused += 1
+            how = "reused"
+        else:
+            with self.tracer.span(
+                "ps.worker.localize", keys=int(flat.size)
+            ) as sp:
+                slots, inverse, n = localize_to_slots(
+                    flat, loc, min_bucket=self.min_bucket
+                )
+                sp.set(unique=n)
+            slots.flags.writeable = False
+            inverse.flags.writeable = False
+            kept = self._localized[table] = _Localized(
+                flat.copy(), loc, self.min_bucket, slots, inverse
             )
-            sp.set(unique=n)
-        return slots, inverse
+            self.localize_computed += 1
+            how = "computed"
+        if root is not None:
+            root.set(localize=how)
+        return kept.slots, kept.inverse
 
     def _req(self, ts: int) -> str:
         """``req`` of this customer's task ``ts`` (``utils/trace.py``)."""
@@ -1307,8 +1371,8 @@ class KVWorker(Customer):
         with self.tracer.span(
             "ps.worker.push", table=table, keys=int(keys.size),
             **({"trace": tctx["tid"]} if tctx is not None else {}),
-        ):
-            slots, combined = self._prepare_push(table, keys, values)
+        ) as root:
+            slots, combined = self._prepare_push(table, keys, values, root)
             if self._group is not None:
                 return self._group_push(
                     table, slots, combined, sync=False, timeout=None
@@ -1330,10 +1394,10 @@ class KVWorker(Customer):
         with self.tracer.span(
             "ps.worker.push", table=table, keys=int(keys.size),
             **({"trace": tctx["tid"]} if tctx is not None else {}),
-        ):
+        ) as root:
             cfg = self.table_cfgs[table]
             vals = values.reshape(keys.size, cfg.dim)
-            slots, inverse = self._localize(table, keys)
+            slots, inverse = self._localize(table, keys, root)
             with self.tracer.span(
                 "ps.worker.combine", unique=slots.shape[0], where="device",
                 h2d_bytes=inverse.nbytes, d2h_bytes=0,
@@ -1388,7 +1452,10 @@ class KVWorker(Customer):
         reads that may NOT observe writes coalesced into the same wire
         bundle.  Training pulls must keep the default.
         """
-        slots, inverse = self._localize(table, keys)
+        return self._pull(table, keys, read_only=read_only)
+
+    def _pull(self, table, keys, *, read_only: bool = False, root=None) -> int:
+        slots, inverse = self._localize(table, keys, root)
         return self._submit_pull(
             table, slots, inverse, keys.shape, read_only=read_only
         )
@@ -1858,8 +1925,8 @@ class KVWorker(Customer):
         NumPy array; ``np.asarray`` reads either."""
         with self.tracer.span(
             "ps.worker.pull", table=table, keys=int(keys.size)
-        ):
-            return self.pull_result(self.pull(table, keys), timeout)
+        ) as root:
+            return self.pull_result(self._pull(table, keys, root=root), timeout)
 
     # -- read-heavy serving plane (ISSUE 13) ---------------------------------
     def pull_serve(
@@ -2017,8 +2084,8 @@ class KVWorker(Customer):
         """
         with self.tracer.span(
             "ps.worker.push", table=table, keys=int(keys.size)
-        ):
-            slots, combined = self._prepare_push(table, keys, values)
+        ) as root:
+            slots, combined = self._prepare_push(table, keys, values, root)
             if self._group is not None:
                 return self._group_push(
                     table, slots, combined, sync=True, timeout=timeout

@@ -122,6 +122,14 @@ def leg_bucket(n: int) -> int:
     return bucket_size(max(n, 1), min_bucket=8)
 
 
+def flat_keys(keys) -> np.ndarray:
+    """Keys as every localization takes them: flattened, as ``uint64``.
+    Keys are uint64 by contract; signed parser output is coerced so PAD_KEY
+    padding cannot wrap to -1 and break the sortedness invariant.  A view
+    of ``keys`` where they are that already."""
+    return np.ascontiguousarray(keys).ravel().astype(np.uint64, copy=False)
+
+
 def localize_batch(
     keys: np.ndarray, *, pad_to_bucket: bool = True, min_bucket: int = 256
 ) -> Tuple[np.ndarray, np.ndarray, int]:
@@ -141,10 +149,7 @@ def localize_batch(
     The sortedness of ``unique_keys`` is what lets the server side slice by
     key range with binary search (reference ``Parameter::Slice`` [U]).
     """
-    # Keys are uint64 by contract; coerce signed parser output so PAD_KEY
-    # padding cannot wrap to -1 and break the sortedness invariant.
-    flat = np.ascontiguousarray(keys).ravel().astype(np.uint64, copy=False)
-    uniq, inverse = np.unique(flat, return_inverse=True)
+    uniq, inverse = np.unique(flat_keys(keys), return_inverse=True)
     n_unique = int(uniq.shape[0])
     if pad_to_bucket:
         cap = bucket_size(n_unique, min_bucket=min_bucket)

@@ -82,7 +82,10 @@ def test_spans_of_a_loopback_cluster_land_in_the_profilers_trace(tmp_path):
         worker.push_sync("w", keys, grads, timeout=30)  # compiles outside
         worker.pull_sync("w", keys, timeout=30)
         with jax.profiler.trace(str(tmp_path)):
-            for _ in range(STEPS):
+            for step in range(STEPS):
+                # another order of the same keys: a batch the worker has to
+                # localize, with the row counts the programs were compiled for
+                keys = np.roll(keys, step + 1)
                 worker.push_sync("w", keys, grads, timeout=30)
                 worker.pull_sync("w", keys, timeout=30)
     finally:
@@ -98,6 +101,10 @@ def test_spans_of_a_loopback_cluster_land_in_the_profilers_trace(tmp_path):
     roots = by_name["ps.worker.pull"] + by_name["ps.worker.push"]
     assert len(roots) == 2 * STEPS
     assert all(r[4]["table"] == "w" and r[4]["keys"] == 40 for r in roots)
+    # a step's keys are localized once: where the first request computes
+    assert len(by_name["ps.worker.localize"]) == STEPS
+    assert {r[4]["localize"] for r in by_name["ps.worker.push"]} == {"computed"}
+    assert {r[4]["localize"] for r in by_name["ps.worker.pull"]} == {"reused"}
     # one submit a root here (no fence, no defer), nested in it
     submits = {}
     for sub in by_name["ps.worker.submit"]:
