@@ -342,6 +342,24 @@ def _build_kimi_linear_hybrid(cfg: AppConfig) -> Callable[[], dict]:
     return run
 
 
+@register_app("lfm2_moe_hybrid")
+def _build_lfm2_moe_hybrid(cfg: AppConfig) -> Callable[[], dict]:
+    """The same hybrid path under a body of gated short convolutions and
+    grouped-query attention (``models/lfm2_moe.py``: a dense MLP and a held
+    share of bias-selected experts), tiny sizes by default so the app runs
+    anywhere; the benchmark's ``lfm2_8b_a1b`` configuration runs the
+    published widths through the same trainer."""
+
+    def run() -> dict:
+        from parameter_server_tpu.models import lfm2_moe
+
+        return _run_hybrid(cfg, lfm2_moe.tiny_config(
+            vocab_size=min(cfg.data.key_space, 1 << 16),
+        ))
+
+    return run
+
+
 def _sp_app_knobs(cfg: AppConfig, round_to: int):
     """Shared knobs of the long-context apps (sp_lm / sptp_lm).
 

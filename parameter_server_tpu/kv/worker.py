@@ -102,12 +102,13 @@ def _assemble_device(positions, rows, inverse, *, n_slots: int, dim: int, dtype)
     every leg's rows scattered to their slots, then gathered by
     ``inverse``.  A leg's rows arrive padded to its bucket; its positions
     are padded with ``n_slots``, which the scatter drops."""
-    uniq = jnp.zeros((n_slots, dim), dtype)
-    for pos, leg in zip(positions, rows):
-        uniq = uniq.at[pos].set(
-            leg.astype(dtype).reshape(-1, dim), mode="drop"
-        )
-    return jnp.take(uniq, inverse, axis=0)
+    with jax.named_scope("ps.worker.assemble"):
+        uniq = jnp.zeros((n_slots, dim), dtype)
+        for pos, leg in zip(positions, rows):
+            uniq = uniq.at[pos].set(
+                leg.astype(dtype).reshape(-1, dim), mode="drop"
+            )
+        return jnp.take(uniq, inverse, axis=0)
 
 
 @jax.jit
@@ -123,7 +124,8 @@ def _gather_rows(plane, inverse):
 def _take_rows(plane, idx):
     """``plane[idx]`` on device, zeros where ``idx`` is past the plane: a
     leg of a device push, padded to its bucket."""
-    return jnp.take(plane, idx, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope("ps.worker.submit"):
+        return jnp.take(plane, idx, axis=0, mode="fill", fill_value=0)
 
 
 def _pad_index(idx: np.ndarray, size: int, fill: int) -> np.ndarray:

@@ -70,8 +70,15 @@ class HybridLMTrainer:
 
     ``cfg`` is the body's model config, whose ``hybrid_body(seed,
     loss_chunk)`` builds what is trained: a ``TransformerConfig`` (one kind
-    of block) or a ``KimiLinearConfig`` (a layer pattern of delta-rule and
-    latent-attention mixers, dense and expert MLPs).
+    of block), a ``KimiLinearConfig`` (a layer pattern of delta-rule and
+    latent-attention mixers, dense and expert MLPs) or an ``Lfm2MoeConfig``
+    (gated short convolutions and grouped-query attention, dense and
+    bias-selected expert MLPs).
+
+    **Buffers.**  A body may hold leaves that are state and not weights (an
+    expert layer's selection bias): its config names them (``cfg.buffers``,
+    leaf names), they ride in the parameter tree, and the step gives them no
+    update: AdamW's weight decay would otherwise move a non-zero one.
 
     ``max_delay``: how many embedding pushes may be in flight before the
     next step blocks on the oldest ack (τ of SSP; 0 = BSP, every push
@@ -125,6 +132,7 @@ class HybridLMTrainer:
         params, loss_fn, self._logits, self.n_active_params, scope = (
             cfg.hybrid_body(seed, loss_chunk)
         )
+        buffers = frozenset(getattr(cfg, "buffers", ()))
         self.params = place_params(params, mesh)
         del params
         # the optimizer state placed like the parameters it shadows (what has
@@ -149,9 +157,9 @@ class HybridLMTrainer:
         self._prefetch: Optional[tuple] = None
         self.tracer = tracer or NULL_TRACER
         self.step_count = 0
-        #: what the last step counted beside its loss (``kimi_linear.COUNTERS`` of
-        #: a body with experts: held, dropped and the fullest expert's token
-        #: slots; empty otherwise), as ints
+        #: what the last step counted beside its loss (``models/moe.py::COUNTERS``
+        #: of a body with experts: held, dropped and the fullest expert's
+        #: token slots; empty otherwise), as ints
         self.counters: Dict[str, int] = {}
         #: the body's loss: ``loss_fn(params, emb_in, targets) -> (loss,
         #: counters)``; what ``step`` differentiates
@@ -176,6 +184,12 @@ class HybridLMTrainer:
                 g_emb = jax.lax.with_sharding_constraint(g_emb, batch3)
                 with jax.named_scope("ps.model.optimizer"):
                     updates, opt_state = tx.update(g_params, opt_state, params)
+                    if buffers:  # a buffer takes no update, weight decay included
+                        updates = jax.tree_util.tree_map_with_path(
+                            lambda path, u: jnp.zeros_like(u)
+                            if path[-1].key in buffers else u,
+                            updates,
+                        )
                     params = optax.apply_updates(params, updates)
             return params, opt_state, loss, g_emb, counters
 

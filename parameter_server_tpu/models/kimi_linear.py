@@ -23,21 +23,13 @@ With ``x'`` the RMS-normed input of a sub-layer:
   shared by the heads and left unrotated; causal softmax at ``1 /
   sqrt(d_nope + d_pe)``; ``W_o``.  Blocked over queries
   (``ops/blocked_attention.py``).
-- **Experts**: ``s = sigmoid(x' W_r)`` over all routed experts, ``sel =
-  top_k(s + bias)``, ``w_i = scale s_i / sum_{j in sel} s_j``; ``y = sum_{i
-  in sel, held} w_i E_i(x') + E_shared(x')``.  **The held share**: this
-  process holds experts ``[experts_first, experts_first + experts_held)``;
-  the router keeps every output; what the absent experts would add is left
-  out, here and in the reference alike, and nothing stands in for them.
-  Token slots that select a held expert are laid out by expert in rows whose
-  groups are padded to whole blocks; a loop over the blocks that hold rows
-  gathers each block's rows, runs them through its expert's three matrices
-  (a grouped product), weights them and adds them back to their tokens
-  (:func:`grouped_experts`), so the cost follows the load while the layout
-  has room for the worst case (every slot of every token held here: index
-  arrays only, 1 MB at the published widths).  No slot may be dropped:
-  ``moe_dropped_slots`` counts the held slots the layout gave no row
-  (:func:`dispatch_layout`), and a caller must find it 0.
+- **Experts**: the held-share expert layer of ``models/moe.py``, which
+  both layer-pattern bodies call: ``s = sigmoid(x' W_r)`` over all 256
+  routed experts, ``sel = top_8(s)`` (the published selection bias is held
+  at zero here: assumed, so this body passes none), ``w_i = scale s_i /
+  sum_{j in sel} s_j``; ``y = sum_{i in sel, held} w_i E_i(x') +
+  E_shared(x')`` over the experts ``[experts_first, experts_first +
+  experts_held)`` this process holds.
 
 **Precision**: parameters, residual stream, norms, router, decay, state,
 softmax and loss are float32; matrix products run at jax's default precision
@@ -51,47 +43,35 @@ caller sets.  Device scopes (under the trainer's
 ``ps.model.kimi``, which holds the whole step, ``ps.model.optimizer``
 included; each is written as a path under it, :func:`_scope`):
 ``ps.model.kda.proj`` / ``.conv`` / ``.scan`` / ``.out``,
-``ps.model.mla.proj`` / ``.attn``, ``ps.model.moe.router`` / ``.dispatch`` /
-``.experts`` / ``.combine`` / ``.shared``, ``ps.model.mlp``,
-``ps.model.head_loss``.
+``ps.model.mla.proj`` / ``.attn``, and ``models/moe.py``'s
+(``ps.model.moe.*``, ``ps.model.mlp``, ``ps.model.head_loss``).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import zlib
+import sys
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from parameter_server_tpu.models import transformer as tfm
+from parameter_server_tpu.models import moe
+from parameter_server_tpu.models.moe import (  # noqa: F401  (this body's API)
+    COUNTERS, dispatch_layout, rms_norm,
+)
 from parameter_server_tpu.ops.blocked_attention import blocked_causal_attention
 from parameter_server_tpu.ops.delta_rule import chunk_kda
 
-HIGHEST = jax.lax.Precision.HIGHEST
 #: the device scope the trainer puts round a step of this body
 BODY_SCOPE = "ps.model.kimi"
 
 
-def _scope(name: str):
-    """Device scope ``ps.model.<name>``, written as a path under the body's
-    own (``ps.model.kimi/ps.model.<name>``).  An operation of a transposed
-    checkpoint or of a ``custom_vjp``'s rule loses the name stack around it,
-    and the profiler leaves a ``while``'s own name out of the trace: a
-    reader that splits the busy time by outermost scope
-    (``scoped_device_pct``) gives such a ``while`` its program's scope only
-    if every scoped operation of the program starts with that one (my chip
-    run, PR 28: 11 % scoped without this, the step's ``lax.map`` loops
-    unscoped)."""
-    return jax.named_scope(f"{BODY_SCOPE}/ps.model.{name}")
-
-
-#: what a step returns beside the loss, summed (``max``: largest) over the
-#: expert layers
-COUNTERS = ("moe_held_slots", "moe_dropped_slots", "moe_max_expert_slots")
+#: device scope ``ps.model.<name>`` as a path under this body's
+#: (``models/moe.py::scope`` says why)
+_scope = functools.partial(moe.scope, BODY_SCOPE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,9 +118,9 @@ class KimiLinearConfig:
         return self.hidden_size
 
     def hybrid_body(self, seed: int, loss_chunk: int):
-        """What ``learner/hybrid.py::HybridLMTrainer`` trains: see
-        :func:`hybrid_body`."""
-        return hybrid_body(self, seed, loss_chunk)
+        """What ``learner/hybrid.py::HybridLMTrainer`` trains
+        (``models/moe.py::hybrid_body``)."""
+        return moe.hybrid_body(sys.modules[__name__], self, seed, loss_chunk)
 
     @classmethod
     def from_published(cls, pub: dict, **over) -> "KimiLinearConfig":
@@ -255,11 +235,7 @@ def param_shapes(cfg: KimiLinearConfig) -> dict:
     C, F, Fd = cfg.kv_lora_rank, cfg.moe_intermediate_size, cfg.intermediate_size
     W = cfg.short_conv_kernel_size
 
-    def swiglu(width, lead=()):
-        return {"gate": {"kernel": (*lead, D, width)},
-                "up": {"kernel": (*lead, D, width)},
-                "down": {"kernel": (*lead, width, D)}}
-
+    swiglu = functools.partial(moe.swiglu_shapes, D)
     kda = {
         "q": {"kernel": (D, H, K)}, "k": {"kernel": (D, H, K)},
         "v": {"kernel": (D, H, K)},
@@ -275,7 +251,7 @@ def param_shapes(cfg: KimiLinearConfig) -> dict:
         "kv_norm": {"scale": (C,)}, "kv_b": {"kernel": (C, A, dn + dv)},
         "o": {"kernel": (A, dv, D)},
     }
-    moe = {
+    experts = {
         "router": {"kernel": (D, cfg.n_routed_experts)},
         "experts": {k: v["kernel"] for k, v in
                     swiglu(F, (cfg.experts_held,)).items()},
@@ -287,44 +263,23 @@ def param_shapes(cfg: KimiLinearConfig) -> dict:
             "mixer_norm": {"scale": (D,)},
             mixer: kda if mixer == "kda" else mla,
             "mlp_norm": {"scale": (D,)},
-            **({"mlp": swiglu(Fd)} if mlp == "dense" else {"moe": moe}),
+            **({"mlp": swiglu(Fd)} if mlp == "dense" else {"moe": experts}),
         }
     tree["final_norm"] = {"scale": (D,)}
     tree["lm_head"] = {"kernel": (D, cfg.vocab_size)}
     return tree
 
 
-def _is_shape(x) -> bool:
-    return isinstance(x, tuple)
-
-
 def count_params(cfg: KimiLinearConfig) -> dict:
-    """``held``: parameters of this body; ``active``: those a token's
-    forward multiplies with here, a routed expert counted by the chance that
-    a slot picks it (top-k x held / routed experts a layer).  What the 6ND
-    rule takes for a body with experts."""
-    shapes = param_shapes(cfg)
-    held = sum(
-        int(np.prod(s)) for s in jax.tree.leaves(shapes, is_leaf=_is_shape)
-    )
-    per_expert = 3 * cfg.hidden_size * cfg.moe_intermediate_size
-    n_moe = sum(mlp == "experts" for _, mlp in cfg.layer_kinds())
-    routed = n_moe * cfg.experts_held * per_expert
-    share = cfg.num_experts_per_token / cfg.n_routed_experts
-    return {"held": held, "active": held - routed + int(routed * share)}
+    """``held`` and ``active`` parameters of this body
+    (``models/moe.py::count_params``)."""
+    return moe.count_params(param_shapes(cfg), expert_layer(cfg))
 
 
 def init_params(cfg: KimiLinearConfig, key) -> dict:
     """Seeded float32 parameters (initial scales: assumed; the file of the
     benchmark's configuration lists them)."""
-    def make(path, shape):
-        name = "/".join(str(getattr(p, "key", p)) for p in path)
-        k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-        leaf = name.rsplit("/", 1)[-1]
-        if leaf == "scale":
-            return jnp.ones(shape, jnp.float32)
-        if leaf == "bias":
-            return jnp.zeros(shape, jnp.float32)
+    def special(leaf, k, shape):
         if leaf == "A_log":  # decay rates exp(A_log) in [1, 16)
             return jnp.log(jax.random.uniform(k, shape, minval=1.0, maxval=16.0))
         if leaf == "dt_bias":  # softplus^-1 of a step in [1e-3, 1e-1), log-uniform
@@ -335,25 +290,12 @@ def init_params(cfg: KimiLinearConfig, key) -> dict:
         if leaf.startswith("conv_"):  # as a depthwise conv's default: 1 / sqrt(width)
             bound = 1.0 / np.sqrt(shape[0])
             return jax.random.uniform(k, shape, minval=-bound, maxval=bound)
-        return cfg.init_scale * jax.random.normal(k, shape, jnp.float32)
+        return None
 
-    return jax.tree_util.tree_map_with_path(
-        make, param_shapes(cfg), is_leaf=_is_shape
-    )
+    return moe.init_tree(param_shapes(cfg), key, cfg.init_scale, special)
 
 
 # -- layers ---------------------------------------------------------------------
-def rms_norm(x, scale, eps):
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale
-
-
-def _swiglu(p, x):
-    h = jax.nn.silu(x @ p["gate"]["kernel"]) * (x @ p["up"]["kernel"])
-    return h @ p["down"]["kernel"]
-
-
 def _causal_conv(x, w):
     """Depthwise causal convolution over time: ``x [B, S, H, K]``, ``w [W, H,
     K]``; ``y_t = sum_i w_i x_{t - (W - 1) + i}``."""
@@ -445,169 +387,35 @@ def mla_mixer(cfg: KimiLinearConfig, band: int, p, x):
         return jnp.einsum("bshk,hkd->bsd", o, p["o"]["kernel"])
 
 
-def _expert_rows(xz, ex, rows, e):
-    """A block's rows through expert ``e``: ``(x, silu'(a) parts, h, out)``."""
-    with _scope("moe.dispatch"):
-        xb = xz[rows]
-    with _scope("moe.experts"):
-        a, b = xb @ ex["gate"][e], xb @ ex["up"][e]
-        h = jax.nn.silu(a) * b
-        return xb, a, b, h, h @ ex["down"][e]
-
-
-@jax.custom_vjp
-def grouped_experts(xz, ex, weight, rows, block_expert, n_live):
-    """The held experts' weighted outputs added back to their tokens.
-
-    ``xz [N + 1, D]``: the tokens and a zero row; ``rows [nb, bm]``: the
-    token of every row of every block (``N``: none); ``weight [nb, bm]``;
-    ``block_expert [nb]``; the first ``n_live`` blocks hold rows, the rest
-    none.  A ``fori_loop`` over the live blocks only: the layout has room
-    for every slot of every token, the cost is the load's.  Its backward is
-    written out below (a loop with a traced trip count has no derivative of
-    jax's own, and under ``lax.scan`` + ``lax.cond`` every block's residuals
-    are stacked for all ``nb`` blocks, 20 GB at the published widths: found
-    by compiling for the chip, PR 28): it recomputes a block's hidden
-    activations and keeps nothing per block."""
-
-    def block(i, y):
-        _xb, _a, _b, _h, out = _expert_rows(xz, ex, rows[i], block_expert[i])
-        with _scope("moe.combine"):
-            return y.at[rows[i]].add(out * weight[i][:, None])
-
-    return jax.lax.fori_loop(
-        0, n_live, block, jnp.zeros(xz.shape, jnp.float32)
+def expert_layer(cfg: KimiLinearConfig) -> moe.ExpertLayer:
+    """What ``models/moe.py``'s expert layer is told by this body: the top 8
+    of 256 sigmoid scores, renormalised and scaled, a shared expert in the
+    parameters and no selection bias (a buffer held at zero and not trained:
+    assumed, so the parameters hold none and selection is by the scores
+    alone)."""
+    return moe.ExpertLayer(
+        root=BODY_SCOPE, n_routed=cfg.n_routed_experts,
+        held=cfg.experts_held, first=cfg.experts_first,
+        top_k=cfg.num_experts_per_token, scale=cfg.routed_scaling_factor,
+        renormalize=cfg.moe_renormalize, block=cfg.moe_block,
     )
-
-
-def _grouped_fwd(xz, ex, weight, rows, block_expert, n_live):
-    y = grouped_experts(xz, ex, weight, rows, block_expert, n_live)
-    return y, (xz, ex, weight, rows, block_expert, n_live)
-
-
-def _grouped_bwd(res, dy):
-    xz, ex, weight, rows, block_expert, n_live = res
-
-    def block(i, carry):
-        dxz, d_ex, d_weight = carry
-        e, r = block_expert[i], rows[i]
-        xb, a, b, h, out = _expert_rows(xz, ex, r, e)
-        with _scope("moe.combine"):
-            dyb = dy[r]
-            d_weight = d_weight.at[i].set(jnp.sum(out * dyb, axis=-1))
-            d_out = dyb * weight[i][:, None]
-        with _scope("moe.experts"):
-            dh = d_out @ ex["down"][e].T
-            sig = jax.nn.sigmoid(a)
-            da = dh * b * sig * (1.0 + a * (1.0 - sig))  # silu'(a)
-            db = dh * a * sig
-            d_ex = {
-                "gate": d_ex["gate"].at[e].add(xb.T @ da),
-                "up": d_ex["up"].at[e].add(xb.T @ db),
-                "down": d_ex["down"].at[e].add(h.T @ d_out),
-            }
-            dxb = da @ ex["gate"][e].T + db @ ex["up"][e].T
-        with _scope("moe.dispatch"):
-            return dxz.at[r].add(dxb), d_ex, d_weight
-
-    dxz, d_ex, d_weight = jax.lax.fori_loop(0, n_live, block, (
-        jnp.zeros(xz.shape, jnp.float32),
-        jax.tree.map(jnp.zeros_like, ex),
-        jnp.zeros(weight.shape, jnp.float32),
-    ))
-    # the zero row takes no gradient; rows, experts and the count are integers
-    return dxz.at[-1].set(0.0), d_ex, d_weight, None, None, None
-
-
-grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def moe_capacity(cfg: KimiLinearConfig, tokens: int) -> int:
-    """Rows of the dispatch layout, a whole number of blocks: room for the
-    worst case, every slot of every token held here and every expert's last
-    block all but empty."""
-    bm = cfg.moe_block
-    rows = (
-        tokens * min(cfg.num_experts_per_token, cfg.experts_held)
-        + cfg.experts_held * (bm - 1)
-    )
-    return -(-rows // bm) * bm
-
-
-def dispatch_layout(group, held: int, block: int, rows: int):
-    """Where every token slot goes.  ``group [slots]``: the held expert a slot
-    selected (``held``: none of them); ``rows``: rows of the layout, a whole
-    number of ``block``s.  The slots are laid out by expert, each expert's
-    group padded to whole blocks.  Returns ``(slot [rows], filled [rows],
-    block_expert [rows / block], live blocks, counters)``: the slot that fills
-    a row, whether one does, a block's expert, how many leading blocks hold
-    rows.  ``moe_dropped_slots`` counts the held slots that found no row:
-    0 whenever ``rows`` is :func:`moe_capacity`'s, which a caller checks."""
-    nb = rows // block
-    order = jnp.argsort(group, stable=True)  # slots by expert, absent last
-    # where each held expert's slots start among the sorted ones
-    first_slot = jnp.searchsorted(group[order], jnp.arange(held + 1))
-    counts = first_slot[1:] - first_slot[:-1]
-    padded = -(-counts // block) * block  # every group a whole number of blocks
-    ends = jnp.cumsum(padded)
-    first_row = ends - padded
-    # layout row -> its block's expert -> the sorted slot that fills it
-    block_expert = jnp.minimum(
-        jnp.searchsorted(ends, jnp.arange(nb) * block, side="right"), held - 1
-    )
-    row_expert = jnp.repeat(block_expert, block)
-    offset = jnp.arange(rows) - first_row[row_expert]
-    filled = (offset >= 0) & (offset < counts[row_expert])
-    slot = order[jnp.where(filled, first_slot[row_expert] + offset, 0)]
-    counters = {
-        "moe_held_slots": jnp.sum(counts),
-        "moe_dropped_slots": jnp.sum(counts) - jnp.sum(filled),
-        "moe_max_expert_slots": jnp.max(counts),
-    }
-    # the blocks past the last group hold no row: they are not run
-    live = jnp.sum((jnp.arange(nb) * block < ends[-1]).astype(jnp.int32))
-    return slot, filled, block_expert, live, counters
+    return moe.moe_capacity(expert_layer(cfg), tokens)
 
 
 def route(cfg: KimiLinearConfig, router_kernel, xt):
-    """``xt [N, D]`` -> ``(idx [N, k] of all routed experts, w [N, k])``, in
-    float32 at the highest matrix precision.  The selection bias is a buffer
-    held at zero and not trained (assumed), so it is left out."""
-    s = jax.nn.sigmoid(jnp.dot(xt, router_kernel, precision=HIGHEST))
-    _top, idx = jax.lax.top_k(s, cfg.num_experts_per_token)
-    w = jnp.take_along_axis(s, idx, axis=-1)
-    if cfg.moe_renormalize:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return idx, w * cfg.routed_scaling_factor
+    """``xt [N, D]`` -> ``(idx [N, k] of all routed experts, w [N, k])``:
+    ``models/moe.py::route`` without a selection bias (this body's is held
+    at zero: assumed), so selection and weights are both the scores'."""
+    return moe.route(expert_layer(cfg), router_kernel, xt)
 
 
 def moe_layer(cfg: KimiLinearConfig, p, x):
     """``x [B, S, D]`` (normed) -> ``(y, counters)``: the held experts' part
-    of the routed sum plus the shared expert."""
-    B, S, D = x.shape
-    N, k, Eh, bm = B * S, cfg.num_experts_per_token, cfg.experts_held, cfg.moe_block
-    nb = moe_capacity(cfg, N) // bm
-    xt = x.reshape(N, D)
-    with _scope("moe.router"):
-        idx, w = route(cfg, p["router"]["kernel"], xt)
-    with _scope("moe.dispatch"):
-        local = idx - cfg.experts_first
-        group = jnp.where((local >= 0) & (local < Eh), local, Eh).reshape(-1)
-        slot, filled, block_expert, live, counters = dispatch_layout(
-            group, Eh, bm, nb * bm
-        )
-        # a row's token (N: none, the zero row) and its weight
-        token = jnp.where(filled, slot // k, N).astype(jnp.int32)
-        weight = jnp.where(filled, w.reshape(-1)[slot], 0.0)
-        xz = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])
-    y = grouped_experts(
-        xz, p["experts"], weight.reshape(nb, bm), token.reshape(nb, bm),
-        block_expert, live,
-    )
-    y = y[:N]
-    with _scope("moe.shared"):
-        y = y + _swiglu(p["shared"], xt)
-    return y.reshape(B, S, D), counters
+    of the routed sum plus the shared expert (``models/moe.py``)."""
+    return moe.moe_layer(expert_layer(cfg), p, x)
 
 
 def _mixer_block(cfg, kind, cut, p, x):
@@ -618,84 +426,27 @@ def _mixer_block(cfg, kind, cut, p, x):
     )
 
 
-def _mlp_block(cfg, kind, p, x):
-    h = rms_norm(x, p["mlp_norm"]["scale"], cfg.rms_norm_eps)
-    if kind == "dense":
-        with _scope("mlp"):
-            return x + _swiglu(p["mlp"], h), {}
-    y, counters = moe_layer(cfg, p["moe"], h)
-    return x + y, counters
-
-
-def _by_sequence(f, p, x):
-    """``f(p, x)`` one sequence at a time (``lax.map`` over the batch): the
-    mixers and the dense MLP treat sequences independently, so this changes
-    no result and divides their live activations by the batch."""
-    return jax.lax.map(lambda row: f(p, row[None])[0], x)
-
-
 def trunk(cfg: KimiLinearConfig, params, x):
     """``x [B, S, D]`` input embeddings -> ``(hidden [B, S, D], counters)``."""
-    x = x.astype(jnp.float32)
-    zero = jnp.zeros((), jnp.int32)
-    held = dropped = most = zero
     by_sequence, groups, band = schedule(cfg, x.shape[0], x.shape[1])
-    for i, (mixer, mlp) in enumerate(cfg.layer_kinds()):
-        p = params[f"layer_{i}"]
-        mix = jax.checkpoint(functools.partial(
+    return moe.trunk(
+        expert_layer(cfg), cfg.layer_kinds(),
+        lambda mixer: functools.partial(
             _mixer_block, cfg, mixer, groups if mixer == "kda" else band
-        ))
-        ffn = jax.checkpoint(functools.partial(_mlp_block, cfg, mlp))
-        if by_sequence:
-            mix = functools.partial(_by_sequence, mix)
-            if mlp == "dense":
-                dense = ffn
-                ffn = lambda p, x: (  # noqa: E731
-                    _by_sequence(lambda p, x: dense(p, x)[0], p, x), {}
-                )
-        x = mix(p, x)
-        x, c = ffn(p, x)
-        if c:
-            held = held + c["moe_held_slots"]
-            dropped = dropped + c["moe_dropped_slots"]
-            most = jnp.maximum(most, c["moe_max_expert_slots"])
-    hidden = rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
-    return hidden, dict(zip(COUNTERS, (held, dropped, most)))
+        ),
+        cfg.rms_norm_eps, by_sequence, params, x,
+    )
 
 
 def loss_fn(cfg: KimiLinearConfig, params, emb_in, targets, loss_chunk: int = 0):
     """Next-token loss over the held vocabulary -> ``(loss, counters)``.
     ``loss_chunk > 0`` fuses the head into the chunked loss."""
     hidden, counters = trunk(cfg, params, emb_in)
-    with _scope("head_loss"):
-        head = params["lm_head"]["kernel"]
-        if loss_chunk > 0:
-            loss = tfm.chunked_causal_lm_loss(hidden, head, targets, loss_chunk)
-        else:
-            loss = tfm.causal_lm_loss(
-                jnp.einsum("bsd,dv->bsv", hidden, head,
-                           preferred_element_type=jnp.float32),
-                targets,
-            )
-    return loss, counters
+    return moe.head_loss(
+        BODY_SCOPE, hidden, params["lm_head"]["kernel"], targets, loss_chunk
+    ), counters
 
 
 def logits(cfg: KimiLinearConfig, params, emb_in):
     hidden, _ = trunk(cfg, params, emb_in)
-    return jnp.einsum(
-        "bsd,dv->bsv", hidden, params["lm_head"]["kernel"],
-        preferred_element_type=jnp.float32,
-    )
-
-
-def hybrid_body(cfg: KimiLinearConfig, seed: int, loss_chunk: int):
-    """This body as the hybrid trainer takes one (the five of
-    ``models/transformer.py::hybrid_body``); a routed expert counts among the
-    active parameters by the chance that a slot picks it."""
-    params = jax.jit(lambda key: init_params(cfg, key))(jax.random.PRNGKey(seed))
-
-    def body_loss(params, emb_in, targets):
-        return loss_fn(cfg, params, emb_in, targets, loss_chunk)
-
-    return (params, body_loss, lambda p, e: logits(cfg, p, e),
-            count_params(cfg)["active"], BODY_SCOPE)
+    return moe.head_logits(hidden, params["lm_head"]["kernel"])

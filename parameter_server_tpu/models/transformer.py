@@ -114,17 +114,27 @@ def tiny_config(causal: bool = True, **kw) -> TransformerConfig:
     return TransformerConfig(**defaults)
 
 
-def _rotary(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Apply rotary embedding over the last (head_dim) axis. x: [B,S,H,D]."""
+def _rotary(x: jax.Array, positions: jax.Array, theta: float,
+            halves: bool = False) -> jax.Array:
+    """Apply rotary embedding over the last (head_dim) axis. x: [B,S,H,D].
+    Frequency ``i`` turns the pair ``(x[2i], x[2i + 1])``, or with
+    ``halves`` the pair ``(x[i], x[i + D/2])`` (the ``rotate_half``
+    convention)."""
     d = x.shape[-1]
     freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions[:, :, None].astype(jnp.float32) * freq  # [B,S,D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
+    if halves:
+        x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    else:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
     out1 = x1 * cos - x2 * sin
     out2 = x2 * cos + x1 * sin
-    out = jnp.stack([out1, out2], axis=-1).reshape(x.shape)
+    if halves:
+        out = jnp.concatenate([out1, out2], axis=-1)
+    else:
+        out = jnp.stack([out1, out2], axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
 
 

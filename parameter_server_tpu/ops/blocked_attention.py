@@ -7,6 +7,14 @@ all heads is ever live (at 8,192 tokens and 32 heads that tensor is 8.6 GB a
 sequence).  The work is the causal half plus half a band's width of masked
 scores a block.
 
+**Grouped queries**: ``k`` and ``v`` may have fewer heads than ``q`` (``Hkv``
+key-value heads, each serving the ``G = H / Hkv`` query heads ``g G .. g G +
+G - 1``).  The queries are then read as ``[B, S, Hkv, G, D]`` and scored
+against their group's one key head; ``k`` and ``v`` are never copied out per
+query head, forward or backward (``dk`` and ``dv`` are summed over a group's
+query heads by the product that forms them).  With ``Hkv == H`` there is no
+group axis, and the program is the one it was before groups existed.
+
 A key may have a part all heads share (latent attention's positional part,
 ``k_shared [B, S, Dr]`` against ``q_shared [B, S, H, Dr]``): it is scored on
 its own, so it is never copied out per head.
@@ -32,12 +40,37 @@ import jax
 import jax.numpy as jnp
 
 
+def _grouped(x, kv_heads):
+    """``[B, S, H, ...]`` -> ``[B, S, Hkv, G, ...]`` (query head ``h`` is ``(h
+    // G, h % G)``); as it came where ``Hkv == H``."""
+    H = x.shape[2]
+    if H == kv_heads:
+        return x
+    return x.reshape(*x.shape[:2], kv_heads, H // kv_heads, *x.shape[3:])
+
+
+def _ungrouped(x, heads):
+    """``[B, S, Hkv, G, ...]`` or ``[B, S, H, ...]`` -> ``[B, S, H, ...]``."""
+    if x.shape[2] == heads:
+        return x
+    return x.reshape(*x.shape[:2], heads, *x.shape[4:])
+
+
+def _g(q, k):
+    """The group axis in a product's subscripts: ``g`` where the queries
+    carry one (``q [B, bq, Hkv, G, D]``), none otherwise."""
+    return "g" if q.ndim > k.ndim else ""
+
+
 def _scores(start, scale, q, k, q_shared, k_shared):
-    """Masked, scaled scores ``[B, H, bq, end]`` of the queries ``start ..``."""
+    """Masked, scaled scores ``[B, Hkv, (G,) bq, end]`` of the queries
+    ``start ..``."""
+    g = _g(q, k)
     s = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+        f"bqh{g}d,bkhd->bh{g}qk", q, k, preferred_element_type=jnp.float32
     ) + jnp.einsum(
-        "bqhd,bkd->bhqk", q_shared, k_shared, preferred_element_type=jnp.float32
+        f"bqh{g}d,bkd->bh{g}qk", q_shared, k_shared,
+        preferred_element_type=jnp.float32,
     )
     q_ids = start + jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
     k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
@@ -91,9 +124,12 @@ def _attention(block, band, scale, q, k, v, q_shared, k_shared):
 
 
 def _forward(block, band, scale, q, k, v, q_shared, k_shared):
-    S = q.shape[1]
+    S, H = q.shape[1:3]
     padded = -(-S // block) * block
-    q, q_shared = _pad_queries(q, padded), _pad_queries(q_shared, padded)
+    q, q_shared = (
+        _grouped(_pad_queries(x, padded), k.shape[2]) for x in (q, q_shared)
+    )
+    g = _g(q, k)
     outs, lses = [], []
     for a, n, end in _bands(S, block, band):
         qa, qsa = _after(
@@ -108,15 +144,18 @@ def _forward(block, band, scale, q, k, v, q_shared, k_shared):
             p = jnp.exp(s - m)
             l = jnp.sum(p, axis=-1, keepdims=True)  # noqa: E741
             out = jnp.einsum(
-                "bhqk,bkhd->bqhd", p / l, vb, preferred_element_type=jnp.float32
+                f"bh{g}qk,bkhd->bqh{g}d", p / l, vb,
+                preferred_element_type=jnp.float32,
             )
             return None, (out, (m + jnp.log(l))[..., 0])
 
         _, (out, lse) = jax.lax.scan(
             one, None, (jnp.arange(n // block), _split(qa, block), _split(qsa, block))
         )
-        outs.append(_join(out))
-        lses.append(jnp.moveaxis(_join(jnp.moveaxis(lse, 3, 2)), 1, 2))
+        outs.append(_ungrouped(_join(out), H))
+        lses.append(jnp.moveaxis(
+            _ungrouped(_join(jnp.moveaxis(lse, -1, 2)), H), 1, 2
+        ))
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
     lse = lses[0] if len(lses) == 1 else jnp.concatenate(lses, axis=2)
     return out[:, :S], lse[:, :, :S]
@@ -141,6 +180,12 @@ def _bwd(block, band, scale, res, d_out):
     lse = _pad_queries(jnp.moveaxis(lse, 1, 2), padded)  # [B, S, H]
     if padded > S:
         lse = lse.at[:, S:].set(jnp.inf)
+    q, q_shared, d_out, delta, lse = (
+        _grouped(x, k.shape[2]) for x in (q, q_shared, d_out, delta, lse)
+    )
+    g = _g(q, k)
+    # [B, bq, Hkv, (G)] -> [B, Hkv, (G,) bq, 1], beside the scores
+    per_query = lambda x: jnp.moveaxis(x, 1, -1)[..., None]  # noqa: E731
     dk, dv, dks = jnp.zeros_like(k), jnp.zeros_like(v), jnp.zeros_like(k_shared)
     dq, dqs = [], []
     for a, n, end in _bands(S, block, band):
@@ -154,19 +199,26 @@ def _bwd(block, band, scale, res, d_out):
             dkb, dvb, dksb = carry
             i, qb, qsb, do, dl, ls = x
             s = _scores(a + i * block, scale, qb, kb, qsb, ksb)
-            p = jnp.exp(s - jnp.moveaxis(ls, 1, 2)[..., None])
+            p = jnp.exp(s - per_query(ls))
+            # dk, dv: the product sums over a group's query heads
             dvb = dvb + jnp.einsum(
-                "bhqk,bqhd->bkhd", p, do, preferred_element_type=f32
+                f"bh{g}qk,bqh{g}d->bkhd", p, do, preferred_element_type=f32
             )
-            dp = jnp.einsum("bqhd,bkhd->bhqk", do, vb, preferred_element_type=f32)
-            ds = p * (dp - jnp.moveaxis(dl, 1, 2)[..., None]) * scale
-            dqb = jnp.einsum("bhqk,bkhd->bqhd", ds, kb, preferred_element_type=f32)
-            dqsb = jnp.einsum("bhqk,bkd->bqhd", ds, ksb, preferred_element_type=f32)
+            dp = jnp.einsum(
+                f"bqh{g}d,bkhd->bh{g}qk", do, vb, preferred_element_type=f32
+            )
+            ds = p * (dp - per_query(dl)) * scale
+            dqb = jnp.einsum(
+                f"bh{g}qk,bkhd->bqh{g}d", ds, kb, preferred_element_type=f32
+            )
+            dqsb = jnp.einsum(
+                f"bh{g}qk,bkd->bqh{g}d", ds, ksb, preferred_element_type=f32
+            )
             dkb = dkb + jnp.einsum(
-                "bhqk,bqhd->bkhd", ds, qb, preferred_element_type=f32
+                f"bh{g}qk,bqh{g}d->bkhd", ds, qb, preferred_element_type=f32
             )
             dksb = dksb + jnp.einsum(
-                "bhqk,bqhd->bkd", ds, qsb, preferred_element_type=f32
+                f"bh{g}qk,bqh{g}d->bkd", ds, qsb, preferred_element_type=f32
             )
             return (dkb, dvb, dksb), (dqb, dqsb)
 
@@ -178,8 +230,8 @@ def _bwd(block, band, scale, res, d_out):
             acc.at[:, :end].add(part)
             for acc, part in ((dk, dkb), (dv, dvb), (dks, dksb))
         )
-        dq.append(_join(dqa))
-        dqs.append(_join(dqsa))
+        dq.append(_ungrouped(_join(dqa), out.shape[2]))
+        dqs.append(_ungrouped(_join(dqsa), out.shape[2]))
     cat = lambda xs: (xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=1))[:, :S]  # noqa: E731
     return cat(dq), dk, dv, cat(dqs), dks
 
@@ -189,10 +241,14 @@ _attention.defvjp(_fwd, _bwd)
 
 def blocked_causal_attention(q, k, v, *, block: int, scale: float,
                              band: int = 4, q_shared=None, k_shared=None):
-    """``q, k [B, S, H, D]``, ``v [B, S, H, Dv]`` -> ``[B, S, H, Dv]``
-    float32.  ``band``: blocks of ``block`` queries that share a key
-    prefix."""
+    """``q [B, S, H, D]``, ``k [B, S, Hkv, D]``, ``v [B, S, Hkv, Dv]`` with
+    ``H % Hkv == 0`` -> ``[B, S, H, Dv]`` float32.  ``band``: blocks of
+    ``block`` queries that share a key prefix."""
     B, S, H, _ = q.shape
+    if k.shape[2] != v.shape[2] or H % k.shape[2]:
+        raise ValueError(
+            f"{H} query heads over {k.shape[2]} key and {v.shape[2]} value heads"
+        )
     if q_shared is None:  # a shared part of width 0 scores 0
         q_shared = jnp.zeros((B, S, H, 0), q.dtype)
         k_shared = jnp.zeros((B, S, 0), k.dtype)

@@ -19,7 +19,13 @@ dense MLP pair as above); the delta-rule mixer's per-head parameters
 (convolutions, the low-rank projections' second halves, decay rates and
 biases, the ``b`` gate) over their head axis; latent attention's ``kv_b``
 over its heads, ``kv_a`` and the latent's norm whole (every head reads the
-whole latent).
+whole latent).  The convolution-and-attention body (``models/lfm2_moe.py``)
+adds the gated short convolution, split over its channels (a channel shares
+nothing with another between the two products): ``in_proj`` ``[3, D, C]``
+column-parallel, the taps ``[L, C]``, ``out_proj`` ``[C, D]`` row-parallel.
+Its grouped-query attention takes the rules above (``k`` and ``v`` over their
+own, fewer, heads; the per-head norms whole); the experts' selection bias
+stays whole beside the router.
 """
 
 from __future__ import annotations
@@ -46,6 +52,12 @@ def _spec_for(path: tuple[str, ...], value: Any) -> P:
         return P(MODEL_AXIS, None, None)
     if leaf.startswith("conv_"):  # depthwise [width, heads, head_dim]
         return P(None, MODEL_AXIS, None)
+    if leaf == "taps":  # depthwise [taps, channels]
+        return P(None, MODEL_AXIS)
+    if parent == "in_proj":  # [3, d_model, channels]
+        return P(None, None, MODEL_AXIS)
+    if parent == "out_proj":  # [channels, d_model]
+        return P(MODEL_AXIS, None)
     if leaf == "A_log":  # [heads]
         return P(MODEL_AXIS)
     if leaf == "dt_bias":  # [heads, head_dim]

@@ -9,7 +9,7 @@ import subprocess
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LIMIT_S = 600  # the suite takes half a minute alone on this box
+LIMIT_S = 900  # the suite takes two and a half minutes alone on this box (PR 33)
 
 
 def test_benchmark_suite_passes():
