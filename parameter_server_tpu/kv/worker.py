@@ -79,6 +79,7 @@ from parameter_server_tpu.utils.keys import (
     HashLocalizer,
     flat_keys,
     leg_bucket,
+    localize_engine,
     localize_to_slots,
 )
 from parameter_server_tpu.utils.platform import role_device
@@ -216,8 +217,10 @@ class KVWorker(Customer):
         #: (:meth:`_localize`).  One entry a table, swapped whole: a second
         #: thread sees the old or the new
         self._localized: Dict[str, _Localized] = {}
-        #: localizations computed / handed back from ``_localized``
+        #: localizations computed (of those, by the native pass of
+        #: ``utils/keys.py``) / handed back from ``_localized``
         self.localize_computed = 0
+        self.localize_native = 0
         self.localize_reused = 0
         #: per-timestamp reassembly info for pulls
         self._pull_plans: Dict[int, dict] = {}
@@ -414,6 +417,7 @@ class KVWorker(Customer):
             "pull_assembled_host": self.pull_assembled_host,
             "push_combined_from_device": self.push_combined_from_device,
             "localize_computed": self.localize_computed,
+            "localize_native": self.localize_native,
             "localize_reused": self.localize_reused,
         }
         if self._group is not None:
@@ -1310,7 +1314,8 @@ class KVWorker(Customer):
         remembered past the next.  The pair is shared between a pull's
         plan and the push, so it is not writable.  ``root``, the request's
         root span, is told which it was (``localize="computed"`` /
-        ``"reused"``)."""
+        ``"reused"``); the ``ps.worker.localize`` span says which engine
+        computed (``engine``: ``utils/keys.py::localize_engine``)."""
         loc = self.localizers[table]
         flat = flat_keys(keys)
         kept = self._localized.get(table)
@@ -1329,7 +1334,10 @@ class KVWorker(Customer):
                 slots, inverse, n = localize_to_slots(
                     flat, loc, min_bucket=self.min_bucket
                 )
-                sp.set(unique=n)
+                engine = localize_engine(loc)
+                sp.set(unique=n, engine=engine)
+            if engine == "native":
+                self.localize_native += 1
             slots.flags.writeable = False
             inverse.flags.writeable = False
             kept = self._localized[table] = _Localized(

@@ -1,4 +1,6 @@
-// Native persistent key->slot map: the Localizer hot path.
+// Native key localization: the persistent key->slot map that is the stateful
+// Localizer's hot path, and (further down) the one-pass batch localization
+// of the stateless maps.
 //
 // The reference keeps the streaming-key vocabulary in the server's C++ hash
 // map (``src/parameter/kv_map.h`` / ``src/util/localizer.h`` [U] —
@@ -18,9 +20,12 @@
 //
 // ABI is plain C for ctypes.
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <new>
+#include <vector>
 
 namespace {
 
@@ -119,6 +124,149 @@ struct KeyMap {
   }
 };
 
+// ---------------------------------------------------------------------------
+// One-pass batch localization for the STATELESS maps of utils/keys.py
+// (HashLocalizer at 64 and 32 hash bits, IdentityLocalizer): what
+// ``localize_to_slots`` returns -- the sorted distinct slots of a batch and
+// every position's rank among them -- without sorting the positions.  A slot
+// there is a pure function of its key, so "unique keys, assign, unique slots"
+// and "assign every position, unique slots" are the same set and the same
+// inverse: each position's slot is computed as the Python class computes it,
+// deduplicated through an open-addressing table in first-seen order, and
+// only the distinct slots (tens of thousands of a Zipf batch's hundreds of
+// thousands of positions) are sorted.
+
+constexpr uint32_t kNoSlot = 0xFFFFFFFFu;  // capacity < 2^31 - 1: never a slot
+
+enum SlotKind : int { kHash64 = 0, kHash32 = 1, kIdentity = 2 };
+
+inline uint32_t mix32(uint32_t x) {  // murmur3 fmix32 == utils.keys.mix32
+  x ^= x >> 16;
+  x *= 0x85EBCA6Bu;
+  x ^= x >> 13;
+  x *= 0xC2B2AE35u;
+  x ^= x >> 16;
+  return x;
+}
+
+// Scratch of one calling thread, kept between calls: workers localize
+// concurrently (ctypes releases the GIL), and a steady-state call allocates
+// nothing here.
+struct Dedup {
+  struct Entry {
+    uint32_t slot;  // kNoSlot where empty
+    int32_t id;     // the slot's first-seen id
+  };
+  std::vector<Entry> table;     // open addressing, load factor <= 1/2
+  std::vector<uint64_t> order;  // slot << 32 | first-seen id: by id while
+                                // the positions pass, then sorted by slot
+                                // (what ps_localize_take hands out)
+  std::vector<uint64_t> order_tmp;
+  std::vector<int32_t> rank;    // first-seen id -> rank among the sorted
+  uint32_t mask = 0;
+  int shift = 0;  // 32 - log2(table.size())
+
+  // Fibonacci hashing, the product's HIGH bits: identity slots are dense or
+  // strided ids, and the low bits of a stride of 2^k are all alike.
+  inline uint32_t home(uint32_t slot) const {
+    return static_cast<uint32_t>((slot * 0x9E3779B1u) >> shift);
+  }
+
+  void resize_table(size_t size) {
+    table.assign(size, Entry{kNoSlot, 0});
+    mask = static_cast<uint32_t>(size - 1);
+    shift = 32;
+    while ((size_t{1} << (32 - shift)) < size) --shift;
+  }
+
+  // The table starts a call sized for the last call's distinct slots (a
+  // thread's batches are alike) and grows from there: a table left at its
+  // high-water mark would make every small batch pay for clearing it.
+  void reset() {
+    size_t size = 1 << 12;
+    while (size < order.size() * 2) size *= 2;
+    resize_table(size);
+    order.clear();
+  }
+
+  void grow() {
+    resize_table(table.size() * 2);
+    for (size_t id = 0; id < order.size(); ++id) {
+      const uint32_t slot = static_cast<uint32_t>(order[id] >> 32);
+      uint32_t p = home(slot);
+      while (table[p].slot != kNoSlot) p = (p + 1) & mask;
+      table[p] = Entry{slot, static_cast<int32_t>(id)};
+    }
+  }
+
+  // find-or-insert; returns the slot's first-seen id
+  inline int32_t id_of(uint32_t slot) {
+    uint32_t p = home(slot);
+    for (;;) {
+      const Entry e = table[p];
+      if (e.slot == slot) return e.id;
+      if (e.slot == kNoSlot) break;
+      p = (p + 1) & mask;
+    }
+    const size_t id = order.size();
+    table[p] = Entry{slot, static_cast<int32_t>(id)};
+    order.push_back((static_cast<uint64_t>(slot) << 32) | id);
+    if (order.size() * 2 > table.size()) grow();
+    return static_cast<int32_t>(id);
+  }
+
+  // ``order`` by its high word, the slot (< 2^31): an LSD radix sort of
+  // three 11-bit digits, linear where a comparison sort of the distinct
+  // slots was a third of the whole call.
+  void sort_order() {
+    order_tmp.resize(order.size());
+    for (int digit = 32; digit < 64; digit += 11) {
+      size_t start[2048] = {0};
+      for (uint64_t v : order) ++start[(v >> digit) & 2047];
+      size_t at = 0;
+      for (size_t& c : start) {
+        const size_t count = c;
+        c = at;
+        at += count;
+      }
+      for (uint64_t v : order) order_tmp[start[(v >> digit) & 2047]++] = v;
+      order.swap(order_tmp);
+    }
+  }
+};
+
+thread_local Dedup tls_dedup;
+
+// Pass one: inverse[i] = first-seen id of position i's slot.  Returns false
+// on an identity key outside [0, capacity) and leaves the smallest such key
+// in *bad (what IdentityLocalizer.assign names: the first of the sorted keys).
+template <int kKind>
+bool first_seen_ids(Dedup& d, const uint64_t* in, int64_t n, uint64_t capacity,
+                    uint64_t seed, int32_t* inverse, uint64_t* bad) {
+  const uint32_t trash = static_cast<uint32_t>(capacity);
+  const uint32_t seed32 = static_cast<uint32_t>(seed);
+  bool ok = true;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint64_t k = in[i];
+    uint32_t slot;
+    if (k == kEmpty) {
+      slot = trash;
+    } else if (kKind == kHash64) {
+      slot = static_cast<uint32_t>(mix64(k ^ seed) % capacity);
+    } else if (kKind == kHash32) {
+      slot = mix32(static_cast<uint32_t>(k) ^ seed32) % trash;
+    } else if (k < capacity) {
+      slot = static_cast<uint32_t>(k);
+    } else {
+      if (ok || k < *bad) *bad = k;
+      ok = false;
+      continue;
+    }
+    inverse[i] = d.id_of(slot);
+  }
+  return ok;
+}
+
 }  // namespace
 
 extern "C" {
@@ -157,6 +305,50 @@ void ps_keymap_assign(void* h, const uint64_t* in, int64_t n, int32_t* out) {
     uint64_t k = in[i];
     out[i] = (k == kEmpty) ? trash : m->assign_one(k);
   }
+}
+
+// Localize n keys through a stateless map (``kind``: 0 mix64 % capacity,
+// 1 mix32 of the truncated key % capacity, 2 the key itself; PAD ->
+// capacity).  Writes each position's rank among the batch's sorted distinct
+// slots to ``inverse`` and returns their count; the slots themselves stay in
+// the calling thread's scratch for ps_localize_take.  -1: an identity key is
+// out of range (*bad names it, nothing is clamped); -2: out of memory.
+int64_t ps_localize_slots(int kind, const uint64_t* in, int64_t n,
+                          int64_t capacity, uint64_t seed, int32_t* inverse,
+                          uint64_t* bad) {
+  Dedup& d = tls_dedup;
+  const uint64_t cap = static_cast<uint64_t>(capacity);
+  try {
+    d.reset();
+    bool ok;
+    if (kind == kHash64) {
+      ok = first_seen_ids<kHash64>(d, in, n, cap, seed, inverse, bad);
+    } else if (kind == kHash32) {
+      ok = first_seen_ids<kHash32>(d, in, n, cap, seed, inverse, bad);
+    } else {
+      ok = first_seen_ids<kIdentity>(d, in, n, cap, seed, inverse, bad);
+    }
+    if (!ok) return -1;
+    d.sort_order();
+    const size_t m = d.order.size();
+    d.rank.resize(m);
+    for (size_t r = 0; r < m; ++r) {
+      d.rank[d.order[r] & 0xFFFFFFFFu] = static_cast<int32_t>(r);
+    }
+    for (int64_t i = 0; i < n; ++i) inverse[i] = d.rank[inverse[i]];
+    return static_cast<int64_t>(m);
+  } catch (const std::bad_alloc&) {
+    return -2;
+  }
+}
+
+// The calling thread's last ps_localize_slots result into ``out[bucket]``:
+// the sorted distinct slots, then ``pad`` (the trash row) up to the bucket.
+void ps_localize_take(int32_t* out, int64_t bucket, int32_t pad) {
+  const std::vector<uint64_t>& order = tls_dedup.order;
+  const int64_t m = std::min<int64_t>(order.size(), bucket);
+  for (int64_t r = 0; r < m; ++r) out[r] = static_cast<int32_t>(order[r] >> 32);
+  std::fill(out + m, out + bucket, pad);
 }
 
 }  // extern "C"

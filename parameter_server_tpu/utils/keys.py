@@ -195,13 +195,27 @@ def localize_to_slots(
 ) -> Tuple[np.ndarray, np.ndarray, int]:
     """Full host-side key pipeline: raw keys -> unique row slots + inverse.
 
-    Composes :func:`localize_batch` with :meth:`Localizer.assign` and then
-    re-uniquifies the *slots* (after vocabulary overflow two distinct keys may
-    hash-share a slot; the device requires unique ids for the scatter fast
-    path).  Returns ``(slots, inverse, n)``: sorted unique slot ids padded to
-    a power-of-two bucket (pads point at the trash row ``capacity``),
+    Returns ``(slots, inverse, n)``: sorted unique slot ids padded to a
+    power-of-two bucket (pads point at the trash row ``capacity``),
     position->slot-row inverse, and the true unique-slot count.
+
+    Two engines, one result (:func:`localize_engine` says which runs).  The
+    definition composes :func:`localize_batch` with ``localizer.assign`` and
+    then re-uniquifies the *slots* (two distinct keys may hash-share a slot;
+    the device requires unique ids for the scatter fast path): two sorts of
+    the positions.  A stateless localizer's slot is a pure function of the
+    key, so for those "unique keys, assign, unique slots" and "assign every
+    position, unique slots" are the same set and the same inverse, and the
+    native pass (``native/src/keymap.cc`` :: ``ps_localize_slots``) hashes
+    every position, dedups the slots through a table and sorts only the
+    distinct ones: bit for bit the same arrays, collisions and ``PAD_KEY``
+    included.  The stateful :class:`Localizer` hands out rows in the arrival
+    order of the *sorted unique* keys, so there the composition is the
+    definition and stays.
     """
+    native = _native_pass(localizer)
+    if native is not None:
+        return _localize_native(*native, flat_keys(keys), localizer, min_bucket)
     uniq, key_inv, _ = localize_batch(
         keys, pad_to_bucket=False, min_bucket=min_bucket
     )
@@ -215,6 +229,67 @@ def localize_to_slots(
         )
     inverse = slot_inv[key_inv].astype(np.int32)
     return uniq_slots.astype(np.int32, copy=False), inverse, n
+
+
+def _native_pass(localizer):
+    """``(lib, kind)`` of ``ps_localize_slots`` where the native pass runs
+    for ``localizer``: it is one of the stateless classes themselves (a
+    subclass may map otherwise) and the keymap library loaded.  None
+    otherwise."""
+    if type(localizer) is IdentityLocalizer:
+        kind = 2
+    elif type(localizer) is HashLocalizer:
+        kind = 1 if localizer.hash_bits == 32 else 0
+    else:
+        return None
+    lib = _keymap_lib()
+    return None if lib is None else (lib, kind)
+
+
+def localize_engine(localizer) -> str:
+    """Which engine :func:`localize_to_slots` runs for ``localizer``:
+    ``"native"`` (one pass over the positions) or ``"numpy"``."""
+    return "numpy" if _native_pass(localizer) is None else "native"
+
+
+def _localize_native(
+    lib, kind: int, flat: np.ndarray, localizer, min_bucket: int
+) -> Tuple[np.ndarray, np.ndarray, int]:
+    """:func:`localize_to_slots` of flat ``uint64`` keys through
+    ``ps_localize_slots``.  The seed goes through the NumPy scalar the
+    Python class builds, so a seed it refuses is refused here the same."""
+    import ctypes
+
+    capacity = int(localizer.capacity)
+    if kind == 2:
+        seed = 0
+    else:
+        seed = int((np.uint32 if kind == 1 else np.uint64)(localizer.seed))
+    inverse = np.empty(flat.shape[0], dtype=np.int32)
+    bad = ctypes.c_uint64(0)
+    n = lib.ps_localize_slots(
+        kind,
+        flat.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+        flat.shape[0],
+        capacity,
+        seed,
+        inverse.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        ctypes.byref(bad),
+    )
+    if n == -1:
+        raise ValueError(
+            f"IdentityLocalizer: key {bad.value} outside [0, "
+            f"{capacity}) (dense-vocab tables take raw ids)"
+        )
+    if n < 0:
+        raise MemoryError("ps_localize_slots: out of memory")
+    slots = np.empty(bucket_size(n, min_bucket=min_bucket), dtype=np.int32)
+    lib.ps_localize_take(
+        slots.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        slots.shape[0],
+        capacity,
+    )
+    return slots, inverse, int(n)
 
 
 class HashLocalizer:
@@ -324,8 +399,10 @@ class _NativeKeyMap:
                 pass
 
 
-def _native_keymap(capacity: int):
-    """Load the native keymap engine, or None (numpy fallback)."""
+def _keymap_lib():
+    """The native keymap library (``native/src/keymap.cc``) with its
+    signatures declared, or None (no toolchain, ``PS_NO_NATIVE``: the
+    NumPy engines run)."""
     import ctypes
 
     from parameter_server_tpu import native
@@ -346,8 +423,30 @@ def _native_keymap(capacity: int):
             ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int32),
         ]
+        lib.ps_localize_slots.argtypes = [
+            ctypes.c_int,
+            ctypes.POINTER(ctypes.c_uint64),
+            ctypes.c_int64,
+            ctypes.c_int64,
+            ctypes.c_uint64,
+            ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_uint64),
+        ]
+        lib.ps_localize_slots.restype = ctypes.c_int64
+        lib.ps_localize_take.argtypes = [
+            ctypes.POINTER(ctypes.c_int32),
+            ctypes.c_int64,
+            ctypes.c_int32,
+        ]
+        lib.ps_localize_take.restype = None
         lib._ps_keymap_sigs = True
-    return _NativeKeyMap(lib, capacity)
+    return lib
+
+
+def _native_keymap(capacity: int):
+    """The native keymap engine, or None (numpy fallback)."""
+    lib = _keymap_lib()
+    return None if lib is None else _NativeKeyMap(lib, capacity)
 
 
 class Localizer:

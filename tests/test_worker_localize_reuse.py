@@ -338,3 +338,38 @@ def test_counters_and_spans_read_as_the_loops_imply():
         assert len(spans("ps.worker.localize")) == 3
     finally:
         van.close()
+
+
+#: localizer kind, whether the keymap library loads -> the engine that runs
+ENGINES = [
+    ("hash64", True, "native"),
+    ("hash32", True, "native"),
+    ("identity", True, "native"),
+    ("stateful", True, "numpy"),  # two np.unique are its definition
+    ("hash64", False, "numpy"),  # no toolchain, PS_NO_NATIVE
+]
+
+
+@pytest.mark.parametrize("kind, library, engine", ENGINES)
+def test_the_localize_span_names_its_engine_and_a_counter_counts_it(
+    kind, library, engine, monkeypatch
+):
+    from parameter_server_tpu.utils import keys as keys_mod
+
+    if not library:
+        monkeypatch.setattr(keys_mod, "_keymap_lib", lambda: None)
+    elif keys_mod._keymap_lib() is None:  # pragma: no cover
+        pytest.skip("no native toolchain")
+    van, worker = _cluster(kind)
+    try:
+        for seed in (1, 2, 3):
+            keys, grads = _batch(kind, seed=seed)
+            worker.pull_sync("t", keys, timeout=30)
+            worker.push_sync("t", keys, grads, timeout=30)
+        c = worker.counters()
+        assert (c["localize_computed"], c["localize_reused"]) == (3, 3)
+        assert c["localize_native"] == (3 if engine == "native" else 0)
+        spans = worker.tracer.spans("ps.worker.localize")
+        assert [s[4]["engine"] for s in spans] == [engine] * 3
+    finally:
+        van.close()
