@@ -360,6 +360,25 @@ def _build_lfm2_moe_hybrid(cfg: AppConfig) -> Callable[[], dict]:
     return run
 
 
+@register_app("laguna_hybrid")
+def _build_laguna_hybrid(cfg: AppConfig) -> Callable[[], dict]:
+    """The same hybrid path under a body of window and full attention
+    layers (``models/laguna.py``: each kind with its own head count, rotary
+    table and key range, a gate a head on the attention output, a dense MLP
+    and a held share of routed experts with a shared one), tiny sizes by
+    default so the app runs anywhere; the benchmark's ``laguna_xs2``
+    configuration runs the published widths through the same trainer."""
+
+    def run() -> dict:
+        from parameter_server_tpu.models import laguna
+
+        return _run_hybrid(cfg, laguna.tiny_config(
+            vocab_size=min(cfg.data.key_space, 1 << 16),
+        ))
+
+    return run
+
+
 def _sp_app_knobs(cfg: AppConfig, round_to: int):
     """Shared knobs of the long-context apps (sp_lm / sptp_lm).
 

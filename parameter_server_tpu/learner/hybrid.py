@@ -71,9 +71,11 @@ class HybridLMTrainer:
     ``cfg`` is the body's model config, whose ``hybrid_body(seed,
     loss_chunk)`` builds what is trained: a ``TransformerConfig`` (one kind
     of block), a ``KimiLinearConfig`` (a layer pattern of delta-rule and
-    latent-attention mixers, dense and expert MLPs) or an ``Lfm2MoeConfig``
+    latent-attention mixers, dense and expert MLPs), an ``Lfm2MoeConfig``
     (gated short convolutions and grouped-query attention, dense and
-    bias-selected expert MLPs).
+    bias-selected expert MLPs) or a ``LagunaConfig`` (window and full
+    attention layers with their own head counts and rotary tables, a gated
+    attention output, dense and expert MLPs with a shared expert).
 
     **Buffers.**  A body may hold leaves that are state and not weights (an
     expert layer's selection bias): its config names them (``cfg.buffers``,

@@ -115,16 +115,35 @@ def tiny_config(causal: bool = True, **kw) -> TransformerConfig:
 
 
 def _rotary(x: jax.Array, positions: jax.Array, theta: float,
-            halves: bool = False) -> jax.Array:
+            halves: bool = False, *, inv_freq=None, rotary_dim=None,
+            amplitude: float = 1.0) -> jax.Array:
     """Apply rotary embedding over the last (head_dim) axis. x: [B,S,H,D].
     Frequency ``i`` turns the pair ``(x[2i], x[2i + 1])``, or with
     ``halves`` the pair ``(x[i], x[i + D/2])`` (the ``rotate_half``
-    convention)."""
-    d = x.shape[-1]
-    freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    convention).
+
+    What a body with more than one table gives (``models/laguna.py``; with
+    none of them given this builds the program it built before they
+    existed): ``rotary_dim``: only the first ``rotary_dim`` dimensions of
+    the head are turned (``D`` above is then ``rotary_dim``) and the rest
+    pass through untouched (a partial rotary factor); ``inv_freq
+    [rotary_dim / 2]``: the frequencies, where they are not ``theta^(-2i /
+    D)`` (a scaled table such as YaRN's; ``theta`` is then not read);
+    ``amplitude``: a factor on ``cos`` and ``sin`` (YaRN's attention factor,
+    by Hugging Face's convention)."""
+    d = x.shape[-1] if rotary_dim is None else rotary_dim
+    if inv_freq is None:
+        freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        freq = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[:, :, None].astype(jnp.float32) * freq  # [B,S,D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
+    rest = None
+    if d < x.shape[-1]:
+        x, rest = x[..., :d], x[..., d:]
     if halves:
         x1, x2 = x[..., : d // 2], x[..., d // 2:]
     else:
@@ -135,7 +154,8 @@ def _rotary(x: jax.Array, positions: jax.Array, theta: float,
         out = jnp.concatenate([out1, out2], axis=-1)
     else:
         out = jnp.stack([out1, out2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
+    out = out.astype(x.dtype)
+    return out if rest is None else jnp.concatenate([out, rest], axis=-1)
 
 
 class Norm(nn.Module):

@@ -25,7 +25,12 @@ nothing with another between the two products): ``in_proj`` ``[3, D, C]``
 column-parallel, the taps ``[L, C]``, ``out_proj`` ``[C, D]`` row-parallel.
 Its grouped-query attention takes the rules above (``k`` and ``v`` over their
 own, fewer, heads; the per-head norms whole); the experts' selection bias
-stays whole beside the router.
+stays whole beside the router.  The window-and-full attention body
+(``models/laguna.py``) adds the attention output's gate ``o_gate`` ``[D,
+H]``, one value a head: column-parallel over the heads beside ``q``, whose
+heads it gates.  A layer's head count is its own kernels' (48 and 64 in one
+body): every rule here reads a leaf's shape and none a config's one count,
+and each count splits where the key-value heads it is grouped over do.
 """
 
 from __future__ import annotations
@@ -66,7 +71,7 @@ def _spec_for(path: tuple[str, ...], value: Any) -> P:
         if leaf == "kernel":  # [rank, heads, head_dim]
             return P(None, MODEL_AXIS, None)
         return P(MODEL_AXIS, None)  # bias [heads, head_dim]
-    if parent == "b":  # [d_model, heads]
+    if parent in ("b", "o_gate"):  # [d_model, heads]
         return P(None, MODEL_AXIS)
     if parent in ("q", "k", "v"):
         if leaf == "kernel":  # [d_model, heads, head_dim]

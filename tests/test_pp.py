@@ -197,11 +197,14 @@ def test_pipeline_bubble_amortizes_with_microbatches():
         micro = jnp.asarray(trainer._micro(tokens))
         params = trainer._params()
         trainer._loss(params, micro)  # compile
-        t0 = time.perf_counter()
-        reps = 3
-        for _ in range(reps):
+        # the best of a few: a neighbour's compile on a shared host stretches
+        # one repetition, not every one
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
             jax.block_until_ready(trainer._loss(params, micro))
-        per_example = (time.perf_counter() - t0) / reps / (M * mb)
+            best = min(best, time.perf_counter() - t0)
+        per_example = best / (M * mb)
         rows.append((M, per_example, (M + S - 1) / M))
         print(
             f"pp bubble: M={M} per-example={per_example * 1e3:.3f} ms "

@@ -141,7 +141,7 @@ def test_filter_chain_applies_on_wire():
         a.add_route("S0", b.address)
         vals = np.zeros(10000, np.float32)  # compresses well
         assert a.send(_msg(values=[vals]))
-        assert ev.wait(10)
+        assert ev.wait(60)  # a loaded host: the wait is no claim about speed
         np.testing.assert_array_equal(got[0].values[0], vals)
         assert a.bytes_sent() < vals.nbytes // 10  # actually compressed
     finally:

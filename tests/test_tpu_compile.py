@@ -8,6 +8,11 @@ such pass and every apply eight, 85 % of ``criteo_lr.skew``'s device time
 (``PERF.md`` section 6, PR 26); with flat planes the only operations over a
 plane are the gather and scatter fusions and the in-place trash reset.
 
+Blocked attention under a window (``ops/blocked_attention.py``, PR 35) is
+compiled here at a window layer's published shapes: what the step's own
+compile showed (stacked masks of 1.9 GB a layer under a checkpoint,
+``blocked_attention._tied``) shows only for the described chip's memory.
+
 All such compiles live in this one file, and the topology is described
 inside a fixture: only one process may load the TPU's library.
 """
@@ -24,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from parameter_server_tpu.config import OptimizerConfig, TableConfig
 from parameter_server_tpu.kv.table import KVTable
 from parameter_server_tpu.ops import scatter
+from parameter_server_tpu.ops.blocked_attention import blocked_causal_attention
 
 #: one server's shard of ``criteo_lr.skew`` and its largest id bucket.  The
 #: real size, because a small plane compiles differently (the compiler
@@ -125,3 +131,77 @@ def test_column_planes_compile_to_passes_over_the_plane(one_chip):
     c = _compile(col, one_chip, _sds((ROWS + 1, 1)), _sds((N,), jnp.int32))
     assert _plane_ops(c, ROWS + 1) != {}
     assert c.memory_analysis().temp_size_in_bytes >= 4 * ROWS
+
+
+# -- blocked attention -----------------------------------------------------------
+def _attention(window, by_sequence=False):
+    """Value and gradients of blocked attention, jitted; ``by_sequence``: as
+    a body runs it, a ``lax.map`` over the sequences of a checkpointed
+    layer (``models/moe.py::by_sequence``)."""
+    def attn(q, k, v, qs=None, ks=None):
+        return blocked_causal_attention(
+            q, k, v, block=256, band=8, scale=0.125, q_shared=qs, k_shared=ks,
+            window=window,
+        )
+
+    def f(*args):
+        if not by_sequence:
+            return jnp.sum(attn(*args))
+        one = jax.checkpoint(lambda *row: attn(*(a[None] for a in row))[0])
+        return jnp.sum(jax.lax.map(lambda row: one(*row), args))
+
+    return lambda n: jax.jit(jax.value_and_grad(f, argnums=tuple(range(n))))
+
+
+def _program(compiled):
+    """The compiled program's operations, without where in the source each
+    came from (the tables of files and stack frames, every ``metadata``)."""
+    text = compiled.as_text()
+    ops = text[text.index("\n\n%"):] if "\n\n%" in text else text
+    return re.sub(r", metadata=\{[^}]*\}", "", ops)
+
+
+@pytest.mark.parametrize("B,by_sequence,limit", [
+    (1, False, 1 << 29), (2, True, 1 << 31),
+], ids=["bare", "by_sequence"])
+def test_windowed_attention_at_a_window_layer_s_published_shapes(
+    one_chip, B, by_sequence, limit
+):
+    """``[B, 8192, 64, 128]`` queries over 8 key heads, window 512, forward
+    and the written-out backward: the chip's compiler takes it, and what it
+    keeps beside the arguments and the results is a few blocks of scores
+    (``[8, 8, 256, 768]`` float32 is 48 MiB) and, by sequence, a sequence's
+    residuals: 0.38 and 1.44 GiB.  Run as a body runs it, the loop without
+    ``blocked_attention._tied`` keeps its masks and its empty shared part's
+    scores stacked over the 32 blocks, 3.13 GiB, which the limit refuses."""
+    S = 8192
+    shapes = (_sds((B, S, 64, 128)), _sds((B, S, 8, 128)), _sds((B, S, 8, 128)))
+    c = _compile(_attention(512, by_sequence)(3), one_chip, *shapes)
+    assert c.memory_analysis().temp_size_in_bytes < limit
+    # no operation's result is a [S, S] tensor of scores or a stack of blocks
+    sizes = [
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"= (?:f32|pred)\[([\d,]+)\]", c.as_text())
+    ]
+    assert max(sizes) <= B * 64 * S * 128
+
+
+@pytest.mark.parametrize("cell,H,Hkv,D,Dv,Dr", [
+    ("kimi_linear_a3b.pretrain8k", 32, 32, 128, 128, 64),
+    ("lfm2_8b_a1b.pretrain8k", 32, 8, 64, 64, 0),
+])
+def test_a_window_of_every_key_compiles_to_the_causal_program(one_chip, cell, H, Hkv, D, Dv, Dr):
+    """At the attention shapes of the two cells that had this kernel before
+    windows existed: no window and a window of exactly the sequence compile
+    to the same program for the described chip, operation for operation
+    (``tests/test_blocked_window.py`` holds that program's operations to
+    what they were before PR 35, and a wider window to the same jaxpr)."""
+    S = 8192
+    shapes = [_sds((1, S, H, D)), _sds((1, S, Hkv, D)), _sds((1, S, Hkv, Dv))]
+    if Dr:
+        shapes += [_sds((1, S, H, Dr)), _sds((1, S, Dr))]
+    causal, whole = (
+        _program(_compile(_attention(w)(len(shapes)), one_chip, *shapes))
+        for w in (None, S)
+    )
+    assert causal == whole and "while" in causal
