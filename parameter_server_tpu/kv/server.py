@@ -188,6 +188,10 @@ class KVServer(Customer):
         #: including the D2H readback — the histogram the ``ro-p99`` SLO
         #: watches).  Recv-thread-only, like every other counter here.
         self.ro_pulls = 0
+        #: ids the applies had to visit and the bucket slots they were
+        #: padded to (``_counted``)
+        self.apply_ids_real = 0
+        self.apply_ids_bucket = 0
         self.ro_hist: Dict[str, LatencyHistogram] = {
             t: LatencyHistogram() for t in table_cfgs
         }
@@ -548,6 +552,10 @@ class KVServer(Customer):
         out = {
             "fenced_rejects": self.fenced_rejects,
             "ro_pulls": self.ro_pulls,
+            # ids the applies had to visit, of the bucket slots they were
+            # padded to: the pad share a flat plane's apply skips
+            "apply_ids_real": self.apply_ids_real,
+            "apply_ids_bucket": self.apply_ids_bucket,
             # hierarchical push (ISSUE 15): fan-in totals the telemetry
             # plane derives grp_pct from (group-reduced applies / raw
             # member contributions they replaced)
@@ -764,6 +772,24 @@ class KVServer(Customer):
         padded_ids[:n] = ids_np
         return padded_ids
 
+    @staticmethod
+    def _real_ids(table: KVTable, ids_np: np.ndarray) -> int:
+        """How many of a leg's ids an apply has to visit: all up to the last
+        that is a row of the shard.  What follows points at the trash row,
+        which every apply resets: the worker's own bucket pads (it pads its
+        sorted slots with keys past the table, so they are the tail of the
+        last shard's leg: 25 k of a 45 k leg in ``criteo_lr.skew``), and
+        after them this server's."""
+        rows = np.flatnonzero(ids_np != table.rows)
+        return int(rows[-1]) + 1 if rows.size else 0
+
+    def _counted(self, real: int, b: int) -> np.int32:
+        """``real``, the ids an apply visits of the ``b`` its bucket holds,
+        as ``KVTable.push*`` takes it; both counted (``counters``)."""
+        self.apply_ids_real += real
+        self.apply_ids_bucket += b
+        return np.int32(real)
+
     def _put(self, x) -> jax.Array:
         """Stage a request array on THIS server's chip, committed — staged
         on the default device it would be copied across on every push."""
@@ -833,7 +859,8 @@ class KVServer(Customer):
         table = self.tables[tname]
         n = int(ids_np.shape[0])
         b = _bucket(n)
-        sp.set(rows=n, bucket=b, members=1)
+        real = self._real_ids(table, ids_np)
+        sp.set(rows=n, bucket=b, real=real, members=1)
         tctx = msg.task.payload.get(TRACE_KEY)
         tok = (
             self.ledger.begin(
@@ -855,7 +882,7 @@ class KVServer(Customer):
                 tok.mark_h2d()
             h2d.set(bytes=ids.nbytes + vals.nbytes)
         with self.tracer.span("ps.server.dispatch", op="push"):
-            ref = table.push(ids, vals)
+            ref = table.push(ids, vals, self._counted(real, b))
         if tok is not None:
             self.ledger.submit(tok, ref, lambda t=table: t.value)
         return self._ack_push(msg, tname, kn, segs)
@@ -1219,7 +1246,7 @@ class KVServer(Customer):
         with self.tracer.span(
             "ps.server.push", **self._span_attrs(group[0][1]),
             rows=rows, bucket=bm, members=k,
-        ):
+        ) as sp:
             with self.tracer.span("ps.server.h2d") as h2d:
                 stack = self._stack_planes(table, group, k, bm, tok)
                 h2d.set(bytes=stack.nbytes)
@@ -1235,6 +1262,7 @@ class KVServer(Customer):
             real = all_ids != table.rows
             rid = all_ids[real]
             rpos = flat_pos[real]
+            sp.set(real=int(rid.size))
             if self.apply_cfg.dup_policy == "combine":
                 ref = self._push_group_combined(table, k, bm, rid, rpos, stack)
             else:
@@ -1286,7 +1314,8 @@ class KVServer(Customer):
             pos_np[:nt] = pos_t
             with self.tracer.span("ps.server.dispatch", op="push_batch"):
                 ref = table.push_batch(
-                    self._put(ids_np), self._put(pos_np), stack
+                    self._put(ids_np), self._put(pos_np), stack,
+                    self._counted(nt, bu),
                 )
         return ref  # last round's value: its readiness bounds every round
 
@@ -1316,7 +1345,8 @@ class KVServer(Customer):
         inverse[rpos] = inv_real.astype(np.int32)
         with self.tracer.span("ps.server.dispatch", op="push_combined"):
             return table.push_combined(
-                self._put(ids_np), self._put(inverse), stack
+                self._put(ids_np), self._put(inverse), stack,
+                self._counted(nu, bu),
             )
 
     # -- shard transfer (same-id restart: kv/replica.restart_same_id) --------

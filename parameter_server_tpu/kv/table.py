@@ -25,6 +25,16 @@ the kernels; nothing selects it.  Rows cross every method as ``[n, dim]``
 :meth:`install_rows`, :meth:`resize`, checkpoints, the server's hand-over) is
 ``[rows(+1), dim]`` NumPy whatever the dim: the conversion is a host
 ``reshape`` and lives in this class only.
+
+**A flat plane's apply.**  ``push``, ``push_batch`` and ``push_combined`` take
+``n``: how many of the bucket's ids are real, the rest being pads at the
+trash row.  A dim-1 table with the fused apply then walks the leg a chunk of
+ids a turn and stops after the turn that holds the last real id
+(``ops/scatter.py``, "A flat plane's apply"): every real row ends as the
+whole-bucket apply leaves it, bit for bit, and the trash row is reset as
+ever.  ``n`` is a traced operand, so there is one program a bucket.  Any
+other table does not hand it to its jitted functions: its programs are the
+ones it had, whatever the caller says.
 """
 
 from __future__ import annotations
@@ -151,17 +161,19 @@ class KVTable:
         return fn(*args, impl=self.scatter_impl, interpret=self._interpret)
 
     # -- jitted bodies ------------------------------------------------------
-    def _apply_core(self, value, state, ids, grads):
+    def _apply_core(self, value, state, ids, grads, n=None):
         """Apply ``grads`` at unique ``ids``: fused or three-pass, then the
-        trash-row reset (shared by every push entry point).  The
-        ``jax.named_scope``s put every device operation of the apply to its
-        line here (``PERF.md`` section 3)."""
+        trash-row reset (shared by every push entry point).  ``n`` is a flat
+        plane's count of real ids (module docstring); the three passes walk
+        the whole bucket.  The ``jax.named_scope``s put every device
+        operation of the apply to its line here (``PERF.md`` section 3)."""
         with jax.named_scope("ps.table.apply"):
             if self.fused_apply:
                 with jax.named_scope("ps.apply.fused"):
                     value, state = scatter.apply_rows(
                         value, state, ids, grads, self.optimizer.apply,
                         impl=self.scatter_impl, interpret=self._interpret,
+                        n=n,
                     )
             else:
                 with jax.named_scope("ps.apply.gather"):
@@ -193,10 +205,10 @@ class KVTable:
                 state = {k: state[k].at[-1].set(fills[k]) for k in state}
         return value, state
 
-    def _push_impl(self, value, state, ids, combined):
-        return self._apply_core(value, state, ids, combined)
+    def _push_impl(self, value, state, ids, combined, n=None):
+        return self._apply_core(value, state, ids, combined, n)
 
-    def _push_batch_impl(self, value, state, ids, positions, vals):
+    def _push_batch_impl(self, value, state, ids, positions, vals, n=None):
         # vals: (k, bm, dim) member stack; positions index its flattening,
         # with pads pointing at the appended zero row — the device-side
         # bucket pad (no host value copies, exact zeros: bitwise-neutral).
@@ -206,16 +218,16 @@ class KVTable:
                 [flat, jnp.zeros((1,) + flat.shape[1:], flat.dtype)]
             )
             grads = flat[positions]
-        return self._apply_core(value, state, ids, grads)
+        return self._apply_core(value, state, ids, grads, n)
 
-    def _push_combined_impl(self, value, state, ids, inverse, vals):
+    def _push_combined_impl(self, value, state, ids, inverse, vals, n=None):
         # segment_combine pre-merges duplicate rows across bundle members on
         # device; slots past the unique count only ever receive pad/trash
         # positions, whose values are exact zeros.
         with jax.named_scope("ps.table.stack"):
             flat = vals.reshape(-1, vals.shape[-1])
             combined = scatter.segment_combine(flat, inverse, ids.shape[0])
-        return self._apply_core(value, state, ids, combined)
+        return self._apply_core(value, state, ids, combined, n)
 
     def _pull_impl(self, value, state, ids):
         with jax.named_scope("ps.table.pull"):
@@ -228,44 +240,57 @@ class KVTable:
             return self.optimizer.pull_weights(v_rows, s_rows)
 
     # -- public ops ---------------------------------------------------------
-    def push(self, ids: jax.Array, combined_grads: jax.Array) -> jax.Array:
+    def _real(self, n):
+        """``n`` as a push's jitted function takes it: a flat plane's count
+        of real ids; nothing for any other table, whose programs then do not
+        depend on what the caller says."""
+        return n if self.dim == 1 else None
+
+    def push(
+        self, ids: jax.Array, combined_grads: jax.Array, n=None
+    ) -> jax.Array:
         """Apply pre-combined gradient rows at unique ``ids`` (in place).
 
         ``ids`` must be unique (host guarantees via ``localize_to_slots``);
         padded ids point at the trash row and must carry zero gradients.
+        ``n`` (an ``int32`` scalar) says that ``ids[n:]`` all point at the
+        trash row; a dim-1 table then does not visit them (module
+        docstring).
         Returns the new ``value`` array so the caller can hand it to the
         ApplyLedger as the readiness ref for this dispatch (the NEXT push
         donates it away, so polling through ``self.value`` would observe a
         later apply, not this one).
         """
         self.value, self.state = self._push_fn(
-            self.value, self.state, ids, combined_grads
+            self.value, self.state, ids, combined_grads, self._real(n)
         )
         return self.value
 
     def push_batch(
-        self, ids: jax.Array, positions: jax.Array, vals: jax.Array
+        self, ids: jax.Array, positions: jax.Array, vals: jax.Array, n=None
     ) -> jax.Array:
         """One bundled apply round: unique ``ids`` gather their gradient rows
         out of the stacked member values by ``positions`` (pad positions index
         the appended zero row).  Donated in-place update, one jit call.
+        ``n`` as in :meth:`push`.
         Returns the new ``value`` (ledger readiness ref, as in :meth:`push`).
         """
         self.value, self.state = self._push_batch_fn(
-            self.value, self.state, ids, positions, vals
+            self.value, self.state, ids, positions, vals, self._real(n)
         )
         return self.value
 
     def push_combined(
-        self, ids: jax.Array, inverse: jax.Array, vals: jax.Array
+        self, ids: jax.Array, inverse: jax.Array, vals: jax.Array, n=None
     ) -> jax.Array:
         """Bundled apply with device pre-combine: every stacked value row is
         segment-summed into its unique-id slot (``inverse``), then applied in
         one donated jit call — the ``dup_policy="combine"`` engine mode.
+        ``n`` as in :meth:`push`.
         Returns the new ``value`` (ledger readiness ref, as in :meth:`push`).
         """
         self.value, self.state = self._push_combined_fn(
-            self.value, self.state, ids, inverse, vals
+            self.value, self.state, ids, inverse, vals, self._real(n)
         )
         return self.value
 

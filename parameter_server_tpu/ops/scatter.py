@@ -19,6 +19,22 @@ as ``[n, dim]`` (``[n, 1]`` for a flat plane), so the only reshapes are of
 ``n``-row operands, never of a plane.  The Pallas kernels need ``dim == 128``
 or ``dim % 1024 == 0`` and refuse a flat plane like any other dim-1 table.
 
+**A flat plane's apply** (:func:`_apply_rows_xla`; ``PERF.md`` section 6,
+PR 36).  On the TPU the write-back into a flat plane is a serial loop over
+the ids, pads included: 100 ns an id a plane in 2 GiB, some ten times the
+gather's cost.  A caller that says how many ids are real (``n``, a traced
+scalar: ids ``[n:]`` point at the trash row, which the table resets) has the
+bucket walked ``_FLAT_CHUNK`` ids a turn, both gathers, the row function and
+both write-backs of a chunk in one turn of one ``while`` with the planes
+carried in place, for ``ceil(n / chunk)`` turns: at most ``chunk - 1`` pads
+are visited, and every real row's arithmetic is the whole bucket's, bit for
+bit.  It is read from what the function sees (the plane's rank, the
+argument): a rank-2 plane's program is the same with and without it.
+**Nothing tells the compiler that the ids are in order**, though a server's
+are: on a v5e ``indices_are_sorted=True`` selects a scatter that stages the
+plane through fast memory window by window, 7 ms a 2 GiB plane whatever the
+ids, against the loop's 2-3 ms for a leg of 20-33 k (section 6, PR 36).
+
 Two implementations:
 
 - **XLA** (default): ``jnp.take`` / ``.at[].add``.  Differentiable, handles
@@ -59,6 +75,11 @@ _SIDE_EFFECTS = pltpu.CompilerParams(has_side_effects=True)
 #: a block asked for 512 and Mosaic refused ("Used 2.1K of 2.0K sflag").
 #: Half the memory leaves room for the rest of the jitted step.
 _MAX_DMA_SEMAPHORES = 256
+
+#: ids a turn of a flat plane's chunked apply handles (:func:`_apply_rows_xla`).
+#: On a v5e a turn costs 2 us beside 0.2 us an id visited (``PERF.md`` section
+#: 6, PR 36): at 1,024 a leg's mean 512 pads and its 20-33 turns weigh the same
+_FLAT_CHUNK = 1024
 
 #: row-wise update rule: (value_rows, state_rows, grad_rows) ->
 #: (new_value_rows, new_state_rows).  ServerOptimizer.apply satisfies this
@@ -395,6 +416,7 @@ def _apply_rows_xla(
     ids: jax.Array,
     grads: jax.Array,
     row_fn: RowFn,
+    n: jax.Array | None = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Gather → row_fn → scatter-update, expressed as one XLA graph.
 
@@ -402,7 +424,24 @@ def _apply_rows_xla(
     ``KVTable._push_impl`` (same gathers, same elementwise update, same
     ``.at[].set`` write-backs), so switching a table between fused and
     three-pass mode is bitwise-neutral on the XLA backends.
+
+    A flat plane given the count ``n`` of its real ids (``int32``, traced:
+    one program a bucket) is applied a chunk of ids a turn, as many turns
+    as hold ``n`` ids (module docstring).
     """
+    chunk = min(_FLAT_CHUNK, ids.shape[0])
+    if n is not None and value.ndim == 1 and ids.shape[0] % chunk == 0:
+        def turn(i, planes):
+            at = i * chunk
+            return _apply_rows_xla(
+                *planes,
+                jax.lax.dynamic_slice_in_dim(ids, at, chunk),
+                jax.lax.dynamic_slice_in_dim(grads, at, chunk),
+                row_fn,
+            )
+
+        turns = jax.lax.div(n + (chunk - 1), jnp.int32(chunk))
+        return jax.lax.fori_loop(0, turns, turn, (value, state))
     v_rows = gather_rows_xla(value, ids)
     s_rows = {k: gather_rows_xla(v, ids) for k, v in state.items()}
     new_v, new_s = row_fn(v_rows, s_rows, grads)
@@ -557,9 +596,13 @@ def _pallas_apply(
 # ---------------------------------------------------------------------------
 
 
-# "auto" resolves to XLA.  Neither path has a roofline figure measured on
-# the chip yet (ROADMAP S8); until one exists the Pallas kernels stay
-# selectable by flag and are kept compiling by ``chip_smoke.py``.
+# "auto" resolves to XLA.  The one reading taken on the chip (a v5e, PR 36:
+# a 2 GiB ``[2^22 + 1, 128]`` plane, 32,768 sorted rows, each call alone) has
+# the Pallas fused apply at 2.39 ms against XLA's 5.24 and the scatter-set at
+# 0.49 against 2.40, the gather at 0.64 against 0.34; no cell shows it (the
+# chips of ``dlrm_emb.skew.x4`` idle 92 %), so selecting by shape is ROADMAP
+# D5's.  Until then the Pallas kernels stay selectable by flag and are kept
+# compiling by ``chip_smoke.py``.
 
 
 def gather_rows(
@@ -628,6 +671,7 @@ def apply_rows(
     impl: Impl = "auto",
     interpret: bool = False,
     block_rows: int | None = None,
+    n: jax.Array | None = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Fused push apply: gather → ``row_fn`` → scatter-update in one pass.
 
@@ -637,10 +681,12 @@ def apply_rows(
     pre-combined; pads all point at the shared trash row, which the caller
     re-zeros).  The pallas path DMAs value + state rows through VMEM once,
     runs ``row_fn`` on the resident block, and writes straight back —
-    double-buffered, tables never materialize in VMEM.
+    double-buffered, tables never materialize in VMEM.  ``n``: how many of
+    ``ids`` are real, which the XLA path of a flat plane reads (module
+    docstring).
     """
     if impl != "pallas":
-        return _apply_rows_xla(value, state, ids, grads, row_fn)
+        return _apply_rows_xla(value, state, ids, grads, row_fn, n)
     return _pallas_apply(
         value, state, ids, grads, row_fn,
         interpret=interpret, block_rows=block_rows,

@@ -264,13 +264,13 @@ def test_push_ack_does_not_wait_for_device_apply(batched):
         entangle = _entangle_fn()
         orig_push, orig_batch = tbl.push, tbl.push_batch
 
-        def slow_push(ids, vals):
-            orig_push(ids, vals)
+        def slow_push(ids, vals, n=None):
+            orig_push(ids, vals, n)
             tbl.value = entangle(tbl.value)
             return tbl.value  # the ledger's readiness ref, as KVTable.push
 
-        def slow_push_batch(ids, positions, vals):
-            orig_batch(ids, positions, vals)
+        def slow_push_batch(ids, positions, vals, n=None):
+            orig_batch(ids, positions, vals, n)
             tbl.value = entangle(tbl.value)
             return tbl.value
 

@@ -439,3 +439,144 @@ def test_dim1_host_forms_round_trip():
     ids = jnp.asarray([0, 60, 61, 61], dtype=jnp.int32)
     t.push(ids, jnp.ones((4, 1), jnp.float32))
     assert t.pull(ids).shape == (4, 1) and t.value.shape == (62,)
+
+
+# ---------------------------------------------------------------------------
+# A flat plane's apply (PR 36): a push that says how many of its ids are real
+# ends with the rows of one that does not, the trash row reset; a server
+# counts a leg's ids up to the last that is a row of its shard.
+# ---------------------------------------------------------------------------
+
+_F_ROWS, _F_BUCKET = 20_000, 4096
+_F_REAL = _F_BUCKET // 2 + 1  # pads present, a chunk's edge + 1
+
+
+@pytest.mark.parametrize("op", ["push", "push_batch", "push_combined"])
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "threepass"])
+@pytest.mark.parametrize("kind", _KINDS)
+def test_dim1_push_of_counted_ids_matches_the_plain_push(kind, fused, op):
+    def table():
+        return KVTable(
+            TableConfig(
+                name="w", rows=_F_ROWS, dim=1, fused_apply=fused,
+                init_scale=0.1,
+                optimizer=OptimizerConfig(kind=kind, learning_rate=0.1),
+            ),
+            seed=5,
+        )
+
+    plain, told = table(), table()
+    rng = np.random.default_rng(17)
+    n = np.int32(_F_REAL)
+    for _ in range(2):
+        ids = np.full(_F_BUCKET, _F_ROWS, np.int32)
+        ids[:_F_REAL] = np.sort(rng.choice(_F_ROWS, _F_REAL, replace=False))
+        ids = jnp.asarray(ids)
+        if op == "push":
+            # the pads carry REAL gradients, as a worker's PAD_KEY
+            # positions may: the trash reset leaves nothing of them
+            args = (jnp.asarray(
+                rng.normal(size=(_F_BUCKET, 1)).astype(np.float32)
+            ),)
+        else:
+            vals = rng.normal(size=(2, _F_BUCKET // 2, 1)).astype(np.float32)
+            if op == "push_batch":
+                index = np.full(_F_BUCKET, _F_BUCKET, np.int32)  # zero row
+                index[:_F_REAL] = rng.permutation(_F_BUCKET)[:_F_REAL]
+            else:  # every position sums into a real slot
+                index = rng.integers(0, _F_REAL, _F_BUCKET).astype(np.int32)
+            args = (jnp.asarray(index), jnp.asarray(vals))
+        getattr(plain, op)(ids, *args)
+        getattr(told, op)(ids, *args, n)
+    want_v, want_s = plain.host_planes()
+    got_v, got_s = told.host_planes()
+    np.testing.assert_array_equal(got_v, want_v)
+    assert got_v[-1, 0] == 0.0 and np.abs(got_v[:-1]).max() > 0.0
+    for k, fill in told.optimizer.state_shapes().items():
+        np.testing.assert_array_equal(got_s[k], want_s[k])
+        assert got_s[k][-1, 0] == fill  # trash row reset
+    text = getattr(told, f"_{op}_fn").lower(
+        told.value, told.state, ids, *args, n
+    ).as_text()
+    assert ("while" in text) == fused  # three passes walk the whole bucket
+
+
+def test_rank2_table_compiles_one_program_whatever_a_push_says():
+    t = KVTable(
+        TableConfig(
+            name="e", rows=64, dim=128,
+            optimizer=OptimizerConfig(kind="adagrad", learning_rate=0.1),
+        )
+    )
+    ids = jnp.asarray(np.r_[np.arange(5), [64] * 3].astype(np.int32))
+    grads = jnp.ones((8, 128), jnp.float32)
+    t.push(ids, grads)
+    t.push(ids, grads, np.int32(5))
+    assert t._push_fn._cache_size() == 1
+    assert "while" not in t._push_fn.lower(
+        t.value, t.state, ids, grads
+    ).as_text()
+
+
+@pytest.mark.parametrize("dup_policy", [None, "rounds", "combine"])
+def test_server_counts_a_leg_up_to_its_last_row_of_the_shard(dup_policy):
+    """A worker's leg ends in its own bucket pads (keys past the table, at
+    the trash row, here with gradients as PAD_KEY positions may carry) and
+    then the server's: neither is visited.  A client whose keys descend,
+    pads first (nothing in the wire contract forbids it), is walked up to
+    its last real id.  Both end with the rows a plain table ends with."""
+    from parameter_server_tpu.config import ApplyEngineConfig
+    from parameter_server_tpu.core.messages import Message, Task, TaskKind
+
+    rows = 6000
+    cfg = TableConfig(
+        name="w", rows=rows, dim=1, init_scale=0.1,
+        optimizer=OptimizerConfig(kind="adagrad", learning_rate=0.1),
+    )
+    apply = (
+        None if dup_policy is None
+        else ApplyEngineConfig(dup_policy=dup_policy)
+    )
+
+    def push(ids, grads):
+        return Message(
+            task=Task(TaskKind.PUSH, "kv", payload={"table": "w"}),
+            sender="W0", recver="S0", keys=ids.astype(np.int32),
+            values=[grads.reshape(-1, 1)],
+        )
+
+    rng = np.random.default_rng(23)
+    perm = rng.permutation(rows)  # disjoint: any dup_policy is sequential
+    # 1500 rows in order and 700 of the worker's pads: a bucket of 4096
+    up = np.r_[np.sort(perm[:1500]), [rows] * 700]
+    down = np.r_[[rows] * 3, np.sort(perm[1500:1521])[::-1]]
+    g_up = rng.normal(size=up.size).astype(np.float32)
+    g_down = rng.normal(size=down.size).astype(np.float32)
+    van = LoopbackVan()
+    try:
+        server = KVServer(Postoffice("S0", van), {"w": cfg}, 0, 1, apply=apply)
+        plain = KVTable(cfg)
+        plain.resize(*server.tables["w"].host_planes())
+        msgs = [push(up, g_up), push(down, g_down)]
+        if dup_policy is None:
+            replies = [server.handle_request(m) for m in msgs]
+            want = {"apply_ids_real": 1500 + 24, "apply_ids_bucket": 4096 + 32}
+        else:  # a bundle: the trash ids are dropped, the rest sorted
+            replies = server.handle_request_batch(msgs)
+            want = {"apply_ids_real": 1521, "apply_ids_bucket": 2048}
+        assert all("__error__" not in r.task.payload for r in replies)
+        got = server.counters()
+        assert {k: got[k] for k in want} == want
+        for ids, g in ((up, g_up), (down, g_down)):
+            pad = -len(ids) % 8
+            plain.push(
+                jnp.asarray(np.r_[ids, [rows] * pad].astype(np.int32)),
+                jnp.asarray(np.r_[g, [0.0] * pad].astype(np.float32)[:, None]),
+            )
+        want_v, want_s = plain.host_planes()
+        got_v, got_s = server.tables["w"].host_planes()
+        np.testing.assert_array_equal(got_v, want_v)
+        np.testing.assert_array_equal(got_s["sum_sq"], want_s["sum_sq"])
+        assert got_v[-1, 0] == 0.0 and got_s["sum_sq"][-1, 0] == 0.0
+    finally:
+        van.close()

@@ -191,13 +191,13 @@ def _slow_table(tbl):
     entangle = _entangle_fn()
     orig_push, orig_batch = tbl.push, tbl.push_batch
 
-    def slow_push(ids, vals):
-        orig_push(ids, vals)
+    def slow_push(ids, vals, n=None):
+        orig_push(ids, vals, n)
         tbl.value = entangle(tbl.value)
         return tbl.value
 
-    def slow_push_batch(ids, positions, vals):
-        orig_batch(ids, positions, vals)
+    def slow_push_batch(ids, positions, vals, n=None):
+        orig_batch(ids, positions, vals, n)
         tbl.value = entangle(tbl.value)
         return tbl.value
 
@@ -340,8 +340,8 @@ def test_backlog_breach_fires_live_slo_busy_hints_and_pstop(
         tbl = srv.tables["w"]
         orig_push, gates = tbl.push, []
 
-        def gated_push(ids, vals):
-            orig_push(ids, vals)
+        def gated_push(ids, vals, n=None):
+            orig_push(ids, vals, n)
             gates.append(_Ref(ready=False))
             return gates[-1]
 

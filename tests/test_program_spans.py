@@ -127,6 +127,8 @@ def test_spans_of_a_loopback_cluster_land_in_the_profilers_trace(tmp_path):
             assert root[1] == f"ps.worker.{kind}"
             assert sp[0] != root[0]  # on a server's recv thread
             assert sp[4]["rows"] <= sp[4]["bucket"]
+            if kind == "push":  # ids the apply visits: not the worker's pads
+                assert 0 < sp[4]["real"] <= sp[4]["rows"]
             inner = [e for e in events if e is not sp and _inside(e, sp)]
             assert {"ps.server.h2d", "ps.server.dispatch"} <= {
                 e[1] for e in inner

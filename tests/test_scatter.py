@@ -1,3 +1,5 @@
+import functools
+
 import numpy as np
 import pytest
 
@@ -358,3 +360,110 @@ def test_pallas_refuses_flat_plane_by_its_dim(op):
             getattr(scatter, op + "_rows")(
                 flat, ids, rows, impl="pallas", interpret=True
             )
+
+
+# ---------------------------------------------------------------------------
+# A flat plane's apply (PR 36): given the count ``n`` of its real ids it walks
+# the bucket a chunk a turn and stops after the turn that holds the last real
+# id.  Every real row is the whole-bucket apply's, bit for bit; a rank-2
+# plane's program is the same with and without the count.
+# ---------------------------------------------------------------------------
+
+_CHUNK = scatter._FLAT_CHUNK
+_BUCKET = 8 * _CHUNK
+_BIG_ROWS = 3 * _BUCKET
+
+
+@functools.lru_cache(maxsize=None)
+def _flat_applies(kind):
+    """``(optimizer, whole, counted)``: the whole-bucket apply and the apply
+    given ``n``, jitted.  ``n`` is traced: one program serves every count."""
+    from parameter_server_tpu.config import OptimizerConfig
+    from parameter_server_tpu.kv.optim import make_optimizer
+
+    opt = make_optimizer(OptimizerConfig(kind=kind, learning_rate=0.1))
+
+    def whole(value, state, ids, grads):
+        return scatter.apply_rows(value, state, ids, grads, opt.apply)
+
+    def counted(value, state, ids, grads, n):
+        return scatter.apply_rows(value, state, ids, grads, opt.apply, n=n)
+
+    return opt, jax.jit(whole), jax.jit(counted)
+
+
+def _leg(n, seed):
+    """A leg as a server hands it over: ``n`` unique ids in order, the
+    bucket's tail padded with the trash row and zero gradients."""
+    rng = np.random.default_rng(seed)
+    ids = np.full(_BUCKET, _BIG_ROWS, np.int32)
+    ids[:n] = np.sort(rng.choice(_BIG_ROWS, size=n, replace=False))
+    grads = np.zeros((_BUCKET, 1), np.float32)
+    grads[:n] = rng.normal(size=(n, 1))
+    return jnp.asarray(ids), jnp.asarray(grads)
+
+
+@pytest.mark.parametrize("kind", ["sgd", "adagrad", "adam", "ftrl"])
+@pytest.mark.parametrize(
+    "n",
+    [0, 1, _CHUNK - 1, _CHUNK, _CHUNK + 1, _BUCKET // 2 + 1, _BUCKET],
+    ids=["none", "one", "edge-1", "edge", "edge+1", "half+1", "bucket"],
+)
+def test_flat_apply_of_counted_ids_matches_whole_bucket_bitwise(kind, n):
+    opt, whole, counted = _flat_applies(kind)
+    rng = np.random.default_rng(2)
+    plane = rng.normal(size=_BIG_ROWS + 1).astype(np.float32)
+    plane[-1] = 0.0
+    fills = opt.state_shapes()
+    wv, ws = jnp.asarray(plane), {
+        k: jnp.full((_BIG_ROWS + 1,), f, jnp.float32) for k, f in fills.items()
+    }
+    cv, cs = wv, dict(ws)
+    for turn in range(2):  # the state planes move too
+        ids, grads = _leg(n, seed=10 * n + turn)
+        wv, ws = whole(wv, ws, ids, grads)
+        cv, cs = counted(cv, cs, ids, grads, np.int32(n))
+        # the trash row is the table's to reset: a pad visited or not
+        # leaves it as it likes
+        np.testing.assert_array_equal(np.asarray(cv)[:-1], np.asarray(wv)[:-1])
+        for k in fills:
+            np.testing.assert_array_equal(
+                np.asarray(cs[k])[:-1], np.asarray(ws[k])[:-1]
+            )
+    assert np.array_equal(np.asarray(cv)[:-1], plane[:-1]) == (n == 0)
+    assert counted._cache_size() == 1  # one program a bucket whatever n
+
+
+def _lowered(value, b, with_n):
+    """Lowered text of ``apply_rows`` on a bucket of ``b`` ids of a plane
+    like ``value``, told the count or not."""
+    def f(value, state, ids, rows, n):
+        return scatter.apply_rows(
+            value, state, ids, rows, lambda v, s, g: (v - g, s),
+            n=n if with_n else None,
+        )
+
+    dim = 1 if value.ndim == 1 else value.shape[1]
+    return jax.jit(f).lower(
+        value, {"s": value}, jnp.zeros(b, jnp.int32), jnp.zeros((b, dim)),
+        np.int32(3),
+    ).as_text()
+
+
+def test_flat_apply_walks_the_whole_bucket_without_a_count():
+    """No ``n``: the parent's program, no loop; a bucket no chunk divides
+    (a caller of the module with its own sizes) likewise; nothing tells the
+    compiler that the ids are in order (module docstring)."""
+    flat = jnp.zeros(41)
+    assert "while" not in _lowered(flat, 16, False)
+    assert "while" in _lowered(flat, 16, True)
+    assert "while" not in _lowered(flat, 3 * _CHUNK // 2, True)
+    assert "indices_are_sorted = true" not in _lowered(flat, 16, True)
+
+
+def test_rank2_plane_lowers_to_the_same_text_with_and_without_the_count():
+    """``dlrm_emb``'s and the ``pretrain8k`` cells' planes are rank 2: the
+    count selects nothing there."""
+    wide = jnp.zeros((41, 128))
+    want = _lowered(wide, 16, False)
+    assert _lowered(wide, 16, True) == want and "while" not in want

@@ -67,16 +67,30 @@ def _compile(fn, one_chip, *shapes):
 
 
 def _plane_ops(compiled, elements):
-    """``{opcode: count}`` of the ENTRY computation's operations whose result
-    is a float32 array of ``elements`` elements, parameters and bitcasts
-    aside (they move nothing)."""
+    """``{opcode: count}`` of the operations of the ENTRY computation and of
+    every ``while``'s body and condition whose result is a float32 array of
+    ``elements`` elements; parameters, bitcasts and a tuple's elements aside
+    (they move nothing)."""
     text = compiled.as_text()
-    entry = text[text.index("ENTRY") :]
-    entry = entry[: entry.index("\n}")]
+    blocks = {
+        m.group(2): m.group(3)
+        for m in re.finditer(
+            r"^(ENTRY )?%(\S+) \(.*?\{\n(.*?)^\}", text, re.M | re.S
+        )
+    }
+    walked = [body for name, body in blocks.items() if name.startswith("main")]
+    walked += [
+        blocks[name]
+        for name in re.findall(r"(?:body|condition)=%([\w.\-]+)", text)
+    ]
     ops = {}
-    for m in re.finditer(r"= f32\[([\d,]+)\]\S* ([\w\-]+)\(", entry):
+    for m in re.finditer(
+        r"= f32\[([\d,]+)\]\S* ([\w\-]+)\(", "\n".join(walked)
+    ):
         size = int(np.prod([int(d) for d in m.group(1).split(",")]))
-        if size == elements and m.group(2) not in ("parameter", "bitcast"):
+        if size == elements and m.group(2) not in (
+            "parameter", "bitcast", "get-tuple-element",
+        ):
             ops[m.group(2)] = ops.get(m.group(2), 0) + 1
     return ops
 
@@ -121,6 +135,50 @@ def test_dim1_apply_compiles_to_scatters_and_the_trash_reset(one_chip, fused):
     # broadcast, no copy of a plane
     assert _plane_ops(c, ROWS + 1) == {"fusion": 2 * planes}
     assert c.memory_analysis().temp_size_in_bytes < 4 * ROWS // 8
+
+
+@pytest.mark.parametrize("n_ids", [N, N // 2])
+def test_dim1_apply_of_counted_ids_loops_over_chunks_in_place(one_chip, n_ids):
+    """What a server's push runs since PR 36: the count ``n`` an operand
+    (one program a bucket whatever it holds), one ``while`` whose body holds
+    a chunk's gathers, update and both write-backs; the planes aliased, and
+    still nothing over a plane but the scatter fusions and the trash reset.
+    No scatter is told that its ids are in order: that selects a pass over
+    the plane (``ops/scatter.py``)."""
+    t = _table()
+    planes = 1 + len(t.state)
+    c = _compile(
+        t._push_fn, one_chip, _sds((ROWS + 1,)),
+        {k: _sds((ROWS + 1,)) for k in t.state}, _sds((n_ids,), jnp.int32),
+        _sds((n_ids, 1)), _sds((), jnp.int32),
+    )
+    text = c.as_text()
+    assert _plane_ops(c, ROWS + 1) == {"fusion": 2 * planes}
+    assert len(re.findall(r" while\(", text)) == 1
+    assert "indices_are_sorted=true" not in text
+    assert re.search(r"s32\[\]\S* parameter\(4\)", text)  # n, traced
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.alias_size_in_bytes >= planes * 4 * ROWS  # donated, in place
+
+
+def test_rank2_apply_compiles_to_the_parent_s_program(one_chip):
+    """``dlrm_emb.skew.x4``'s shard and leg: told the count or not, a rank-2
+    plane's apply is one optimised program, operation for operation."""
+    rows, dim, leg = 11735464, 128, 16384
+    t = KVTable(
+        TableConfig(
+            name="e", rows=8, dim=dim,
+            optimizer=OptimizerConfig(kind="adagrad", learning_rate=0.1),
+        )
+    )
+    shapes = (
+        _sds((rows + 1, dim)), {k: _sds((rows + 1, dim)) for k in t.state},
+        _sds((leg,), jnp.int32), _sds((leg, dim)),
+    )
+    plain = _program(_compile(t._push_fn, one_chip, *shapes))
+    told = _program(_compile(t._push_fn, one_chip, *shapes, _sds((), jnp.int32)))
+    assert told == plain and " while(" not in plain
 
 
 def test_column_planes_compile_to_passes_over_the_plane(one_chip):
