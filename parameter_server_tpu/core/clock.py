@@ -11,14 +11,21 @@ Semantics (matching SSP literature and the reference's bounded delay):
 a worker may *start* iteration ``t`` only when every worker has *completed*
 iteration ``t - 1 - bound`` — i.e. the fastest worker leads the slowest by at
 most ``bound`` iterations.  ``bound=0`` is BSP lockstep; ``bound=None`` is ASP.
+
+Under a bound every :meth:`ConsistencyController.wait_turn` is one
+``ps.worker.turn`` span (``utils/trace.py``: the module-level ``span``, so
+this file stays free of jax), and the turns that had to wait are counted
+(:meth:`ConsistencyController.counters`).
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 from parameter_server_tpu.config import ConsistencyConfig
+from parameter_server_tpu.utils.trace import span
 
 
 class VectorClock:
@@ -66,6 +73,10 @@ class ConsistencyController:
         self.clock = VectorClock(num_workers)
         self._dead: set[int] = set()
         self._dead_lock = threading.Lock()
+        #: turns that found the bound unmet on entry, and the seconds they
+        #: then waited (written under the clock's condition)
+        self.turn_waits = 0
+        self.turn_wait_s = 0.0
 
     def wait_turn(self, worker: int, t: int, timeout: Optional[float] = None) -> bool:
         """Block until worker ``worker`` may start iteration ``t``.
@@ -77,19 +88,33 @@ class ConsistencyController:
         if bound is None:  # ASP
             return True
         need = t - bound  # all workers must have completed >= t - bound
-        if need <= 0:
-            return True
-        return self._wait_min_alive(need, timeout)
-
-    def _wait_min_alive(self, t: int, timeout: Optional[float]) -> bool:
         # Dead workers are excluded from the bound (elasticity: a lost worker
         # must not stall SSP forever; its shard is reassigned by the
         # WorkloadPool — reference Executor::ReplaceNode behavior [U]).
         cond = self.clock._cond
-        with cond:
-            return cond.wait_for(
-                lambda: min(self._alive_clocks()) >= t, timeout
+        with span("ps.worker.turn", worker=worker, t=t) as sp, cond:
+            slowest = min(self._alive_clocks())
+            blocked = slowest < need
+            if sp.recording:
+                sp.set(lead=t - slowest, blocked=int(blocked))
+            if not blocked:
+                return True
+            t0 = time.perf_counter()
+            ok = cond.wait_for(
+                lambda: min(self._alive_clocks()) >= need, timeout
             )
+            self.turn_waits += 1
+            self.turn_wait_s += time.perf_counter() - t0
+            return ok
+
+    def counters(self) -> dict:
+        """What an operator reads of the bounded-delay wait: how many turns
+        waited for a slower worker, and for how long in all."""
+        with self.clock._cond:
+            return {
+                "turn_waits": self.turn_waits,
+                "turn_wait_s": self.turn_wait_s,
+            }
 
     def _alive_clocks(self) -> list[int]:
         clocks = self.clock._clocks

@@ -725,7 +725,14 @@ class KVServer(Customer):
                 f"routing epoch mismatch: request {repoch} != "
                 f"server {self.routing.epoch}",
             )
-        loc = self._localize_request(tname, msg.keys)
+        with self.tracer.span("ps.server.localize") as lsp:
+            loc = self._localize_request(tname, msg.keys)
+            if lsp.recording and loc is not None:
+                lsp.set(
+                    keys=int(loc[1].size),
+                    real=int(np.count_nonzero(loc[0] != self.tables[tname].rows)),
+                    segs=int(loc[2].size),
+                )
         if loc is None:
             return self._fence_reply(
                 msg,
@@ -883,9 +890,10 @@ class KVServer(Customer):
             h2d.set(bytes=ids.nbytes + vals.nbytes)
         with self.tracer.span("ps.server.dispatch", op="push"):
             ref = table.push(ids, vals, self._counted(real, b))
-        if tok is not None:
-            self.ledger.submit(tok, ref, lambda t=table: t.value)
-        return self._ack_push(msg, tname, kn, segs)
+        with self.tracer.span("ps.server.ack", kind="push"):
+            if tok is not None:
+                self.ledger.submit(tok, ref, lambda t=table: t.value)
+            return self._ack_push(msg, tname, kn, segs)
 
     def _ack_push(
         self, msg: Message, tname: str, kn: np.ndarray, segs: np.ndarray
@@ -993,6 +1001,12 @@ class KVServer(Customer):
         with self.tracer.span("ps.server.d2h", bytes=0):
             return rows
 
+    def _ack_pull(self, msg: Message, vals, sver: int) -> Message:
+        """A pull's reply, built once its rows are there (``ps.server.d2h``
+        has closed): the acknowledgement stage of a pull."""
+        with self.tracer.span("ps.server.ack", kind="pull"):
+            return self._stamp_version(msg, msg.reply(values=[vals]), sver)
+
     def _pull_device(
         self, tname: str, ids_np: np.ndarray, segs: np.ndarray, sp
     ) -> Tuple[jax.Array, int, int]:
@@ -1044,19 +1058,15 @@ class KVServer(Customer):
                 t0 = time.perf_counter()
                 rows, n, sver = self._pull_ro_device(tname, ids_np, segs, sp)
                 if self.device_replies:
-                    vals = [self._device_reply(rows)]
+                    vals = self._device_reply(rows)
                 else:
-                    vals = [self._to_host(rows)[:n]]
+                    vals = self._to_host(rows)[:n]
                 self.ro_hist[tname].record(time.perf_counter() - t0)
-                return self._stamp_version(msg, msg.reply(values=vals), sver)
+                return self._ack_pull(msg, vals, sver)
             rows, n, sver = self._pull_device(tname, ids_np, segs, sp)
             if self.device_replies:
-                return self._stamp_version(
-                    msg, msg.reply(values=[self._device_reply(rows)]), sver
-                )
-            return self._stamp_version(
-                msg, msg.reply(values=[self._to_host(rows)[:n]]), sver
-            )
+                return self._ack_pull(msg, self._device_reply(rows), sver)
+            return self._ack_pull(msg, self._to_host(rows)[:n], sver)
 
     # -- bundle-batched apply engine (ISSUE 11) -------------------------------
     def _error_reply(self, msg: Message, exc: Exception) -> Message:
@@ -1190,13 +1200,11 @@ class KVServer(Customer):
             return
         if self.device_replies:
             for i, m, rows, n, sver in pulls:
-                replies[i] = self._stamp_version(
-                    m, m.reply(values=[self._device_reply(rows)]), sver
-                )
+                replies[i] = self._ack_pull(m, self._device_reply(rows), sver)
             return
         host = self._to_host([rows for _, _, rows, _, _ in pulls])
         for (i, m, _, n, sver), h in zip(pulls, host):
-            replies[i] = self._stamp_version(m, m.reply(values=[h[:n]]), sver)
+            replies[i] = self._ack_pull(m, h[:n], sver)
 
     def _finish_ro_pulls(self, ro: List[tuple], replies: List) -> None:
         """Materialize deferred READ-ONLY pull replies: the bundle's other
@@ -1206,15 +1214,13 @@ class KVServer(Customer):
             return
         if self.device_replies:
             for i, m, tname, rows, n, sver, t0 in ro:
-                replies[i] = self._stamp_version(
-                    m, m.reply(values=[self._device_reply(rows)]), sver
-                )
+                replies[i] = self._ack_pull(m, self._device_reply(rows), sver)
                 self.ro_hist[tname].record(time.perf_counter() - t0)
             return
         host = self._to_host([rows for _, _, _, rows, _, _, _ in ro])
         done = time.perf_counter()
         for (i, m, tname, _, n, sver, t0), h in zip(ro, host):
-            replies[i] = self._stamp_version(m, m.reply(values=[h[:n]]), sver)
+            replies[i] = self._ack_pull(m, h[:n], sver)
             self.ro_hist[tname].record(done - t0)
 
     def _apply_push_group(self, group: List[tuple], replies: List) -> None:
@@ -1267,10 +1273,12 @@ class KVServer(Customer):
                 ref = self._push_group_combined(table, k, bm, rid, rpos, stack)
             else:
                 ref = self._push_group_rounds(table, k, bm, rid, rpos, stack)
-            if tok is not None:
-                self.ledger.submit(tok, ref, lambda t=table: t.value)
-            for i, m, tname_, _, kn, segs in group:
-                replies[i] = self._ack_push(m, tname_, kn, segs)
+            # one acknowledgement stage for the group, as one span holds it
+            with self.tracer.span("ps.server.ack", kind="push"):
+                if tok is not None:
+                    self.ledger.submit(tok, ref, lambda t=table: t.value)
+                for i, m, tname_, _, kn, segs in group:
+                    replies[i] = self._ack_push(m, tname_, kn, segs)
 
     def _push_group_rounds(
         self,

@@ -22,6 +22,13 @@ two sinks:
 With no session and a disabled tracer a span is one check and a shared
 no-op context manager.  Span names are closed over :data:`SPANS`.
 
+Code that holds a tracer opens its spans on it (``kv/worker.py``,
+``kv/server.py``: the request's span and, under it, ``ps.server.localize``,
+``.h2d``, ``.dispatch``, ``.d2h`` and ``.ack``; ``learner/hybrid.py``).  Code
+with no tracer handle, which also stays free of jax, calls the module-level
+:func:`span`: ``core/netmon.py`` (``ps.van.*``) and ``core/clock.py``
+(``ps.worker.turn``, the bounded-delay wait that opens a worker's step).
+
 Spans of one request share ``req="<sender>/<customer>/<task.time>"`` across
 threads (:func:`req_id`): fields every message already has, so no payload
 key rides the wire for it.
@@ -44,6 +51,9 @@ from typing import Dict, List, Optional, Tuple
 #: frozenset of plain string constants.  ``PERF.md`` section 3 says which
 #: metric reads which.
 SPANS = frozenset({
+    # consistency (core/clock.py, through the module-level ``span``): a
+    # worker's turn under a bounded delay, before the step's first request
+    "ps.worker.turn",
     # worker (kv/worker.py): the roots of a request, then what they nest
     "ps.worker.pull",
     "ps.worker.push",
@@ -57,12 +67,14 @@ SPANS = frozenset({
     # van (core/netmon.py)
     "ps.van.send",
     "ps.van.deliver",
-    # server (kv/server.py)
+    # server (kv/server.py): a request's span, then its stages in order
     "ps.server.pull",
     "ps.server.push",
+    "ps.server.localize",
     "ps.server.h2d",
     "ps.server.dispatch",
     "ps.server.d2h",
+    "ps.server.ack",
     # hybrid learner (learner/hybrid.py): the root of a step, then its parts
     "ps.hybrid.step",
     "ps.hybrid.pull_wait",
@@ -406,7 +418,7 @@ class Tracer:
 #: capturing ``jax.profiler`` session
 NULL_TRACER = Tracer(enabled=False)
 
-#: for code with no tracer handle (``core/netmon.py``)
+#: for code with no tracer handle (``core/netmon.py``, ``core/clock.py``)
 span = NULL_TRACER.span
 
 

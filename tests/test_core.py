@@ -144,6 +144,74 @@ def test_ssp_dead_worker_excluded():
     assert ctl.wait_turn(0, 2, timeout=5)  # dead worker no longer gates
 
 
+@pytest.fixture
+def turns(monkeypatch):
+    """The ``ps.worker.turn`` spans ``core/clock.py`` opens, kept by an
+    enabled tracer in place of its module-level ``span`` (the sink a profiler
+    session is to the benchmark): ``[(attrs, seconds)]``."""
+    from parameter_server_tpu.core import clock as clock_mod
+    from parameter_server_tpu.utils.trace import Tracer
+
+    tracer = Tracer()
+    monkeypatch.setattr(clock_mod, "span", tracer.span)
+    return lambda: [
+        (attrs, dur) for name, _, dur, _, attrs in tracer.spans()
+        if name == "ps.worker.turn"
+    ]
+
+
+@pytest.mark.parametrize(
+    "mode,delay,spans",
+    [
+        (ConsistencyMode.BSP, 0, 4),
+        (ConsistencyMode.SSP, 2, 4),
+        (ConsistencyMode.ASP, 0, 0),
+    ],
+)
+def test_a_turn_is_one_span_under_a_bound_and_none_without(
+    turns, mode, delay, spans
+):
+    ctl = ConsistencyController(ConsistencyConfig(mode=mode, max_delay=delay), 1)
+    for t in range(4):
+        assert ctl.wait_turn(0, t, timeout=1)
+        ctl.finish_iteration(0)
+    got = turns()
+    assert len(got) == spans
+    # a lone worker is its own slowest: it leads by nothing and never waits
+    assert [a for a, _ in got] == [
+        {"worker": 0, "t": t, "lead": 0, "blocked": 0} for t in range(spans)
+    ]
+    assert ctl.counters() == {"turn_waits": 0, "turn_wait_s": 0.0}
+
+
+def test_a_held_back_turn_says_so_and_only_it_is_counted(turns):
+    ctl = ConsistencyController(
+        ConsistencyConfig(mode=ConsistencyMode.SSP, max_delay=1), num_workers=2
+    )
+    for t in range(2):  # within the bound of the idle worker 1: open turns
+        assert ctl.wait_turn(0, t, timeout=1)
+        ctl.finish_iteration(0)
+    assert ctl.counters()["turn_waits"] == 0
+    release = threading.Timer(0.05, ctl.finish_iteration, args=(1,))
+    release.start()
+    try:
+        assert ctl.wait_turn(0, 2, timeout=5)  # held until worker 1 steps
+    finally:
+        release.join()
+    assert ctl.wait_turn(1, 1, timeout=1)  # the slow worker itself: open
+    held, slow = turns()[2:]
+    assert held[0] == {"worker": 0, "t": 2, "lead": 2, "blocked": 1}
+    assert slow[0] == {"worker": 1, "t": 1, "lead": 0, "blocked": 0}
+    got = ctl.counters()
+    assert got["turn_waits"] == 1
+    # the counter times the wait alone, inside the span
+    assert 0.03 < got["turn_wait_s"] <= held[1]
+    # a turn that times out waited too
+    assert not ctl.wait_turn(0, 3, timeout=0.02)
+    assert ctl.counters()["turn_waits"] == 2
+    assert turns()[-1][0] == {"worker": 0, "t": 3, "lead": 2, "blocked": 1}
+
+
 class SlowEcho(Customer):
     """Echo that answers after ``delay`` seconds (deadline-path fixture)."""
 
