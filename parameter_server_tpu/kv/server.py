@@ -58,6 +58,7 @@ from parameter_server_tpu.kv.routing import (
     RoutingTable,
 )
 from parameter_server_tpu.kv.table import KVTable
+from parameter_server_tpu.utils import keys as keys_lib
 from parameter_server_tpu.utils.keys import leg_bucket as _bucket
 from parameter_server_tpu.utils.platform import role_device
 from parameter_server_tpu.utils.trace import (
@@ -192,6 +193,12 @@ class KVServer(Customer):
         #: padded to (``_counted``)
         self.apply_ids_real = 0
         self.apply_ids_bucket = 0
+        #: requests localized (``_localize_request``), and of those the ones
+        #: the native pass ran: the two are equal wherever the keymap
+        #: library loaded
+        self.localize_requests = 0
+        self.localize_native = 0
+        self._keymap = keys_lib._keymap_lib()
         self.ro_hist: Dict[str, LatencyHistogram] = {
             t: LatencyHistogram() for t in table_cfgs
         }
@@ -293,6 +300,8 @@ class KVServer(Customer):
         ends = np.asarray([hi for _, hi in segs], dtype=np.int64)
         sizes = ends - starts
         locs = np.concatenate([[0], np.cumsum(sizes)])[:-1].astype(np.int64)
+        # each a fresh contiguous int64 array, made once a routing: the
+        # native localization reads them through their addresses
         return starts, ends, locs
 
     def _try_localize(
@@ -314,17 +323,42 @@ class KVServer(Customer):
 
     def _localize_request(
         self, table: str, keys
-    ) -> Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
-        """Worker keys (sorted GLOBAL ids, pad == global rows) -> local ids.
+    ) -> Optional[Tuple[np.ndarray, np.ndarray, int, int]]:
+        """A request's keys (GLOBAL ids in any order, a pad >= the table's
+        global rows) against this server's shard map: everything the request
+        reads of them, ``(local_ids int32, touched_segments, real, upto)``.
 
-        One vectorized pass over the keys produces everything both data
-        paths need: ``(local_ids int32, keys int64, touched_segments)``.
-        The segment indices fall out of the localization's own
-        ``searchsorted`` ranking, so the staleness bump no longer re-ranks
-        the keys (it used to run ``searchsorted`` a second time per
-        request).  Pads map to this shard's trash row; returns None when
-        any real id is not owned here (the fence trigger).
-        """
+        Pads map to this shard's trash row.  ``touched_segments`` are the
+        sorted distinct indices of the owned segments the real keys fall in
+        (what the staleness clock bumps and reports).  ``real`` counts the
+        real keys; ``upto`` is one past the last of them, how many of the
+        leg's ids an apply has to visit: what follows points at the trash
+        row, which every apply resets (the worker pads its sorted slots with
+        keys past the table, so they are the tail of the last shard's leg:
+        25 k of a 45 k leg in ``criteo_lr.skew``).  None when a real key is
+        not owned here, a negative one included (the fence trigger).
+
+        Where the keymap library loaded this is ONE native call over the
+        keys as they arrived (``utils/keys.py::localize_shard_native``) that
+        keeps the interpreter's lock for its tens of microseconds, where the
+        NumPy body hands it back in every pass and queues for it behind the
+        process's other threads.  :meth:`_localize_numpy` is the definition,
+        and what runs without the library (``counters``: ``localize_native``
+        of ``localize_requests``)."""
+        self.localize_requests += 1
+        if self._keymap is None:
+            return self._localize_numpy(table, keys)
+        self.localize_native += 1
+        return keys_lib.localize_shard_native(
+            self._keymap, keys, self.routing.tables[table].rows,
+            self._shard_maps[table], self.tables[table].rows,
+        )
+
+    def _localize_numpy(
+        self, table: str, keys
+    ) -> Optional[Tuple[np.ndarray, np.ndarray, int, int]]:
+        """:meth:`_localize_request` in NumPy, some fifteen passes over the
+        keys: the definition the native pass is held to, and the fallback."""
         grows = self.routing.tables[table].rows
         kn = np.asarray(keys, dtype=np.int64)
         out = np.full(kn.shape, self.tables[table].rows, dtype=np.int32)
@@ -342,7 +376,9 @@ class KVServer(Customer):
                 return None
             out[real] = (rk - starts[idx_c] + locs[idx_c]).astype(np.int32)
             segs = np.unique(idx_c)
-        return out, kn, segs
+        rows = np.flatnonzero(real)
+        upto = int(rows[-1]) + 1 if rows.size else 0
+        return out, segs, int(rows.size), upto
 
     def _fence_reply(self, msg: Message, why: str) -> Message:
         """Typed reject: ``__error__`` + ``__fenced__`` + the CURRENT table.
@@ -556,6 +592,8 @@ class KVServer(Customer):
             # padded to: the pad share a flat plane's apply skips
             "apply_ids_real": self.apply_ids_real,
             "apply_ids_bucket": self.apply_ids_bucket,
+            "localize_requests": self.localize_requests,
+            "localize_native": self.localize_native,
             # hierarchical push (ISSUE 15): fan-in totals the telemetry
             # plane derives grp_pct from (group-reduced applies / raw
             # member contributions they replaced)
@@ -707,7 +745,9 @@ class KVServer(Customer):
         """Routing fence + localization for a PUSH/PULL.
 
         Returns a fence-reject ``Message``, or the localized
-        ``(tname, ids_np, kn, segs)`` tuple when the request may proceed.
+        ``(tname, ids_np, upto, segs)`` tuple when the request may proceed
+        (:meth:`_localize_request`: ``upto`` is how many of ``ids_np`` an
+        apply has to visit).
 
         Routing fence (PR-6): a stamped epoch that disagrees means the
         sender routed with a different table generation — reject with the
@@ -727,12 +767,13 @@ class KVServer(Customer):
             )
         with self.tracer.span("ps.server.localize") as lsp:
             loc = self._localize_request(tname, msg.keys)
-            if lsp.recording and loc is not None:
-                lsp.set(
-                    keys=int(loc[1].size),
-                    real=int(np.count_nonzero(loc[0] != self.tables[tname].rows)),
-                    segs=int(loc[2].size),
-                )
+            if lsp.recording:
+                lsp.set(engine="numpy" if self._keymap is None else "native")
+                if loc is not None:
+                    lsp.set(
+                        keys=int(loc[0].size), real=loc[2],
+                        segs=int(loc[1].size),
+                    )
         if loc is None:
             return self._fence_reply(
                 msg,
@@ -762,8 +803,8 @@ class KVServer(Customer):
                     sender=msg.sender, table=tname, step=int(cstep),
                     fleet_min=fm,
                 )
-        ids_np, kn, segs = loc
-        return tname, ids_np, kn, segs
+        ids_np, segs, _real, upto = loc
+        return tname, ids_np, upto, segs
 
     def _pad_ids(self, table: KVTable, ids_np: np.ndarray, b: int) -> np.ndarray:
         # Bucket-pad the slice to a power of two: the worker bucket-pads its
@@ -778,17 +819,6 @@ class KVServer(Customer):
         padded_ids = np.full(b, table.rows, dtype=np.int32)
         padded_ids[:n] = ids_np
         return padded_ids
-
-    @staticmethod
-    def _real_ids(table: KVTable, ids_np: np.ndarray) -> int:
-        """How many of a leg's ids an apply has to visit: all up to the last
-        that is a row of the shard.  What follows points at the trash row,
-        which every apply resets: the worker's own bucket pads (it pads its
-        sorted slots with keys past the table, so they are the tail of the
-        last shard's leg: 25 k of a 45 k leg in ``criteo_lr.skew``), and
-        after them this server's."""
-        rows = np.flatnonzero(ids_np != table.rows)
-        return int(rows[-1]) + 1 if rows.size else 0
 
     def _counted(self, real: int, b: int) -> np.int32:
         """``real``, the ids an apply visits of the ``b`` its bucket holds,
@@ -858,15 +888,16 @@ class KVServer(Customer):
         msg: Message,
         tname: str,
         ids_np: np.ndarray,
-        kn: np.ndarray,
+        real: int,
         segs: np.ndarray,
         sp,
     ) -> Message:
-        """Apply one push under its open ``ps.server.push`` span ``sp``."""
+        """Apply one push under its open ``ps.server.push`` span ``sp``;
+        ``real`` of its ``ids_np`` are to be visited (the localization's
+        ``upto``)."""
         table = self.tables[tname]
         n = int(ids_np.shape[0])
         b = _bucket(n)
-        real = self._real_ids(table, ids_np)
         sp.set(rows=n, bucket=b, real=real, members=1)
         tctx = msg.task.payload.get(TRACE_KEY)
         tok = (
@@ -893,11 +924,17 @@ class KVServer(Customer):
         with self.tracer.span("ps.server.ack", kind="push"):
             if tok is not None:
                 self.ledger.submit(tok, ref, lambda t=table: t.value)
-            return self._ack_push(msg, tname, kn, segs)
+            return self._ack_push(msg, tname, segs)
 
-    def _ack_push(
-        self, msg: Message, tname: str, kn: np.ndarray, segs: np.ndarray
-    ) -> Message:
+    @staticmethod
+    def _written_keys(msg: Message) -> np.ndarray:
+        """A push's keys as the ``int64`` the dirty tracking compares in.
+        Built only while a migration or a snapshot is open (``_ack_push``):
+        a leg outside those windows pays no conversion pass.  The keys are
+        the HOST wire plane, so this observes no device result."""
+        return np.asarray(msg.keys, dtype=np.int64)
+
+    def _ack_push(self, msg: Message, tname: str, segs: np.ndarray) -> Message:
         """Post-dispatch bookkeeping + ack: the SYNC-FREE tail of every push.
 
         Runs after the device apply is dispatched but makes no attempt to
@@ -931,6 +968,11 @@ class KVServer(Customer):
             sver = int(ver[segs].max())
         else:
             sver = self.version_max(tname)
+        kn = (
+            self._written_keys(msg)
+            if self._migrations or self._snapshots
+            else None
+        )
         if self._migrations:
             # dirty tracking: rows in a migrating range changed after
             # their chunk may have shipped — the commit delta re-sends
@@ -1053,7 +1095,7 @@ class KVServer(Customer):
             v = self._admit(msg, sp)
             if isinstance(v, Message):
                 return v
-            tname, ids_np, _kn, segs = v
+            tname, ids_np, _upto, segs = v
             if msg.task.payload.get(READ_ONLY_KEY):
                 t0 = time.perf_counter()
                 rows, n, sver = self._pull_ro_device(tname, ids_np, segs, sp)
@@ -1110,19 +1152,19 @@ class KVServer(Customer):
         replies: List[Optional[Message]] = [None] * len(msgs)
         pulls: List[tuple] = []  # (i, msg, rows, n, sver)
         ro: List[tuple] = []  # (i, msg, tname, rows, n, sver, t0)
-        group: List[tuple] = []  # (i, msg, tname, ids_np, kn, segs)
+        group: List[tuple] = []  # (i, msg, tname, ids_np, upto, segs)
 
         def flush_group() -> None:
             if not group:
                 return
             try:
                 if len(group) == 1:
-                    i, m, tname, ids_np, kn, segs = group[0]
+                    i, m, tname, ids_np, upto, segs = group[0]
                     with self.tracer.span(
                         "ps.server.push", **self._span_attrs(m)
                     ) as sp:
                         replies[i] = self._handle_push_single(
-                            m, tname, ids_np, kn, segs, sp
+                            m, tname, ids_np, upto, segs, sp
                         )
                 else:
                     self._apply_push_group(group, replies)
@@ -1149,13 +1191,13 @@ class KVServer(Customer):
                     flush_group()  # the fence observes prior writes too
                     replies[i] = v
                     continue
-                tname, ids_np, kn, segs = v
+                tname, ids_np, upto, segs = v
                 if msg.task.kind == TaskKind.PUSH:
                     if group and (
                         group[0][2] != tname or len(group) >= batch_cap
                     ):
                         flush_group()
-                    group.append((i, msg, tname, ids_np, kn, segs))
+                    group.append((i, msg, tname, ids_np, upto, segs))
                 elif msg.task.kind == TaskKind.PULL:
                     if msg.task.payload.get(READ_ONLY_KEY):
                         # NO flush_group(): relaxed read, see docstring
@@ -1277,8 +1319,8 @@ class KVServer(Customer):
             with self.tracer.span("ps.server.ack", kind="push"):
                 if tok is not None:
                     self.ledger.submit(tok, ref, lambda t=table: t.value)
-                for i, m, tname_, _, kn, segs in group:
-                    replies[i] = self._ack_push(m, tname_, kn, segs)
+                for i, m, tname_, _, _, segs in group:
+                    replies[i] = self._ack_push(m, tname_, segs)
 
     def _push_group_rounds(
         self,

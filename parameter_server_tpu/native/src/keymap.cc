@@ -1,6 +1,7 @@
 // Native key localization: the persistent key->slot map that is the stateful
-// Localizer's hot path, and (further down) the one-pass batch localization
-// of the stateless maps.
+// Localizer's hot path, (further down) the one-pass batch localization of
+// the stateless maps on the worker, and (last) a server's one-pass
+// localization of a request's leg against its shard map.
 //
 // The reference keeps the streaming-key vocabulary in the server's C++ hash
 // map (``src/parameter/kv_map.h`` / ``src/util/localizer.h`` [U] —
@@ -267,6 +268,44 @@ bool first_seen_ids(Dedup& d, const uint64_t* in, int64_t n, uint64_t capacity,
   return ok;
 }
 
+// A server's leg against its shard map (ps_localize_shard), keys of type K
+// widened to signed 64 bits.  ``segs`` holds a flag a segment on entry to
+// the loop and the touched segments' indices, ascending, on return.
+template <typename K>
+int64_t localize_shard(const K* keys, int64_t n, int64_t grows,
+                       const int64_t* starts, const int64_t* ends,
+                       const int64_t* locals, int64_t nseg, int32_t trash,
+                       int32_t* out, int64_t* segs, int64_t* counts) {
+  std::fill(segs, segs + nseg, 0);
+  int64_t n_real = 0, upto = 0;
+  int64_t seg = 0;  // the previous real key's segment: where a search starts
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t g = static_cast<int64_t>(keys[i]);
+    if (g >= grows) {
+      out[i] = trash;
+      continue;
+    }
+    // a row offset is >= 0, so a negative key ends here too
+    if (nseg == 0 || g < starts[0]) return -1;
+    // the last segment that starts at or before g (searchsorted "right" - 1)
+    if (g < starts[seg] || (seg + 1 < nseg && g >= starts[seg + 1])) {
+      seg = (std::upper_bound(starts, starts + nseg, g) - starts) - 1;
+    }
+    if (g >= ends[seg]) return -1;
+    out[i] = static_cast<int32_t>(g - starts[seg] + locals[seg]);
+    segs[seg] = 1;
+    ++n_real;
+    upto = i + 1;
+  }
+  int64_t m = 0;
+  for (int64_t s = 0; s < nseg; ++s) {
+    if (segs[s]) segs[m++] = s;
+  }
+  counts[0] = n_real;
+  counts[1] = upto;
+  return m;
+}
+
 }  // namespace
 
 extern "C" {
@@ -349,6 +388,29 @@ void ps_localize_take(int32_t* out, int64_t bucket, int32_t pad) {
   const int64_t m = std::min<int64_t>(order.size(), bucket);
   for (int64_t r = 0; r < m; ++r) out[r] = static_cast<int32_t>(order[r] >> 32);
   std::fill(out + m, out + bucket, pad);
+}
+
+// A server's localization of one request's leg, in one pass: ``n`` global
+// keys of ``key_bytes`` (4: int32, 8: int64) each, compared as signed 64-bit
+// values, against the shard map ``starts/ends/locals[nseg]`` (the owned
+// segments, ascending).  A key >= ``grows`` (a pad) -> ``trash``; a key g in
+// owned segment i -> g - starts[i] + locals[i].  The keys need not be
+// sorted; sorted keys find their segment where the last one was.  Returns
+// the count of touched segments and leaves their indices, ascending, in
+// ``segs[nseg]``; counts[0] = the real keys, counts[1] = one past the last
+// real key's position.  -1: a real key is in no owned segment (or is
+// negative): the request fences, and ``out`` / ``segs`` hold nothing.
+int64_t ps_localize_shard(const void* keys, int key_bytes, int64_t n,
+                          int64_t grows, const int64_t* starts,
+                          const int64_t* ends, const int64_t* locals,
+                          int64_t nseg, int32_t trash, int32_t* out,
+                          int64_t* segs, int64_t* counts) {
+  if (key_bytes == 4) {
+    return localize_shard(static_cast<const int32_t*>(keys), n, grows, starts,
+                          ends, locals, nseg, trash, out, segs, counts);
+  }
+  return localize_shard(static_cast<const int64_t*>(keys), n, grows, starts,
+                        ends, locals, nseg, trash, out, segs, counts);
 }
 
 }  // extern "C"

@@ -23,7 +23,7 @@ most ``O(log(max_keys))`` times (SURVEY.md §7 hard part #1).
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -292,6 +292,44 @@ def _localize_native(
     return slots, inverse, int(n)
 
 
+def localize_shard_native(
+    lib,
+    keys: np.ndarray,
+    grows: int,
+    shard_map: Tuple[np.ndarray, np.ndarray, np.ndarray],
+    trash: int,
+) -> Optional[Tuple[np.ndarray, np.ndarray, int, int]]:
+    """A server's localization of one leg through ``ps_localize_shard``
+    (``native/src/keymap.cc``): one pass over ``keys`` in the dtype they
+    arrived in (``int32`` / ``int64``; anything else is first made the
+    ``int64`` the definition compares in) against ``shard_map``, the
+    contiguous ``int64`` ``(starts, ends, locals)`` of the owned segments.
+    The call keeps the interpreter's lock (:func:`_keymap_lib` says why).
+
+    Returns ``(local_ids int32, touched_segments int64, real, upto)``:
+    ``real`` counts the keys below ``grows`` and ``upto`` is one past the
+    last of them (what follows are pads, at ``trash``); or None where a
+    real key is in no owned segment.  ``kv/server.py::_localize_numpy`` is
+    the definition.
+    """
+    k = np.asarray(keys)
+    if k.dtype not in (np.int32, np.int64) or not k.flags.c_contiguous:
+        k = np.ascontiguousarray(k, dtype=np.int64)
+    starts, ends, locs = shard_map
+    nseg = starts.shape[0]
+    out = np.empty(k.shape, dtype=np.int32)
+    segs = np.empty(nseg, dtype=np.int64)
+    counts = np.empty(2, dtype=np.int64)
+    m = lib.ps_localize_shard_locked(
+        k.ctypes.data, k.itemsize, k.size, grows,
+        starts.ctypes.data, ends.ctypes.data, locs.ctypes.data, nseg,
+        trash, out.ctypes.data, segs.ctypes.data, counts.ctypes.data,
+    )
+    if m < 0:
+        return None
+    return out, segs[:m], int(counts[0]), int(counts[1])
+
+
 class HashLocalizer:
     """Stateless deterministic key -> slot mapping (the hashing trick).
 
@@ -439,6 +477,22 @@ def _keymap_lib():
             ctypes.c_int32,
         ]
         lib.ps_localize_take.restype = None
+        # The server's pass is bound through PyDLL: the call KEEPS the
+        # interpreter's lock.  It lasts tens of microseconds (7.7 k to 46 k
+        # keys), less than handing the lock back and queueing for it behind
+        # a process's other threads costs a recv thread: through CDLL the
+        # stage read 0.54 ms a leg in ``dlrm_emb.skew.x4`` and 0.51 in
+        # ``criteo_lr.skew``, through PyDLL 0.12 and 0.19 (PERF.md §6, PR 38).
+        shard = ctypes.PyDLL(lib._name).ps_localize_shard
+        shard.argtypes = (
+            [ctypes.c_void_p, ctypes.c_int]
+            + [ctypes.c_int64] * 2
+            + [ctypes.c_void_p] * 3
+            + [ctypes.c_int64, ctypes.c_int32]
+            + [ctypes.c_void_p] * 3
+        )
+        shard.restype = ctypes.c_int64
+        lib.ps_localize_shard_locked = shard
         lib._ps_keymap_sigs = True
     return lib
 
