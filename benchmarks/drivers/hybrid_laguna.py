@@ -2,12 +2,13 @@
 and full attention layers with their own head counts and rotary tables, a
 gate a head on the attention output, a held share of routed experts with a
 shared one) on the hybrid path.  Set-up, the loop and the window are
-``drivers/hybrid_lm.py``'s, the ``.moe.json`` file ``drivers/hybrid_lfm2.py``'s:
-the package's ``HybridLMTrainer`` on a 1 x 1 mesh of the cell's chip, the
-embedding rows pulled from and pushed to the cluster's ``KVServer``s as
-device arrays, a step that drops a token slot of a held expert retiring the
-worker.  This file names what those drivers name in their bodies: the
-model's config, the reference, and the leaves the comparison reads.
+``drivers/hybrid_lm.py``'s: the package's ``HybridLMTrainer`` on a 1 x 1
+mesh of the cell's chip, the embedding rows pulled from and pushed to the
+cluster's ``KVServer``s as device arrays, a step that drops a token slot of
+a held expert retiring the worker.  This file names what those drivers
+name in their bodies: the model's config, the reference, the leaves the
+comparison reads, and ``body``, the module of operations, bytes and scopes
+the readers read.
 
 ``grad_check`` is ``hybrid_lfm2``'s comparison with this body's reference
 (that driver reads its reference as a module-level name, so the comparison
@@ -34,6 +35,8 @@ import time
 
 import numpy as np
 
+# this body's operations, bytes and device scopes (``harness/model_scopes.py``)
+from benchmarks.harness import laguna_flops as body  # noqa: F401
 from benchmarks.harness.cell import load_module
 from benchmarks.harness.correctness import TIMEOUT, compare_grads
 from benchmarks.reference import laguna as ref

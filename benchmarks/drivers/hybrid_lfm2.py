@@ -26,10 +26,6 @@ state or a parameter the step left as it was, reads of order 1.  A buffer
 (``expert_bias``) must have a zero first moment and come out of the step
 bit for bit as it went in.
 
-Beside ``hybrid_lm``'s ``[moe]`` line the run leaves the same numbers as a
-file, ``out/series/<cell>.seed<seed>.trace<t>.moe.json``, which
-``harness/lfm2_scopes.py`` reads (held slots a step, the fullest expert over
-the mean).
 """
 
 from __future__ import annotations
@@ -40,6 +36,8 @@ import time
 
 import numpy as np
 
+# this body's operations, bytes and device scopes (``harness/model_scopes.py``)
+from benchmarks.harness import lfm2_flops as body  # noqa: F401
 from benchmarks.harness.cell import load_module
 from benchmarks.harness.correctness import TIMEOUT, compare_grads
 from benchmarks.reference import lfm2_moe as ref
@@ -210,29 +208,3 @@ class Driver(hybrid_lm.Driver):
             fails.append(f"moe_dropped_slots = {counters['moe_dropped_slots']}")
         log(f"[grad_check] {json.dumps(info)}")
         return fails
-
-    # -- the window -----------------------------------------------------------
-    def train(self, clock):
-        super().train(clock)
-        steps = [c for c in self.counters if c.get("moe_held_slots")]
-        if not steps:
-            return
-        run = self.run
-        experts = self.model.experts_held * sum(
-            "experts" in kinds for kinds in self.model.layer_kinds()
-        )
-        out = os.path.join(run.bench_dir, "out", "series")
-        os.makedirs(out, exist_ok=True)
-        with open(os.path.join(
-            out, f"{run.name}.seed{run.seed}.trace{run.trace}.moe.json"
-        ), "w") as f:
-            json.dump({
-                "steps": len(steps),
-                "held_slots_mean": float(np.mean(
-                    [c["moe_held_slots"] for c in steps]
-                )),
-                "load_max_over_mean_p50": float(np.median([
-                    c["moe_max_expert_slots"] * experts / c["moe_held_slots"]
-                    for c in steps
-                ])),
-            }, f)

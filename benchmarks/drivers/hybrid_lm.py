@@ -29,6 +29,7 @@ the step left as it was, reads of order 1.
 from __future__ import annotations
 
 import json
+import os
 import sys
 import threading
 import time
@@ -36,6 +37,9 @@ import time
 import numpy as np
 
 from benchmarks.harness import cluster as cluster_lib
+# this body's operations, bytes and device scopes: ``harness/model_scopes.py``
+# reads them through the driver the configuration names
+from benchmarks.harness import flops_model as body  # noqa: F401
 from benchmarks.harness.correctness import TIMEOUT, compare_grads
 from benchmarks.harness.spans import spanned
 from benchmarks.reference import kimi_linear as ref
@@ -282,23 +286,33 @@ class Driver:
         # not fall, this says what the steps before it did
         log("[losses] " + json.dumps([round(x, 4) for x in self._losses]))
         steps = [c for c in self.counters if c.get("moe_held_slots")]
-        if steps:  # the expert layers' load over the run's steps
-            experts = self.model.experts_held * sum(
-                "experts" in kinds for kinds in self.model.layer_kinds()
-            )
-            held = [c["moe_held_slots"] for c in steps]
-            log("[moe] " + json.dumps({
-                "steps": len(steps), "held_slots_mean": float(np.mean(held)),
-                "held_slots_max": int(max(held)),
-                "max_expert_slots": max(c["moe_max_expert_slots"] for c in steps),
-                "load_max_over_mean_p50": float(np.median([
-                    c["moe_max_expert_slots"] * experts / c["moe_held_slots"]
-                    for c in steps
-                ])),
-                "dropped_slots": sum(
-                    c["moe_dropped_slots"] for c in self.counters
-                ),
-            }))
+        if not steps:
+            return
+        # the expert layers' load over the run's steps: for the readers of
+        # the body (``harness/model_scopes.py``) and, as a file beside the
+        # series, for its command
+        run = self.run
+        experts = self.model.experts_held * sum(
+            "experts" in kinds for kinds in self.model.layer_kinds()
+        )
+        held = [c["moe_held_slots"] for c in steps]
+        run.moe = {
+            "steps": len(steps), "held_slots_mean": float(np.mean(held)),
+            "held_slots_max": int(max(held)),
+            "max_expert_slots": max(c["moe_max_expert_slots"] for c in steps),
+            "load_max_over_mean_p50": float(np.median([
+                c["moe_max_expert_slots"] * experts / c["moe_held_slots"]
+                for c in steps
+            ])),
+            "dropped_slots": sum(c["moe_dropped_slots"] for c in self.counters),
+        }
+        log("[moe] " + json.dumps(run.moe))
+        out = os.path.join(run.bench_dir, "out", "series")
+        os.makedirs(out, exist_ok=True)
+        with open(os.path.join(
+            out, f"{run.name}.seed{run.seed}.trace{run.trace}.moe.json"
+        ), "w") as f:
+            json.dump(run.moe, f)
 
     def loss_count(self):
         return len(self._losses)

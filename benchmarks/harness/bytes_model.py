@@ -1,9 +1,10 @@
 """Bytes and operations the algorithm needs, from shapes alone.
 
-Extends ``bench.py::lr_hbm_bytes_per_example`` / ``lr_flops_per_example``
-(dim 1, one AdaGrad plane, counted per key position) to rows of any ``dim``
-and any number of optimizer planes, counted per UNIQUE row touched: the
-server applies pre-combined rows, so duplicates cost the table nothing."""
+A worker step moves 5 x 4 B at the table for a dim-1 row with AdaGrad (the
+pull reads the weight; the apply reads the weight and its accumulator and
+writes both back); this counts rows of any ``dim`` and any number of
+optimizer planes, per UNIQUE row touched: the server applies pre-combined
+rows, so duplicates cost the table nothing."""
 
 
 def row_bytes(dim, itemsize=4):

@@ -66,6 +66,9 @@ class Run:
     trace_reduced: Optional[dict] = None
     unique_rows_per_step: Optional[float] = None
     planes: int = 1
+    #: a driver that runs a model with experts: the held experts' load over
+    #: the run's steps (``held_slots_mean``, ``load_max_over_mean_p50``)
+    moe: Optional[dict] = None
 
 
 def resolve(bench: dict, workload: str, *, seed: int, seconds: float,

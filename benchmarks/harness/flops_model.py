@@ -17,6 +17,26 @@ second pass over its scores, the checkpoints' second forward).
 
 from __future__ import annotations
 
+from typing import Optional
+
+
+#: what ``harness/model_scopes.py`` reads of this body's trace (the driver
+#: ``hybrid_lm`` names this module): the device scope of the whole jitted
+#: step, and each kernel's scopes
+ROOT_SCOPE = "ps.model.kimi"
+KERNELS = {
+    "kda_scan": ("ps.model.kda.scan",),
+    "mla_attn": ("ps.model.mla.attn",),
+    # the grouped product with the gather that feeds it and the scatter that
+    # takes its rows back: ``moe_experts`` counts a slot's row in and out,
+    # and the products alone read above their roofline (the compiler fuses
+    # row traffic into ``.dispatch``; my chip run, PR 28)
+    "moe_experts": ("ps.model.moe.dispatch", "ps.model.moe.experts",
+                    "ps.model.moe.combine"),
+}
+#: kernels read a layer: kernel -> the mixer whose layers share its time
+PER_LAYER: dict = {}
+
 
 def layer_kinds(cfg: dict) -> list:
     lin = cfg["linear_attn_config"]
@@ -100,6 +120,17 @@ def moe_experts(cfg: dict, held_slots: float) -> dict:
             "bytes": 4 * (3 * weights + 4 * D * held_slots)}
 
 
+def work(cfg: dict, sequences: int, seq_len: int,
+         held_slots: Optional[float] = None) -> dict:
+    """Operations and bytes of each of ``KERNELS`` a step that the shapes
+    count; the experts' only where ``held_slots`` (the step's count) is known."""
+    out = {"kda_scan": kda_scan(cfg, sequences * seq_len),
+           "mla_attn": mla_attn(cfg, sequences, seq_len)}
+    if held_slots is not None:
+        out["moe_experts"] = moe_experts(cfg, held_slots)
+    return out
+
+
 def step_flops(cfg: dict, sequences: int, seq_len: int) -> float:
     """Model operations of one training step: 6 x active matrix parameters
     x tokens, plus attention's scores and the delta rule's recurrence."""
@@ -110,9 +141,3 @@ def step_flops(cfg: dict, sequences: int, seq_len: int) -> float:
         + kda_scan(cfg, tokens)["flops"]
     )
 
-
-def roofline_s(work: dict, peaks: dict) -> float:
-    """The least time the chip could take: the larger of operations over
-    peak FLOP/s and bytes over peak bytes/s."""
-    return max(work["flops"] / peaks["flops"],
-               work["bytes"] / peaks["hbm_bytes_per_s"])

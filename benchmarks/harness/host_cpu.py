@@ -6,10 +6,9 @@ turn.  Nine readings of ``program_spans``' account of a traced run (PR 37):
     python3 -m benchmarks.harness.host_cpu <file.xplane.pb>
 
 prints them as one JSON line after the account they come from, and exits 1
-where a reading cannot be (``recv_thread_cpu_pct`` above 105).  They are not
-``BENCHMARK.json`` entries yet: ``METRICS`` holds what an entry and its
-``layer_metrics`` file need (``PERF.md``, section 7, says which two files of
-the benchmark's tests stand in the way).
+where a reading cannot be (``recv_thread_cpu_pct`` above 105).  Since PR 39
+each is a ``BENCHMARK.json`` entry whose ``layer_metrics`` file calls
+``read`` below; ``METRICS`` holds its unit, direction, layer and ``moves``.
 
 **How a CPU share is read.**  Every ``ps.`` span carries ``cpu_us``, its
 thread's CPU time (``time.thread_time``) between its edges.  Wall less CPU
@@ -173,6 +172,12 @@ METRICS: Dict[str, Metric] = {
         "ms", "lower", "worker wire", "step_ms_p50",
         span_ms_p50("ps.worker.assemble")),
 }
+
+
+def read(run, name: str) -> Optional[float]:
+    """The reading ``name`` of a traced run, for its ``layer_metrics`` file."""
+    acc = program_spans.for_run(run)
+    return METRICS[name].read(acc) if acc is not None else None
 
 
 def read_all(acc: Account) -> Dict[str, Optional[float]]:

@@ -21,8 +21,25 @@ published keys."""
 
 from __future__ import annotations
 
+from typing import Optional
+
 MIXERS = {"full_attention": "full", "sliding_attention": "window"}
 MLPS = {"dense": "dense", "sparse": "experts"}
+
+#: what ``harness/model_scopes.py`` reads of this body's trace (the driver
+#: ``hybrid_laguna`` names this module): the device scope of the whole jitted
+#: step, and each kernel's scopes
+ROOT_SCOPE = "ps.model.laguna"
+KERNELS = {
+    "full_attn": ("ps.model.attn.full",),
+    "window_attn": ("ps.model.attn.window",),
+    # the products with their gather and scatter (``flops_model.KERNELS``)
+    "moe_experts": ("ps.model.moe.dispatch", "ps.model.moe.experts",
+                    "ps.model.moe.combine"),
+}
+#: kernels read a layer: kernel -> the mixer whose layers share its time
+#: (the roofline is the kind's whole work over its whole time)
+PER_LAYER = {"full_attn": "full", "window_attn": "window"}
 
 
 def _held(cfg: dict) -> range:
@@ -111,6 +128,17 @@ def moe_experts(cfg: dict, held_slots: float) -> dict:
     weights = 3 * D * F * cfg["experts_held"] * _count(cfg, "experts")
     return {"flops": 3 * 2 * 3 * D * F * held_slots,
             "bytes": 4 * (3 * weights + 4 * D * held_slots)}
+
+
+def work(cfg: dict, sequences: int, seq_len: int,
+         held_slots: Optional[float] = None) -> dict:
+    """Operations and bytes of each of ``KERNELS`` a step that the shapes
+    count; the experts' only where ``held_slots`` (the step's count) is known."""
+    out = {"full_attn": full_attn(cfg, sequences, seq_len),
+           "window_attn": window_attn(cfg, sequences, seq_len)}
+    if held_slots is not None:
+        out["moe_experts"] = moe_experts(cfg, held_slots)
+    return out
 
 
 def step_flops(cfg: dict, sequences: int, seq_len: int) -> float:

@@ -24,7 +24,24 @@ published keys."""
 
 from __future__ import annotations
 
+from typing import Optional
+
 MIXERS = {"conv": "conv", "full_attention": "gqa"}
+
+#: what ``harness/model_scopes.py`` reads of this body's trace (the driver
+#: ``hybrid_lfm2`` names this module): the device scope of the whole jitted
+#: step, and each kernel's scopes
+ROOT_SCOPE = "ps.model.lfm2"
+KERNELS = {
+    "short_conv": ("ps.model.conv.proj", "ps.model.conv.gate",
+                   "ps.model.conv.out"),
+    "gqa_attn": ("ps.model.gqa.attn",),
+    # the products with their gather and scatter (``flops_model.KERNELS``)
+    "moe_experts": ("ps.model.moe.dispatch", "ps.model.moe.experts",
+                    "ps.model.moe.combine"),
+}
+#: kernels read a layer: kernel -> the mixer whose layers share its time
+PER_LAYER: dict = {}
 
 
 def layer_kinds(cfg: dict) -> list:
@@ -110,6 +127,17 @@ def moe_experts(cfg: dict, held_slots: float) -> dict:
             "bytes": 4 * (3 * weights + 4 * D * held_slots)}
 
 
+def work(cfg: dict, sequences: int, seq_len: int,
+         held_slots: Optional[float] = None) -> dict:
+    """Operations and bytes of each of ``KERNELS`` a step that the shapes
+    count; the experts' only where ``held_slots`` (the step's count) is known."""
+    out = {"short_conv": short_conv(cfg, sequences * seq_len),
+           "gqa_attn": gqa_attn(cfg, sequences, seq_len)}
+    if held_slots is not None:
+        out["moe_experts"] = moe_experts(cfg, held_slots)
+    return out
+
+
 def step_flops(cfg: dict, sequences: int, seq_len: int) -> float:
     """Model operations of one training step: 6 x active matrix parameters
     x tokens, plus attention's scores over the causal half."""
@@ -119,8 +147,3 @@ def step_flops(cfg: dict, sequences: int, seq_len: int) -> float:
         + gqa_attn(cfg, sequences, seq_len)["flops"]
     )
 
-
-def bounds_s(work: dict, peaks: dict) -> dict:
-    """The least time the chip could take by each of its two peaks."""
-    return {"flops": work["flops"] / peaks["flops"],
-            "bytes": work["bytes"] / peaks["hbm_bytes_per_s"]}

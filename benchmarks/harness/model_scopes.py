@@ -1,21 +1,37 @@
-"""The model's own layers in a traced run of a cell on the hybrid path:
-device time under the ``ps.model.*`` scopes a step, the step's model FLOP/s
-utilization and each kernel's share of its roofline (``flops_model``'s work
-over the chip's peaks, over that time).
+"""The model body's own layers in a traced run of a cell on the hybrid path:
+the step's model FLOP/s utilization, device time under the ``ps.model.*``
+scopes a step, each kernel's share of its roofline, the step program's
+executions, the experts' load and the trainer's wait for its rows.  One
+reader for every body: what differs lives in the body's module of
+operations and bytes, which the configuration's driver names as ``body``
+(``drivers/hybrid_lm.py``: ``flops_model``; ``hybrid_lfm2``: ``lfm2_flops``;
+``hybrid_laguna``: ``laguna_flops``).  That module gives
 
-    python3 -m benchmarks.harness.model_scopes <series.json> <file.xplane.pb> [held slots a step]
+- ``ROOT_SCOPE``: the device scope of the whole jitted step;
+- ``KERNELS``: kernel -> the scopes that hold it; ``PER_LAYER``: kernel ->
+  the mixer whose layers share its time, for kernels read a layer;
+- ``layer_kinds(cfg)``, ``work(cfg, sequences, seq_len, held_slots)`` and
+  ``step_flops(cfg, sequences, seq_len)``.
 
-prints them as one JSON object.  They are not per-layer metrics of
-``BENCHMARK.json`` yet: ``benchmarks/tests/test_cell_metrics.py`` holds every cell to
-the same 22 quantities, so an entry of a cell's own waits for a ``benchmark``
-PR (``PERF.md``, section 7); each function below is what such an entry's
-``read(run)`` would call.
+A new body adds that module, its driver, and for each new reading a file
+``layer_metrics/<reading>.py`` of four lines over ``read`` below.
 
-A traced window holds a few steps and cuts the first and the last, so "a
-step" is the traced window's length times the steps a second that the whole
-window's step series reads (the cell is a closed loop on one device: the
-device's step is the host's).  A share above 100 means the count or the time
-is wrong."""
+    python3 -m benchmarks.harness.model_scopes <series.json> <file.xplane.pb> [<moe.json> | held slots]
+
+prints every reading as one JSON object, and exits 1 where a share of a
+peak is above 100; ``<moe.json>`` is what the driver left beside the series
+(``lfm2_scopes`` and ``laguna_scopes`` are the same command).
+
+``mfu_pct`` is the whole step's: model operations a step (``step_flops``:
+the held share's forward and backward) x the window's steps a second (its
+``examples_per_s`` over the batch) over the chip's peak, host stalls
+included.  A traced window holds a few steps and cuts the first and the
+last, so a scope's "ms a step" is its device seconds over the traced
+window's length times the steps a second of the whole window's step series
+(the cell is a closed loop on one device: the device's step is the host's).
+A kernel's roofline is the larger of its two bounds (operations over peak
+FLOP/s, bytes over peak bytes/s) over its device time; ``<kernel>_bound``
+says which.  A share above 100 means the count or the time is wrong."""
 
 from __future__ import annotations
 
@@ -25,21 +41,20 @@ import statistics
 import sys
 from typing import Optional
 
-from benchmarks.harness import flops_model, program_spans, trace_reduce
-from benchmarks.harness.peaks import peaks_for
+from benchmarks.harness import cell as cell_lib
+from benchmarks.harness import program_spans, trace_reduce
+from benchmarks.harness.peaks import bounds_s, peaks_for
 
 STEP_PROGRAM = "jit_step_fn"
-STEP_SCOPE = "ps.model.kimi"
-KERNELS = {  # name -> the scopes that hold it
-    "kda_scan": ("ps.model.kda.scan",),
-    "mla_attn": ("ps.model.mla.attn",),
-    # the grouped product with the gather that feeds it and the scatter that
-    # takes its rows back: ``flops_model.moe_experts`` counts a slot's row in
-    # and out, and the products alone read above their roofline (the
-    # compiler fuses row traffic into ``.dispatch``; my chip run, PR 28)
-    "moe_experts": ("ps.model.moe.dispatch", "ps.model.moe.experts",
-                    "ps.model.moe.combine"),
-}
+#: readings that are a share of a peak: none may pass 100
+SHARES = ("_roofline", "mfu_pct")
+
+
+def body_of(cfg: dict, bench_dir: str = cell_lib.BENCH_DIR):
+    """The body module the configuration's driver names, or ``None`` (a
+    driver that runs no model)."""
+    return getattr(cell_lib.load_module("drivers", cfg["driver"], bench_dir),
+                   "body", None)
 
 
 def steps_per_s(steps) -> Optional[float]:
@@ -77,41 +92,46 @@ def step_program_ms(acc) -> list:
     return out
 
 
-def report(acc, steps, cfg: dict, batch: int, peaks: dict,
-           held_slots: Optional[float] = None) -> dict:
+def report(acc, steps, cfg: dict, batch: int, peaks: Optional[dict],
+           moe: Optional[dict] = None, examples_per_s: Optional[float] = None,
+           bench_dir: str = cell_lib.BENCH_DIR) -> dict:
     """``cfg``: the configuration file's dict; ``batch``: token positions a
-    step; ``held_slots``: mean ``moe_held_slots`` a step, where known."""
+    step; ``peaks``: the chip's (``None``: no share is read); ``moe``: the
+    driver's ``held_slots_mean`` and ``load_max_over_mean_p50``, where
+    known; ``examples_per_s``: the window's."""
+    body = body_of(cfg, bench_dir)
     rate = steps_per_s(steps)
-    if rate is None:
+    if body is None or rate is None:
         return {}
     sequences = cfg["generator_params"]["sequences"]
     seq_len = batch // sequences
-    work = {
-        "kda_scan": flops_model.kda_scan(cfg, batch),
-        "mla_attn": flops_model.mla_attn(cfg, sequences, seq_len),
-    }
-    if held_slots is not None:
-        work["moe_experts"] = flops_model.moe_experts(cfg, held_slots)
+    moe = moe or {}
+    work = body.work(cfg, sequences, seq_len, moe.get("held_slots_mean"))
     out = {"steps_per_s": rate, "traced_window_s": acc.window_s}
-    body = scope_ms_per_step(acc, rate, STEP_SCOPE)
-    if body is not None:
-        out["body_ms"] = body
-        out["body_mfu_pct"] = 100.0 * flops_model.step_flops(
-            cfg, sequences, seq_len
-        ) / peaks["flops"] / (1e-3 * body)
+    if moe.get("load_max_over_mean_p50") is not None:
+        out["moe_load_max_over_mean"] = moe["load_max_over_mean_p50"]
+    if peaks is not None and examples_per_s:
+        out["mfu_pct"] = 100.0 * body.step_flops(cfg, sequences, seq_len) * (
+            examples_per_s / batch
+        ) / peaks["flops"]
+    root = scope_ms_per_step(acc, rate, body.ROOT_SCOPE)
+    if root is not None:
+        out["body_ms"] = root
     programs = step_program_ms(acc)
     if programs:
         out["body_ms_p50"] = statistics.median(programs)
-    for name, scopes in KERNELS.items():
-        parts = [scope_ms_per_step(acc, rate, scope) for scope in scopes]
+    mixers = [mixer for mixer, _mlp in body.layer_kinds(cfg)]
+    for name, scopes in body.KERNELS.items():
+        parts = [scope_ms_per_step(acc, rate, s) for s in scopes]
         if None in parts:
             continue
         ms = sum(parts)
-        out[f"{name}_ms"] = ms
-        if name in work:
-            out[f"{name}_roofline"] = (
-                100.0 * flops_model.roofline_s(work[name], peaks) / (1e-3 * ms)
-            )
+        layers = mixers.count(body.PER_LAYER[name]) if name in body.PER_LAYER else 1
+        out[f"{name}_ms"] = ms / layers
+        if name in work and peaks is not None:
+            bounds = bounds_s(work[name], peaks)
+            out[f"{name}_bound"] = max(bounds, key=bounds.get)
+            out[f"{name}_roofline"] = 100.0 * max(bounds.values()) / (1e-3 * ms)
     out["scope_ms"] = {
         k: scope_ms_per_step(acc, rate, k)
         for k in sorted(acc.scope_s) if k.startswith("ps.model.")
@@ -122,23 +142,61 @@ def report(acc, steps, cfg: dict, batch: int, peaks: dict,
     return out
 
 
-def main(argv) -> int:
-    from benchmarks.harness.cell import BENCH_DIR, load_json
+def above_100(out: dict) -> list:
+    return [
+        f for k, v in out.items() if k.endswith(SHARES)
+        for f in program_spans.above_100(k, v)
+    ]
 
-    series = load_json(argv[0])
+
+# -- what the per-layer metrics read -------------------------------------------
+_CACHE: dict = {}
+
+
+def for_run(run) -> dict:
+    """``report`` of ``run``'s trace, once a process: ``{}`` where there is
+    nothing to read (no trace, no ``ps.`` spans, a driver with no body)."""
+    acc = program_spans.for_run(run)
+    if acc is None:
+        return {}
+    if acc.path not in _CACHE:
+        _CACHE[acc.path] = report(
+            acc, [(s.start, s.end, s.ok) for s in run.steps], run.config,
+            run.sizes["batch"], run.peaks, run.moe,
+            run.window.get("examples_per_s"), run.bench_dir,
+        )
+    return _CACHE[acc.path]
+
+
+def read(run, name: str) -> Optional[float]:
+    return for_run(run).get(name)
+
+
+def main(argv) -> int:
+    root = os.path.dirname(cell_lib.BENCH_DIR)
+    series = cell_lib.load_json(argv[0])
     acc = program_spans.load(argv[1])
-    bench = load_json(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json"))
+    bench = cell_lib.load_json(os.path.join(root, "BENCHMARK.json"))
     cell = next(w for w in bench["workloads"] if w["name"] == series["cell"])
-    cfg = load_json(os.path.join(
-        os.path.dirname(BENCH_DIR),
-        next(c["file"] for c in bench["configs"] if c["name"] == cell["config"]),
-    ))
+    cfg = cell_lib.load_json(os.path.join(root, next(
+        c["file"] for c in bench["configs"] if c["name"] == cell["config"]
+    )))
     steps = [(a, b, ok) for _w, _i, a, b, ok, _spans in series["steps"]]
-    held = float(argv[2]) if len(argv) > 2 else None
-    print(json.dumps(report(
-        acc, steps, cfg, cfg["batch_per_worker"], peaks_for("TPU v5 lite"), held
-    ), indent=1))
-    return 0
+    moe = None
+    if len(argv) > 2:  # the driver's file, or held slots a step as a number
+        moe = (cell_lib.load_json(argv[2]) if argv[2].endswith(".json")
+               else {"held_slots_mean": float(argv[2])})
+    out = report(
+        acc, steps, cfg, cfg["batch_per_worker"],
+        peaks_for(series["result"]["device"]["kind"]), moe,
+        series["window"]["examples_per_s"],
+    )
+    print(json.dumps(out, indent=1))
+    fails = above_100(out)
+    for f in fails:
+        print(f"{f}: the count is too high or the time leaves out work",
+              file=sys.stderr)
+    return 1 if fails else 0
 
 
 if __name__ == "__main__":

@@ -18,3 +18,10 @@ def peaks_for(device_kind):
             f"no peaks for device_kind {device_kind!r}: add it to "
             "benchmarks/harness/peaks.py with its source"
         ) from None
+
+
+def bounds_s(work: dict, peaks: dict) -> dict:
+    """The least time the chip could take for ``work`` (``{"flops",
+    "bytes"}``) by each of its two peaks; a roofline is the larger."""
+    return {"flops": work["flops"] / peaks["flops"],
+            "bytes": work["bytes"] / peaks["hbm_bytes_per_s"]}

@@ -1,0 +1,16 @@
+"""Share of its roofline grouped-query attention reaches (the causal half
+over the query heads).  Above 100 the traced run fails.
+``harness/model_scopes.py`` reads it; a cell whose driver runs no such
+body reads nothing."""
+
+from benchmarks.harness import model_scopes, program_spans
+
+NAME, UNIT, LAYER, MOVES = "gqa_attn_roofline", "%", "model kernels", "step_ms_p50"
+
+
+def read(run):
+    return model_scopes.read(run, NAME)
+
+
+def check(value):
+    return program_spans.above_100(NAME, value)

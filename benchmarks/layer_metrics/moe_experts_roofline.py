@@ -1,0 +1,18 @@
+"""Share of its roofline the expert layer reaches: the held experts' three
+matrices over the step's held slots (the driver's count), operations or
+bytes, whichever bounds, over its device time.  Above 100 the traced run
+fails.
+``harness/model_scopes.py`` reads it; a cell whose driver runs no such
+body reads nothing."""
+
+from benchmarks.harness import model_scopes, program_spans
+
+NAME, UNIT, LAYER, MOVES = "moe_experts_roofline", "%", "model kernels", "step_ms_p50"
+
+
+def read(run):
+    return model_scopes.read(run, NAME)
+
+
+def check(value):
+    return program_spans.above_100(NAME, value)

@@ -1,0 +1,10 @@
+"""``recv_thread_cpu_pct`` in the cells that hold only ``step_ms_p95`` end to end
+(``PERF.md``, section 2): the same reader, split because those cells report
+another end-to-end metric for it to move."""
+
+from benchmarks.harness.cell import base_reader
+
+_BASE = base_reader(__file__)
+NAME, UNIT, LAYER, MOVES = "recv_thread_cpu_pct.p95only", _BASE.UNIT, _BASE.LAYER, "step_ms_p95"
+read = _BASE.read
+check = getattr(_BASE, "check", None)

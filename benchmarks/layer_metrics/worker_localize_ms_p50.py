@@ -1,6 +1,8 @@
 """Median of the program's ``ps.worker.localize`` spans in the traced window:
-``localize_to_slots`` (hash, two ``np.unique``, bucket pad) in
-``KVWorker.pull`` and in ``_prepare_push``, once each a step."""
+``KVWorker._localize``, opened only where a localization is computed (PR 32:
+once a step in a pull-then-push loop, under the pull; the push reuses it),
+and since PR 34 one native pass (``engine`` ``native``) wherever the keymap
+library loaded."""
 
 from benchmarks.harness import program_spans
 

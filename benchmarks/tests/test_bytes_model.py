@@ -10,7 +10,7 @@ from benchmarks.harness.peaks import peaks_for
 
 
 def test_dim_1_one_plane_is_five_row_touches_of_4_bytes():
-    # bench.py::lr_hbm_bytes_per_example's 5 x 4 B, per unique row
+    # the pull's read, the apply's two reads and two writes: 5 x 4 B a row
     assert bytes_model.step_hbm_bytes(1, dim=1, planes=1) == 20
     assert bytes_model.step_hbm_bytes(40_000, dim=1, planes=1) == 800_000
     assert bytes_model.pull_bytes(40_000, 1) == 160_000

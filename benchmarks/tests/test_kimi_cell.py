@@ -14,6 +14,7 @@ import pytest
 
 from benchmarks.harness import cell as cell_lib
 from benchmarks.harness import flops_model, model_scopes
+from benchmarks.harness.peaks import bounds_s
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
@@ -57,6 +58,12 @@ def test_the_cell_reports_all_four_end_to_end_metrics_on_one_chip():
     run = cell_lib.resolve(BENCH, CELL, seed=1, seconds=1.0, trace=0, dry_run=False)
     assert run.sizes == {"workers": 1, "servers": 2, "rows": 20480,
                          "batch": 16384, "cycle": 32, "warmup": 32}
+    # its own entries: the body's, with its two kernels (PR 39)
+    assert {m["name"] for m in cell_lib.layer_metrics_for(run) if "workloads" in m} == {
+        "mfu_pct", "body_ms_p50", "moe_experts_ms", "moe_experts_roofline",
+        "moe_load_max_over_mean", "hybrid_pull_wait_ms_p50",
+        "kda_scan_ms", "kda_scan_roofline", "mla_attn_ms", "mla_attn_roofline",
+    }
 
 
 def test_the_generator_makes_whole_sequences_of_the_slice():
@@ -119,11 +126,11 @@ def test_a_step_is_38_tflop_and_a_roofline_takes_the_larger_bound():
     flops = flops_model.step_flops(CFG, 2, 8192)
     assert abs(flops - 37.8e12) < 0.1e12
     peaks = {"flops": 197e12, "hbm_bytes_per_s": 819e9}
-    assert flops_model.roofline_s({"flops": 197e12, "bytes": 1}, peaks) == 1.0
-    assert flops_model.roofline_s({"flops": 1, "bytes": 819e9}, peaks) == 1.0
+    assert bounds_s({"flops": 197e12, "bytes": 1}, peaks)["flops"] == 1.0
+    assert bounds_s({"flops": 1, "bytes": 819e9}, peaks)["bytes"] == 1.0
 
 
-def test_model_scopes_reads_a_step_s_share_from_an_account():
+def test_model_scopes_reads_a_step_s_share_from_an_account(monkeypatch):
     peaks = {"flops": 197e12, "hbm_bytes_per_s": 819e9}
     # ten steps of 0.5 s; a traced window of 2 s holds four of them
     steps = [(0.5 * i, 0.5 * (i + 1), True) for i in range(10)]
@@ -133,11 +140,15 @@ def test_model_scopes_reads_a_step_s_share_from_an_account():
                  "ps.model.mla.attn": 0.2, "ps.model.moe.experts": 0.02,
                  "ps.model.moe.dispatch": 0.016, "ps.model.moe.combine": 0.004},
     )
-    model_scopes.step_program_ms = lambda acc: [470.0, 480.0, 490.0]
-    out = model_scopes.report(acc, steps, CFG, 16384, peaks, held_slots=19200.0)
+    monkeypatch.setattr(model_scopes, "step_program_ms", lambda acc: [470.0, 480.0, 490.0])
+    # the window's rate: 2 steps of 16,384 positions a second
+    out = model_scopes.report(acc, steps, CFG, 16384, peaks,
+                              {"held_slots_mean": 19200.0}, 32768.0)
     assert out["steps_per_s"] == 2.0 and out["body_ms"] == 475.0
-    mfu = 100 * flops_model.step_flops(CFG, 2, 8192) / 197e12 / 0.475
-    assert abs(out["body_mfu_pct"] - mfu) < 1e-9 and 30 < mfu < 50
+    # the whole step's share: the step's operations twice a second
+    mfu = 100 * flops_model.step_flops(CFG, 2, 8192) * 2 / 197e12
+    assert abs(out["mfu_pct"] - mfu) < 1e-9 and 30 < mfu < 50
+    assert "body_mfu_pct" not in out
     assert out["body_ms_p50"] == 480.0 and out["kda_scan_ms"] == 100.0
     assert abs(out["mla_attn_roofline"]
                - 100 * flops_model.mla_attn(CFG, 2, 8192)["flops"] / 197e12 / 0.05) < 1e-9
@@ -240,11 +251,19 @@ def test_the_dry_run_is_correct_and_traced_reports_the_hybrid_spans(tmp_path):
     assert '"dropped_slots": 0' in err.split("[moe] ")[-1].splitlines()[0]
     traced, err = dry(tmp_path, "--trace", "1")
     assert traced["correct"] is True, err[-3000:]
-    # the 22 metrics' readers find the worker's and the servers' spans here
+    # the readers find the worker's, the servers' and the trainer's spans
+    # and the driver's counts here; the device's scopes and shares only a
+    # chip's trace holds
     for name in ("pull_ms_p50", "grad_ms_p50", "push_ms_p50",
                  "worker_localize_ms_p50", "server_pull_busy_ms_p50",
-                 "server_push_busy_ms_p50", "compiles_in_window"):
+                 "server_push_busy_ms_p50", "compiles_in_window",
+                 "server_localize_ms_p50", "server_ack_ms_p50",
+                 "server_self_ms_p50", "worker_submit_ms_p50",
+                 "worker_combine_ms_p50", "worker_assemble_ms_p50",
+                 "hybrid_pull_wait_ms_p50", "moe_load_max_over_mean"):
         assert name in traced["metrics"], name
+    assert traced["metrics"]["turn_wait_ms_p50"]["value"] == 0.0  # no controller
+    assert "mfu_pct" not in traced["metrics"]  # no chip, no peak
     for span in ("ps.hybrid.step", "ps.hybrid.pull_wait", "ps.hybrid.push_submit",
                  "ps.hybrid.prefetch", "ps.hybrid.body_dispatch"):
         assert span in err, span

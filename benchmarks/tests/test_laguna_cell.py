@@ -1,5 +1,5 @@
 """The cell ``laguna_xs2.pretrain8k``: its configuration file against the
-catalog row's keys, ``laguna_flops`` against hand counts, ``laguna_scopes``
+catalog row's keys, ``laguna_flops`` against hand counts, ``model_scopes``
 on a made-up account, what the gradient check refuses, and the command's dry
 run (CPU, tiny sizes, float32: the reference comparison there holds to 1e-4
 / 1e-3)."""
@@ -13,8 +13,8 @@ import types
 import pytest
 
 from benchmarks.harness import cell as cell_lib
-from benchmarks.harness import laguna_flops, laguna_scopes, model_scopes
-from benchmarks.harness.lfm2_flops import bounds_s
+from benchmarks.harness import laguna_flops, model_scopes
+from benchmarks.harness.peaks import bounds_s
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
@@ -101,7 +101,12 @@ def test_the_cell_reports_all_four_end_to_end_metrics_on_one_chip():
     run = cell_lib.resolve(BENCH, CELL, seed=1, seconds=1.0, trace=0, dry_run=False)
     assert run.sizes == {"workers": 1, "servers": 2, "rows": 12544,
                          "batch": 16384, "cycle": 32, "warmup": 32}
-    assert len(cell_lib.layer_metrics_for(run)) == 22
+    # its own entries: the body's, with its two kernels (PR 39)
+    assert {m["name"] for m in cell_lib.layer_metrics_for(run) if "workloads" in m} == {
+        "mfu_pct", "body_ms_p50", "moe_experts_ms", "moe_experts_roofline",
+        "moe_load_max_over_mean", "hybrid_pull_wait_ms_p50",
+        "full_attn_ms", "full_attn_roofline", "window_attn_ms", "window_attn_roofline",
+    }
 
 
 # -- laguna_flops against hand counts ----------------------------------------------
@@ -186,15 +191,15 @@ def test_laguna_scopes_reads_a_step_s_shares_from_an_account(monkeypatch):
     })
     monkeypatch.setattr(model_scopes, "step_program_ms", lambda acc: [1140.0, 1150.0, 1160.0])
     moe = {"held_slots_mean": 65000.0, "load_max_over_mean_p50": 1.3}
-    out = laguna_scopes.report(acc, steps, CFG, 16384, PEAKS, moe)
+    # the window's rate: a step of 16,384 positions in 1.2 s
+    out = model_scopes.report(acc, steps, CFG, 16384, PEAKS, moe, 16384 / 1.2)
     assert abs(out["steps_per_s"] - 1 / 1.2) < 1e-9 and abs(out["body_ms"] - 1150.0) < 1e-6
-    mfu = 100 * laguna_flops.step_flops(CFG, 2, 8192) / 197e12 / 1.15
-    assert abs(out["body_mfu_pct"] - mfu) < 1e-9 and 15 < mfu < 20
+    mfu = 100 * laguna_flops.step_flops(CFG, 2, 8192) / 197e12 / 1.2
+    assert abs(out["mfu_pct"] - mfu) < 1e-9 and 15 < mfu < 20
     assert out["body_ms_p50"] == 1150.0
     # a layer: 500 ms of full attention over 2 layers, 180 of window over 3
     assert abs(out["full_attn_ms"] - 250.0) < 1e-6
     assert abs(out["window_attn_ms"] - 60.0) < 1e-6
-    assert abs(out["window_over_full"] - 0.24) < 1e-9
     assert abs(out["moe_experts_ms"] - 120.0) < 1e-6  # dispatch + experts + combine
     assert out["full_attn_bound"] == out["window_attn_bound"] == "flops"
     assert out["moe_experts_bound"] == "bytes"
@@ -208,21 +213,23 @@ def test_laguna_scopes_reads_a_step_s_shares_from_an_account(monkeypatch):
     assert out["hybrid_pull_wait_ms_p50"] == 4.0
     assert out["scope_ms"]["ps.model.attn.gate"] == pytest.approx(10.0)
     # without the driver's counts the experts' roofline is left out, not guessed
-    bare = laguna_scopes.report(acc, steps, CFG, 16384, PEAKS)
+    bare = model_scopes.report(acc, steps, CFG, 16384, PEAKS)
     assert "moe_experts_roofline" not in bare and "moe_experts_ms" in bare
     # a program without these scopes (the parent) gives nothing, and no error
-    assert laguna_scopes.report(_account(), steps[:1], CFG, 16384, PEAKS) == {}
-    nothing = laguna_scopes.report(_account(), steps, CFG, 16384, PEAKS)
-    assert "body_mfu_pct" not in nothing and "window_attn_ms" not in nothing
-    assert "window_over_full" not in nothing
+    assert model_scopes.report(_account(), steps[:1], CFG, 16384, PEAKS) == {}
+    nothing = model_scopes.report(_account(), steps, CFG, 16384, PEAKS)
+    assert "mfu_pct" not in nothing and "window_attn_ms" not in nothing
 
 
 def test_a_share_over_100_is_an_error(monkeypatch):
     steps = [(1.2 * i, 1.2 * (i + 1), True) for i in range(10)]
     monkeypatch.setattr(model_scopes, "step_program_ms", lambda acc: [])
     acc = _account(**{"ps.model.laguna": 2.3, "ps.model.attn.window": 0.02})
-    with pytest.raises(ValueError, match="window_attn_roofline"):
-        laguna_scopes.report(acc, steps, CFG, 16384, PEAKS)
+    out = model_scopes.report(acc, steps, CFG, 16384, PEAKS)
+    (fail,) = model_scopes.above_100(out)
+    assert "window_attn_roofline" in fail
+    reader = cell_lib.load_module("layer_metrics", "window_attn_roofline")
+    assert reader.check(out["window_attn_roofline"]) == [fail]
 
 
 # -- what the comparison that decides ``correct`` refuses ----------------------------
@@ -352,11 +359,19 @@ def test_the_dry_run_is_correct_and_traced_reports_the_hybrid_spans(tmp_path):
     assert json.load(open(moe))["held_slots_mean"] > 0
     traced, err = dry(tmp_path, "--trace", "1")
     assert traced["correct"] is True, err[-3000:]
-    # the 22 metrics' readers find the worker's and the servers' spans here
+    # the readers find the worker's, the servers' and the trainer's spans
+    # and the driver's counts here; the device's scopes and shares only a
+    # chip's trace holds
     for name in ("pull_ms_p50", "grad_ms_p50", "push_ms_p50",
                  "worker_localize_ms_p50", "server_pull_busy_ms_p50",
-                 "server_push_busy_ms_p50", "compiles_in_window"):
+                 "server_push_busy_ms_p50", "compiles_in_window",
+                 "server_localize_ms_p50", "server_ack_ms_p50",
+                 "server_self_ms_p50", "worker_submit_ms_p50",
+                 "worker_combine_ms_p50", "worker_assemble_ms_p50",
+                 "hybrid_pull_wait_ms_p50", "moe_load_max_over_mean"):
         assert name in traced["metrics"], name
+    assert traced["metrics"]["turn_wait_ms_p50"]["value"] == 0.0  # no controller
+    assert "mfu_pct" not in traced["metrics"]  # no chip, no peak
     for span in ("ps.hybrid.step", "ps.hybrid.pull_wait", "ps.hybrid.push_submit",
                  "ps.hybrid.prefetch", "ps.hybrid.body_dispatch"):
         assert span in err, span

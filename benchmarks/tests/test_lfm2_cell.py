@@ -1,6 +1,6 @@
 """The cell ``lfm2_8b_a1b.pretrain8k``: its configuration file against the
-catalog row's keys, ``lfm2_flops`` against hand counts, ``lfm2_scopes`` on a
-made-up account, what the gradient check refuses, and the command's dry run
+catalog row's keys, ``lfm2_flops`` against hand counts, ``model_scopes`` on
+a made-up account, what the gradient check refuses, and the command's dry run
 (CPU, tiny sizes, float32: the reference comparison there holds to 1e-4 /
 1e-3)."""
 
@@ -13,7 +13,8 @@ import types
 import pytest
 
 from benchmarks.harness import cell as cell_lib
-from benchmarks.harness import lfm2_flops, lfm2_scopes, model_scopes
+from benchmarks.harness import lfm2_flops, model_scopes
+from benchmarks.harness.peaks import bounds_s
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
@@ -70,7 +71,12 @@ def test_the_cell_reports_all_four_end_to_end_metrics_on_one_chip():
     run = cell_lib.resolve(BENCH, CELL, seed=1, seconds=1.0, trace=0, dry_run=False)
     assert run.sizes == {"workers": 1, "servers": 2, "rows": 16384,
                          "batch": 16384, "cycle": 32, "warmup": 32}
-    assert len(cell_lib.layer_metrics_for(run)) == 22
+    # its own entries: the body's, with its two kernels (PR 39)
+    assert {m["name"] for m in cell_lib.layer_metrics_for(run) if "workloads" in m} == {
+        "mfu_pct", "body_ms_p50", "moe_experts_ms", "moe_experts_roofline",
+        "moe_load_max_over_mean", "hybrid_pull_wait_ms_p50",
+        "short_conv_ms", "short_conv_roofline", "gqa_attn_ms", "gqa_attn_roofline",
+    }
 
 
 # -- lfm2_flops against hand counts ------------------------------------------------
@@ -120,13 +126,13 @@ def test_kernel_operations_and_bytes_by_hand(what, flops, bytes_):
 def test_a_step_is_21_tflop_and_a_kernel_has_two_bounds():
     flops = lfm2_flops.step_flops(CFG, 2, 8192)
     assert abs(flops - 21.26e12) < 0.01e12
-    assert lfm2_flops.bounds_s({"flops": 197e12, "bytes": 819e9 / 2}, PEAKS) == {
+    assert bounds_s({"flops": 197e12, "bytes": 819e9 / 2}, PEAKS) == {
         "flops": 1.0, "bytes": 0.5
     }
     # the whole conv mixer is bound by its products, the experts at a
     # quarter of their share by their weights' bytes
-    conv = lfm2_flops.bounds_s(lfm2_flops.short_conv(CFG, 16384), PEAKS)
-    experts = lfm2_flops.bounds_s(lfm2_flops.moe_experts(CFG, 16384), PEAKS)
+    conv = bounds_s(lfm2_flops.short_conv(CFG, 16384), PEAKS)
+    experts = bounds_s(lfm2_flops.moe_experts(CFG, 16384), PEAKS)
     assert conv["flops"] > conv["bytes"] and experts["bytes"] > experts["flops"]
 
 
@@ -148,10 +154,11 @@ def test_lfm2_scopes_reads_a_step_s_shares_from_an_account(monkeypatch):
     })
     monkeypatch.setattr(model_scopes, "step_program_ms", lambda acc: [470.0, 480.0, 490.0])
     moe = {"held_slots_mean": 16000.0, "load_max_over_mean_p50": 1.4}
-    out = lfm2_scopes.report(acc, steps, CFG, 16384, PEAKS, moe)
+    # the window's rate: 2 steps of 16,384 positions a second
+    out = model_scopes.report(acc, steps, CFG, 16384, PEAKS, moe, 32768.0)
     assert out["steps_per_s"] == 2.0 and out["body_ms"] == 475.0
-    mfu = 100 * lfm2_flops.step_flops(CFG, 2, 8192) / 197e12 / 0.475
-    assert abs(out["body_mfu_pct"] - mfu) < 1e-9 and 20 < mfu < 25
+    mfu = 100 * lfm2_flops.step_flops(CFG, 2, 8192) * 2 / 197e12
+    assert abs(out["mfu_pct"] - mfu) < 1e-9 and 20 < mfu < 25
     assert out["body_ms_p50"] == 480.0
     assert out["short_conv_ms"] == 100.0  # proj + gate + out
     assert out["gqa_attn_ms"] == 150.0
@@ -163,20 +170,23 @@ def test_lfm2_scopes_reads_a_step_s_shares_from_an_account(monkeypatch):
     assert out["moe_load_max_over_mean"] == 1.4
     assert out["hybrid_pull_wait_ms_p50"] == 4.0
     # without the driver's counts the experts' roofline is left out, not guessed
-    bare = lfm2_scopes.report(acc, steps, CFG, 16384, PEAKS)
+    bare = model_scopes.report(acc, steps, CFG, 16384, PEAKS)
     assert "moe_experts_roofline" not in bare and "moe_experts_ms" in bare
     # a program without these scopes (the parent) gives nothing, and no error
-    assert lfm2_scopes.report(_account(), steps[:1], CFG, 16384, PEAKS) == {}
-    nothing = lfm2_scopes.report(_account(), steps, CFG, 16384, PEAKS)
-    assert "body_mfu_pct" not in nothing and "short_conv_ms" not in nothing
+    assert model_scopes.report(_account(), steps[:1], CFG, 16384, PEAKS) == {}
+    nothing = model_scopes.report(_account(), steps, CFG, 16384, PEAKS)
+    assert "mfu_pct" not in nothing and "short_conv_ms" not in nothing
 
 
 def test_a_share_over_100_is_an_error(monkeypatch):
     steps = [(0.5 * i, 0.5 * (i + 1), True) for i in range(10)]
     monkeypatch.setattr(model_scopes, "step_program_ms", lambda acc: [])
     acc = _account(**{"ps.model.lfm2": 1.9, "ps.model.gqa.attn": 0.02})
-    with pytest.raises(ValueError, match="gqa_attn_roofline"):
-        lfm2_scopes.report(acc, steps, CFG, 16384, PEAKS)
+    out = model_scopes.report(acc, steps, CFG, 16384, PEAKS)
+    (fail,) = model_scopes.above_100(out)
+    assert "gqa_attn_roofline" in fail
+    reader = cell_lib.load_module("layer_metrics", "gqa_attn_roofline")
+    assert reader.check(out["gqa_attn_roofline"]) == [fail]
 
 
 # -- what the comparison that decides ``correct`` refuses ----------------------------
@@ -318,11 +328,19 @@ def test_the_dry_run_is_correct_and_traced_reports_the_hybrid_spans(tmp_path):
     assert "'conv', 'dense'" in err and "'gqa', 'experts'" in err
     traced, err = dry(tmp_path, "--trace", "1")
     assert traced["correct"] is True, err[-3000:]
-    # the 22 metrics' readers find the worker's and the servers' spans here
+    # the readers find the worker's, the servers' and the trainer's spans
+    # and the driver's counts here; the device's scopes and shares only a
+    # chip's trace holds
     for name in ("pull_ms_p50", "grad_ms_p50", "push_ms_p50",
                  "worker_localize_ms_p50", "server_pull_busy_ms_p50",
-                 "server_push_busy_ms_p50", "compiles_in_window"):
+                 "server_push_busy_ms_p50", "compiles_in_window",
+                 "server_localize_ms_p50", "server_ack_ms_p50",
+                 "server_self_ms_p50", "worker_submit_ms_p50",
+                 "worker_combine_ms_p50", "worker_assemble_ms_p50",
+                 "hybrid_pull_wait_ms_p50", "moe_load_max_over_mean"):
         assert name in traced["metrics"], name
+    assert traced["metrics"]["turn_wait_ms_p50"]["value"] == 0.0  # no controller
+    assert "mfu_pct" not in traced["metrics"]  # no chip, no peak
     for span in ("ps.hybrid.step", "ps.hybrid.pull_wait", "ps.hybrid.push_submit",
                  "ps.hybrid.prefetch", "ps.hybrid.body_dispatch"):
         assert span in err, span
