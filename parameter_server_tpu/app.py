@@ -251,10 +251,12 @@ def _build_fm(cfg: AppConfig) -> Callable[[], dict]:
     return run
 
 
-def _run_hybrid(cfg: AppConfig, model_cfg) -> dict:
+def _run_hybrid(cfg: AppConfig, model_cfg, prepare=None) -> dict:
     """What the registered hybrid apps run: ``model_cfg``'s body under
     ``learner/hybrid.py::HybridLMTrainer``, its embedding table on
-    ``cfg.topology.num_servers`` ``KVServer``s over a loopback Van."""
+    ``cfg.topology.num_servers`` ``KVServer``s over a loopback Van.
+    ``prepare(tokens, rng)``, where given, turns a batch of tokens into what
+    the body trains on (a block-diffusion body: ``[xt ; x0]``)."""
     import numpy as np
 
     from parameter_server_tpu.core.postoffice import Postoffice
@@ -295,6 +297,8 @@ def _run_hybrid(cfg: AppConfig, model_cfg) -> dict:
         for _ in range(cfg.steps):
             base = rng.integers(0, model_cfg.vocab_size, size=(B, 1))
             tokens = (base + np.arange(S)[None]) % model_cfg.vocab_size
+            if prepare is not None:
+                tokens = prepare(tokens, rng)
             losses.append(trainer.step(tokens.astype(np.int32)))
         trainer.drain()
         out = {"losses": losses, "steps": cfg.steps}
@@ -374,6 +378,29 @@ def _build_laguna_hybrid(cfg: AppConfig) -> Callable[[], dict]:
 
         return _run_hybrid(cfg, laguna.tiny_config(
             vocab_size=min(cfg.data.key_space, 1 << 16),
+        ))
+
+    return run
+
+
+@register_app("sdar_hybrid")
+def _build_sdar_hybrid(cfg: AppConfig) -> Callable[[], dict]:
+    """The same hybrid path under a body trained by block diffusion
+    (``models/sdar_moe.py``: grouped-query attention over a masked copy of
+    every sequence beside its clean one, a held share of softmax-routed
+    experts in every layer): the clean tokens, kept off the mask row, are
+    noised into ``[xt ; x0]`` a step; tiny sizes by default so the app runs
+    anywhere; the benchmark's ``sdar_30b_a3b`` configuration runs the
+    published widths through the same trainer."""
+
+    def run() -> dict:
+        from parameter_server_tpu.models import sdar_moe
+
+        model_cfg = sdar_moe.tiny_config(
+            vocab_size=min(cfg.data.key_space, 1 << 16),
+        )
+        return _run_hybrid(cfg, model_cfg, lambda tokens, rng: sdar_moe.noise(
+            tokens % model_cfg.mask_id, rng, model_cfg
         ))
 
     return run

@@ -73,9 +73,12 @@ class HybridLMTrainer:
     of block), a ``KimiLinearConfig`` (a layer pattern of delta-rule and
     latent-attention mixers, dense and expert MLPs), an ``Lfm2MoeConfig``
     (gated short convolutions and grouped-query attention, dense and
-    bias-selected expert MLPs) or a ``LagunaConfig`` (window and full
+    bias-selected expert MLPs), a ``LagunaConfig`` (window and full
     attention layers with their own head counts and rotary tables, a gated
-    attention output, dense and expert MLPs with a shared expert).
+    attention output, dense and expert MLPs with a shared expert) or an
+    ``SdarMoeConfig`` (trained by block diffusion: the tokens are ``[xt ;
+    x0]``, a masked copy beside the clean sequence, and the body reads its
+    loss off them).
 
     **Buffers.**  A body may hold leaves that are state and not weights (an
     expert layer's selection bias): its config names them (``cfg.buffers``,

@@ -1,13 +1,16 @@
 """What the layer-pattern bodies of the hybrid path share
-(``models/kimi_linear.py``, ``models/lfm2_moe.py``): the held-share expert
-layer, the norm and the SwiGLU around it, the checkpointed loop over a
-body's layers, and the counters a step returns.
+(``models/kimi_linear.py``, ``models/lfm2_moe.py``, ``models/laguna.py``,
+``models/sdar_moe.py``): the held-share expert layer, the norm and the
+SwiGLU around it, the checkpointed loop over a body's layers, the counters a
+step returns, and the two losses: next-token (:func:`head_loss`) and
+masked, weighted and unshifted (:func:`weighted_loss`).
 
-**The expert layer.**  ``s = sigmoid(x' W_r)`` over all routed experts in
-float32 at the highest matrix precision; ``sel = top_k(s + bias)`` (the
-selection bias takes part in the selection only; a caller without one
-passes none); ``w_i = scale s_i / sum_{j in sel} s_j`` (without
-renormalisation ``scale s_i``); ``y = sum_{i in sel, held} w_i E_i(x')``
+**The expert layer.**  ``s = sigmoid(x' W_r)`` over all routed experts
+(where the body scores by a softmax, ``s = softmax(x' W_r)`` over all
+routed outputs) in float32 at the highest matrix precision; ``sel =
+top_k(s + bias)`` (the selection bias takes part in the selection only; a
+caller without one passes none); ``w_i = scale s_i / sum_{j in sel} s_j``
+(without renormalisation ``scale s_i``); ``y = sum_{i in sel, held} w_i E_i(x')``
 plus a shared expert where the parameters hold one.  **The held share**:
 this process holds experts ``[first, first + held)``; the router keeps
 every output; what the absent experts would add is left out, here and in
@@ -19,10 +22,18 @@ weights them and adds them back to their tokens (:func:`grouped_experts`),
 so the cost follows the load while the layout has room for the worst case
 (every slot of every token held here: index arrays only).  No slot may be
 dropped: ``moe_dropped_slots`` counts the held slots the layout gave no row
-(:func:`dispatch_layout`), and a caller must find it 0.
+(:func:`dispatch_layout`), and a caller must find it 0.  **A static
+capacity** (``capacity_factor`` c > 0): the loop runs at least the blocks of
+c times the share's expected slots (:func:`capacity_blocks`), as an expert
+exchange with static shapes computes its buffers whatever they hold; the
+blocks past the last group hold the zero row at weight 0 and change no
+result.  The work is then the capacity's until the load outgrows it; past
+it the loop runs the blocks that hold rows and drops nothing.  Such a layer
+counts ``moe_blocks_run``.
 
 What differs between the bodies is :class:`ExpertLayer` (experts a token,
-the scale, the share, the block, the body's device scope) and what the
+the scale, the share, the block, the router's scoring, the body's device
+scope) and what the
 layer's parameters hold (``shared``: a shared expert; ``expert_bias``: the
 selection bias, a buffer that takes no gradient and no update).
 
@@ -76,6 +87,13 @@ class ExpertLayer:
     scale: float
     renormalize: bool
     block: int  # rows of a block of the dispatch layout
+    #: the router's scores: ``sigmoid`` of each output, or ``softmax`` over
+    #: all ``n_routed`` outputs (so the held share routes over every expert)
+    scoring: str = "sigmoid"
+    #: the share's static capacity as a multiple of its expected slots
+    #: (``tokens x top_k x held / n_routed``); 0: none, the loop runs the
+    #: blocks that hold rows only
+    capacity_factor: float = 0.0
 
 
 # -- what every layer uses ------------------------------------------------------
@@ -234,6 +252,15 @@ def moe_capacity(layer: ExpertLayer, tokens: int) -> int:
     return -(-rows // bm) * bm
 
 
+def capacity_blocks(layer: ExpertLayer, tokens: int) -> int:
+    """Blocks the loop runs at least under a static capacity: ``c x tokens
+    x top_k x held / n_routed`` rows in whole blocks, no more than the
+    layout's (:func:`moe_capacity`)."""
+    rows = layer.capacity_factor * tokens * layer.top_k * layer.held / layer.n_routed
+    return min(-(-int(np.ceil(rows)) // layer.block),
+               moe_capacity(layer, tokens) // layer.block)
+
+
 def dispatch_layout(group, held: int, block: int, rows: int):
     """Where every token slot goes.  ``group [slots]``: the held expert a slot
     selected (``held``: none of them); ``rows``: rows of the layout, a whole
@@ -274,7 +301,11 @@ def route(layer: ExpertLayer, router_kernel, xt, bias=None):
     float32 at the highest matrix precision.  ``bias [n_routed]``, where a
     caller has one, is added to the scores for the selection alone: the
     weights are the selected experts' own scores."""
-    s = jax.nn.sigmoid(jnp.dot(xt, router_kernel, precision=HIGHEST))
+    logits = jnp.dot(xt, router_kernel, precision=HIGHEST)
+    if layer.scoring == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        s = jax.nn.sigmoid(logits)
     _top, idx = jax.lax.top_k(s if bias is None else s + bias, layer.top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if layer.renormalize:
@@ -302,6 +333,10 @@ def moe_layer(layer: ExpertLayer, p, x):
         token = jnp.where(filled, slot // k, N).astype(jnp.int32)
         weight = jnp.where(filled, w.reshape(-1)[slot], 0.0)
         xz = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])
+        if layer.capacity_factor:
+            # past the last group a block holds the zero row at weight 0
+            live = jnp.maximum(live, capacity_blocks(layer, N))
+            counters = dict(counters, moe_blocks_run=live)
     y = grouped_experts(
         layer.root, xz, p["experts"], weight.reshape(nb, bm),
         token.reshape(nb, bm), block_expert, live,
@@ -335,6 +370,7 @@ def trunk(layer: ExpertLayer, kinds, mixer_block, eps, per_sequence, params, x):
     x = x.astype(jnp.float32)
     zero = jnp.zeros((), jnp.int32)
     held = dropped = most = zero
+    run = None  # blocks the expert loops ran, where a layer counts them
     for i, (mixer, mlp) in enumerate(kinds):
         p = params[f"layer_{i}"]
         mix = jax.checkpoint(mixer_block(mixer))
@@ -352,8 +388,13 @@ def trunk(layer: ExpertLayer, kinds, mixer_block, eps, per_sequence, params, x):
             held = held + c["moe_held_slots"]
             dropped = dropped + c["moe_dropped_slots"]
             most = jnp.maximum(most, c["moe_max_expert_slots"])
+            if "moe_blocks_run" in c:
+                run = c["moe_blocks_run"] + (zero if run is None else run)
     hidden = rms_norm(x, params["final_norm"]["scale"], eps)
-    return hidden, dict(zip(COUNTERS, (held, dropped, most)))
+    counters = dict(zip(COUNTERS, (held, dropped, most)))
+    if run is not None:
+        counters["moe_blocks_run"] = run
+    return hidden, counters
 
 
 def hybrid_body(body, cfg, seed: int, loss_chunk: int):
@@ -386,3 +427,41 @@ def head_loss(root, hidden, head, targets, loss_chunk: int):
         if loss_chunk > 0:
             return tfm.chunked_causal_lm_loss(hidden, head, targets, loss_chunk)
         return tfm.causal_lm_loss(head_logits(hidden, head), targets)
+
+
+def weighted_loss(root, hidden, head, targets, weights, loss_chunk: int):
+    """``sum_i weights_i * -log p(targets_i | hidden_i)`` over the positions
+    of ``hidden [B, S, D]``: read at the position itself (no shift), over
+    the held vocabulary; a position of weight 0 adds nothing.  ``loss_chunk
+    > 0`` fuses the head into a loop over chunks of positions, each a
+    ``jax.checkpoint`` (``tfm.chunked_causal_lm_loss``'s form): no ``[B, S,
+    vocab]`` logits are live, and the backward computes a chunk's again."""
+
+    def nll(xc, tc, wc):
+        logp = jax.nn.log_softmax(jnp.einsum(
+            "bcd,dv->bcv", xc, head, preferred_element_type=jnp.float32
+        ))
+        return -jnp.sum(
+            wc * jnp.take_along_axis(logp, tc[..., None], axis=-1)[..., 0]
+        )
+
+    with scope(root, "head_loss"):
+        B, S, _ = hidden.shape
+        if loss_chunk <= 0:
+            return nll(hidden, targets, weights)
+        chunk = min(loss_chunk, S)
+        pad = (-S) % chunk
+        xs = (hidden, targets, weights)
+        if pad:  # the padding's weight is 0
+            xs = tuple(
+                jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                for x in xs
+            )
+        xs = tuple(
+            jnp.moveaxis(x.reshape(B, -1, chunk, *x.shape[2:]), 1, 0) for x in xs
+        )
+        part = jax.checkpoint(nll)
+        total, _ = jax.lax.scan(
+            lambda acc, x: (acc + part(*x), None), jnp.float32(0.0), xs
+        )
+        return total
