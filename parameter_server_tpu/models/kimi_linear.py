@@ -191,13 +191,8 @@ def tiny_config(**kw) -> KimiLinearConfig:
     return KimiLinearConfig(**defaults)
 
 
-#: attention runs its query blocks in at most this many bands (a band's
-#: blocks share one key prefix and one piece of code: ``ops/blocked_attention``)
-ATTN_BANDS = 4
-
-
 def schedule(cfg: KimiLinearConfig, batch: int, seq: int):
-    """``(by_sequence, kda_head_groups, attn_band)`` of a step of ``batch``
+    """``(by_sequence, kda_head_groups)`` of a step of ``batch``
     sequences of ``seq`` tokens: how it is cut so that one of a KDA mixer's
     float32 activations (``[batch, seq, heads, head size]``: it keeps a dozen
     of them) stays within ``cfg.live_elems``.  None changes a result.
@@ -208,11 +203,10 @@ def schedule(cfg: KimiLinearConfig, batch: int, seq: int):
       per-device batch needs);
     - ``kda_head_groups``: KDA runs its heads in that many groups, one after
       the other (a head shares nothing with another but the input and the sum
-      into ``W_o``): the smallest divisor of the heads that fits the budget;
-    - ``attn_band``: blocks of queries a band, for ``ATTN_BANDS`` bands.
+      into ``W_o``): the smallest divisor of the heads that fits the budget.
 
     At the published widths and 2 x 8,192 tokens: one sequence at a time, 4
-    groups of 8 heads (2^23 elements, 32 MiB an activation), bands of 8."""
+    groups of 8 heads (2^23 elements, 32 MiB an activation)."""
     H, K = cfg.linear_num_heads, cfg.linear_head_dim
     by_sequence = batch > 1 and batch * seq * H * K > cfg.live_elems
     rows = seq if by_sequence else batch * seq
@@ -220,8 +214,7 @@ def schedule(cfg: KimiLinearConfig, batch: int, seq: int):
         (g for g in range(1, H + 1)
          if H % g == 0 and rows * (H // g) * K <= cfg.live_elems), H
     )
-    blocks = -(-seq // cfg.attn_block)
-    return by_sequence, groups, -(-blocks // ATTN_BANDS)
+    return by_sequence, groups
 
 
 # -- parameters ---------------------------------------------------------------
@@ -368,7 +361,7 @@ def kda_mixer_grouped(cfg: KimiLinearConfig, G: int, p, x):
     return y
 
 
-def mla_mixer(cfg: KimiLinearConfig, band: int, p, x):
+def mla_mixer(cfg: KimiLinearConfig, p, x):
     dn, dr, C = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     with _scope("mla.proj"):
         q = jnp.einsum("bsd,dhk->bshk", x, p["q"]["kernel"])
@@ -379,7 +372,6 @@ def mla_mixer(cfg: KimiLinearConfig, band: int, p, x):
         # k_h = [k_nope_h, k_pe], k_pe shared by the heads and not rotated
         o = blocked_causal_attention(
             q[..., :dn], kv[..., :dn], kv[..., dn:], block=cfg.attn_block,
-            band=band,
             scale=1.0 / np.sqrt(dn + dr),
             q_shared=q[..., dn:], k_shared=kva[..., C:],
         )
@@ -418,22 +410,20 @@ def moe_layer(cfg: KimiLinearConfig, p, x):
     return moe.moe_layer(expert_layer(cfg), p, x)
 
 
-def _mixer_block(cfg, kind, cut, p, x):
-    """``cut``: KDA's head groups, or attention's band (:func:`schedule`)."""
+def _mixer_block(cfg, kind, groups, p, x):
+    """``groups``: KDA's head groups (:func:`schedule`)."""
     h = rms_norm(x, p["mixer_norm"]["scale"], cfg.rms_norm_eps)
-    return x + (kda_mixer_grouped if kind == "kda" else mla_mixer)(
-        cfg, cut, p[kind], h
-    )
+    if kind == "kda":
+        return x + kda_mixer_grouped(cfg, groups, p[kind], h)
+    return x + mla_mixer(cfg, p[kind], h)
 
 
 def trunk(cfg: KimiLinearConfig, params, x):
     """``x [B, S, D]`` input embeddings -> ``(hidden [B, S, D], counters)``."""
-    by_sequence, groups, band = schedule(cfg, x.shape[0], x.shape[1])
+    by_sequence, groups = schedule(cfg, x.shape[0], x.shape[1])
     return moe.trunk(
         expert_layer(cfg), cfg.layer_kinds(),
-        lambda mixer: functools.partial(
-            _mixer_block, cfg, mixer, groups if mixer == "kda" else band
-        ),
+        lambda mixer: functools.partial(_mixer_block, cfg, mixer, groups),
         cfg.rms_norm_eps, by_sequence, params, x,
     )
 

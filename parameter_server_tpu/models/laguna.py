@@ -116,9 +116,6 @@ BODY_SCOPE = "ps.model.laguna"
 MIXERS = {"full_attention": "full", "sliding_attention": "window"}
 #: ``mlp_layer_types`` -> ``models/moe.py``'s MLP kinds
 MLPS = {"dense": "dense", "sparse": "experts"}
-#: full attention runs its query blocks in at most this many bands
-ATTN_BANDS = 4
-
 #: device scope ``ps.model.<name>`` as a path under this body's
 _scope = functools.partial(moe.scope, BODY_SCOPE)
 
@@ -335,26 +332,19 @@ def expert_layer(cfg: LagunaConfig) -> moe.ExpertLayer:
     )
 
 
-def schedule(cfg: LagunaConfig, batch: int, seq: int):
-    """``(by_sequence, attn_band)`` of a step of ``batch`` sequences of
-    ``seq`` tokens: how it is cut so that a mixer's widest float32
+def schedule(cfg: LagunaConfig, batch: int, seq: int) -> bool:
+    """``by_sequence`` of a step of ``batch`` sequences of ``seq`` tokens:
+    whether the mixers and the dense MLP run one sequence at a time
+    (``models/moe.py::by_sequence``) so that a mixer's widest float32
     activation (the queries of the layer with the most heads, ``[batch, seq,
     heads x head_dim]``; the dense MLP's ``[batch, seq, intermediate]`` is
-    of its order) stays within ``cfg.live_elems``.  Neither changes a
-    result.
-
-    - ``by_sequence``: the mixers and the dense MLP run one sequence at a
-      time (``models/moe.py::by_sequence``);
-    - ``attn_band``: blocks of queries a band of a full layer, for
-      ``ATTN_BANDS`` bands (a window layer has no bands: one loop).
+    of its order) stays within ``cfg.live_elems``.  It changes no result.
 
     At the published widths and 2 x 8,192 tokens: one sequence at a time
     (a window layer's 64 x 128 = 8,192 a token: 2^26 elements, 256 MiB an
-    activation), bands of 8."""
+    activation)."""
     widest = max(cfg.layer_heads()) * cfg.head_dim
-    by_sequence = batch > 1 and batch * seq * widest > cfg.live_elems
-    blocks = -(-seq // cfg.attn_block)
-    return by_sequence, -(-blocks // ATTN_BANDS)
+    return batch > 1 and batch * seq * widest > cfg.live_elems
 
 
 # -- parameters ---------------------------------------------------------------
@@ -413,7 +403,7 @@ def _gated(o, gate_in):
         return o * jax.nn.sigmoid(gate_in)[..., None]
 
 
-def attn_mixer(cfg: LagunaConfig, kind: str, band: int, p, x):
+def attn_mixer(cfg: LagunaConfig, kind: str, p, x):
     """A layer's attention, ``kind`` ``full`` or ``window``: its head count
     is its ``q`` kernel's, its rotary table and key range its kind's."""
     B, S, _ = x.shape
@@ -435,7 +425,7 @@ def attn_mixer(cfg: LagunaConfig, kind: str, band: int, p, x):
     with _scope(f"attn.{kind}"):
         # no shared key part: empty slices, as the kernel's docstring asks
         o = blocked_causal_attention(
-            q, k, v, block=cfg.attn_block, band=band,
+            q, k, v, block=cfg.attn_block,
             scale=1.0 / np.sqrt(cfg.head_dim),
             q_shared=q[..., :0], k_shared=k[:, :, 0, :0],
             window=cfg.sliding_window if kind == "window" else None,
@@ -445,18 +435,17 @@ def attn_mixer(cfg: LagunaConfig, kind: str, band: int, p, x):
         return jnp.einsum("bshk,hkd->bsd", o, p["o"]["kernel"])
 
 
-def _mixer_block(cfg, kind, band, p, x):
+def _mixer_block(cfg, kind, p, x):
     h = rms_norm(x, p["mixer_norm"]["scale"], cfg.norm_eps)
-    return x + attn_mixer(cfg, kind, band, p["attn"], h)
+    return x + attn_mixer(cfg, kind, p["attn"], h)
 
 
 def trunk(cfg: LagunaConfig, params, x):
     """``x [B, S, D]`` input embeddings -> ``(hidden [B, S, D], counters)``."""
-    by_sequence, band = schedule(cfg, x.shape[0], x.shape[1])
     return moe.trunk(
         expert_layer(cfg), cfg.layer_kinds(),
-        lambda mixer: functools.partial(_mixer_block, cfg, mixer, band),
-        cfg.norm_eps, by_sequence, params, x,
+        lambda mixer: functools.partial(_mixer_block, cfg, mixer),
+        cfg.norm_eps, schedule(cfg, x.shape[0], x.shape[1]), params, x,
     )
 
 

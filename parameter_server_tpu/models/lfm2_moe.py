@@ -80,8 +80,6 @@ from parameter_server_tpu.ops.blocked_attention import blocked_causal_attention
 BODY_SCOPE = "ps.model.lfm2"
 #: ``layer_types`` -> this body's mixers
 MIXERS = {"conv": "conv", "full_attention": "gqa"}
-#: attention runs its query blocks in at most this many bands
-ATTN_BANDS = 4
 
 #: device scope ``ps.model.<name>`` as a path under this body's
 _scope = functools.partial(moe.scope, BODY_SCOPE)
@@ -210,22 +208,17 @@ def expert_layer(cfg: Lfm2MoeConfig) -> moe.ExpertLayer:
     )
 
 
-def schedule(cfg: Lfm2MoeConfig, batch: int, seq: int):
-    """``(by_sequence, attn_band)`` of a step of ``batch`` sequences of
-    ``seq`` tokens: how it is cut so that the conv mixer's widest float32
+def schedule(cfg: Lfm2MoeConfig, batch: int, seq: int) -> bool:
+    """``by_sequence`` of a step of ``batch`` sequences of ``seq`` tokens:
+    whether the mixers and the dense MLP run one sequence at a time
+    (``models/moe.py::by_sequence``) so that the conv mixer's widest float32
     activation (``[batch, seq, 3 x hidden]``; the dense MLP's ``[batch, seq,
-    intermediate]`` is of its order) stays within ``cfg.live_elems``.
-    Neither changes a result.
-
-    - ``by_sequence``: the mixers and the dense MLP run one sequence at a
-      time (``models/moe.py::by_sequence``);
-    - ``attn_band``: blocks of queries a band, for ``ATTN_BANDS`` bands.
+    intermediate]`` is of its order) stays within ``cfg.live_elems``.  It
+    changes no result.
 
     At the published widths and 2 x 8,192 tokens: one sequence at a time
-    (2^26 elements, 256 MiB an activation), bands of 8."""
-    by_sequence = batch > 1 and batch * seq * 3 * cfg.hidden_size > cfg.live_elems
-    blocks = -(-seq // cfg.attn_block)
-    return by_sequence, -(-blocks // ATTN_BANDS)
+    (2^26 elements, 256 MiB an activation)."""
+    return batch > 1 and batch * seq * 3 * cfg.hidden_size > cfg.live_elems
 
 
 # -- parameters ---------------------------------------------------------------
@@ -305,7 +298,7 @@ def conv_mixer(cfg: Lfm2MoeConfig, p, x):
         return y @ p["out_proj"]["kernel"]
 
 
-def gqa_mixer(cfg: Lfm2MoeConfig, band: int, p, x):
+def gqa_mixer(cfg: Lfm2MoeConfig, p, x):
     B, S, _ = x.shape
     with _scope("gqa.proj"):
         q, k, v = (
@@ -321,27 +314,26 @@ def gqa_mixer(cfg: Lfm2MoeConfig, band: int, p, x):
         )
     with _scope("gqa.attn"):
         o = blocked_causal_attention(
-            q, k, v, block=cfg.attn_block, band=band,
+            q, k, v, block=cfg.attn_block,
             scale=1.0 / np.sqrt(cfg.head_dim),
         )
     with _scope("gqa.proj"):
         return jnp.einsum("bshk,hkd->bsd", o, p["o"]["kernel"])
 
 
-def _mixer_block(cfg, kind, band, p, x):
+def _mixer_block(cfg, kind, p, x):
     h = rms_norm(x, p["mixer_norm"]["scale"], cfg.norm_eps)
     if kind == "conv":
         return x + conv_mixer(cfg, p["conv"], h)
-    return x + gqa_mixer(cfg, band, p["gqa"], h)
+    return x + gqa_mixer(cfg, p["gqa"], h)
 
 
 def trunk(cfg: Lfm2MoeConfig, params, x):
     """``x [B, S, D]`` input embeddings -> ``(hidden [B, S, D], counters)``."""
-    by_sequence, band = schedule(cfg, x.shape[0], x.shape[1])
     return moe.trunk(
         expert_layer(cfg), cfg.layer_kinds(),
-        lambda mixer: functools.partial(_mixer_block, cfg, mixer, band),
-        cfg.norm_eps, by_sequence, params, x,
+        lambda mixer: functools.partial(_mixer_block, cfg, mixer),
+        cfg.norm_eps, schedule(cfg, x.shape[0], x.shape[1]), params, x,
     )
 
 

@@ -1,35 +1,40 @@
-"""Causal softmax attention blocked over queries, in plain XLA operations.
+"""Causal softmax attention over tiles of queries and keys, in plain XLA
+operations.
 
-A block of ``block`` queries attends to the keys up to the last position of
-its band (a few blocks that share one key prefix) and no further: the scores
-of one block are ``[B, H, block, end]`` float32, and no ``[S, S]`` tensor of
-all heads is ever live (at 8,192 tokens and 32 heads that tensor is 8.6 GB a
-sequence).  The work is the causal half plus half a band's width of masked
-scores a block.
+**Tiles** (:func:`blocked_causal_attention`): one loop over the blocks of
+``block`` queries (``lax.scan``), and inside it one loop over the tiles of
+keys up to the block's own (``fori_loop``), each tile ``[block, width]``
+scored, masked from the tiles' traced positions and taken into a running
+softmax (maximum, sum and weighted values in float32, the online form of
+the blockwise recurrence).  No ``[S, S]`` tensor and no score block longer
+than a tile is ever live, and no key a block cannot see is scored outside
+the tiles that cross the diagonal: at 8,192 tokens and tiles of 256, 528
+tiles a pass where the causal half is 512.  The program is two loops a
+pass whatever the length.  A tile is a block of keys wide, two where the
+head is narrower than 128: its products are then half as deep, and a
+wider tile keeps a loop turn's work (at 32 heads of 64 over 8, 8 % less
+time for value and gradients on a v5e).
 
 **Grouped queries**: ``k`` and ``v`` may have fewer heads than ``q`` (``Hkv``
 key-value heads, each serving the ``G = H / Hkv`` query heads ``g G .. g G +
 G - 1``).  The queries are then read as ``[B, S, Hkv, G, D]`` and scored
 against their group's one key head; ``k`` and ``v`` are never copied out per
 query head, forward or backward (``dk`` and ``dv`` are summed over a group's
-query heads by the product that forms them).  With ``Hkv == H`` there is no
-group axis, and the program is the one it was before groups existed.
+query heads by the product that forms them).
 
 **A window** (``window``: a query sees itself and the ``window - 1`` keys
 before it).  The keys a block of ``block`` queries can see are ``[start -
 back, start + block)`` with ``back = ceil_to_block(window - 1)``: a slice
-whose width is the same for every block, so a window layer is **one loop**
-over all its blocks (``lax.scan``), each taking its slice of the keys at a
-traced offset, the keys outside **not computed** (at 8,192 tokens, blocks of
-256 and a window of 512: 3 key blocks a query block, 0.19 of the causal half
-with its band overhead); the keys are padded in front by ``back`` zero rows
-so that the first blocks' slices exist, and masked there.  The backward adds
-a block's ``dk`` and ``dv`` into the same slice of one accumulator for the
-sequence.  **No bands under a window**: a band is what a growing prefix
-needed (one piece of code for blocks whose key ranges differ in length); a
-window's slices are all one shape, and one loop's scores are live at a time
-by the loop's own order.  ``window=None`` and ``window >= S`` are the causal
-program above, as it was before windows existed.
+whose width is the same for every block, so a window layer is one loop over
+all its blocks (``lax.scan``), each scoring its slice of the keys at a
+traced offset in one block of scores, the keys outside not computed (at
+8,192 tokens, blocks of 256 and a window of 512: 768 keys a block of
+queries); the keys are padded in front by ``back`` zero rows so that the
+first blocks' slices exist, and masked there.  The backward adds a block's
+``dk`` and ``dv`` into the same slice of one accumulator for the sequence.
+Three tiles of 256 in a running softmax instead took 4.7 % longer at a
+window layer's shapes on a v5e.  ``window=None`` and ``window >= S`` are the
+causal program.
 
 **Block diffusion** (:func:`block_diffusion_attention`): the body runs on a
 noisy copy ``xt`` of a sequence beside its clean one ``x0``, ``2L``
@@ -38,32 +43,33 @@ block ``j`` sees the ``x0`` keys of blocks ``<= j``; a query of ``xt`` in
 block ``j`` sees the ``x0`` keys of blocks ``< j`` and the ``xt`` keys of
 block ``j`` in both directions, which lie in its own block of queries (``b``
 divides ``block``).  One loop over the blocks of queries takes both halves'
-block at once, and an inner loop over the ``x0`` key blocks up to its own
-carries a running softmax a half (the online form of the blockwise
-recurrence); the ``xt`` rows then take their own block's ``xt`` keys into
-the same softmax, one log-sum-exp.  No ``[2L, 2L]`` square is formed and no
-key a query cannot see is scored outside the diagonal tiles: ``L^2 + 2 L
+block at once, and an inner loop over the ``x0`` key tiles up to its own
+carries a running softmax a half; the ``xt`` rows then take their own
+block's ``xt`` keys into the same softmax, one log-sum-exp: ``L^2 + 2 L
 block`` pairs a head and sequence where ``L^2 + L b`` are needed, half of a
-causal pass over ``2L``.  **No bands here**: at 8 bands of 2 blocks the
-step of a six-layer body compiled for a v5e to a 773 MB program, three
-times the largest sibling's, where this loop's are the same code for every
-block (found by compiling the step for a described v5e).
+causal pass over ``2L``.  It shares the running softmax and a tile's
+backward with the causal loop, operation for operation what it ran before
+(``tests/test_sdar_moe.py`` holds its operations).
 
 A key may have a part all heads share (latent attention's positional part,
 ``k_shared [B, S, Dr]`` against ``q_shared [B, S, H, Dr]``): it is scored on
-its own, so it is never copied out per head.
+its own, so it is never copied out per head; the tiles score no part of
+width 0.
 
-**The backward is written out** (a ``custom_vjp``, the blockwise recurrence
-``ops/ring_attention.py`` uses): the forward keeps ``q, k, v``, the output
-and the log-sum-exp of every query; the backward recomputes one block's
-probabilities at a time and adds its part of ``dk`` and ``dv`` into one
-accumulator a band, the band's into one for the sequence.  Differentiating
-the blocked forward instead leaves one cotangent per block for every prefix
-``k[:, :end]`` and ``v[:, :end]`` it sliced, 8 GB of them at 32 blocks of
-8,192 tokens (found by compiling the step for the chip's memory, PR 28).
+**The backward is written out** (a ``custom_vjp``): the forward keeps ``q,
+k, v``, the output and the log-sum-exp of every query; the backward runs the
+same loops, recomputes one tile's (a window: one block's) probabilities at a
+time from the log-sum-exp, carries ``dq`` of its block of queries through
+the inner loop and adds the tile's ``dk`` and ``dv`` into one float32
+accumulator for the sequence (``dynamic_update_slice``).  Differentiating
+the forward instead keeps every tile's scores for the backward.
 
-Scores, softmax and log-sum-exp are float32; the products run at jax's
-default precision.
+**Nothing is stacked across a checkpoint**: every loop ties its block's
+index to the block's inputs (:func:`_tied`), so the masks, which depend on
+the index alone, are computed where they are used.
+
+Scores, softmax, log-sum-exp and the output are float32; the products run
+at jax's default precision.
 """
 
 from __future__ import annotations
@@ -96,50 +102,6 @@ def _g(q, k):
     return "g" if q.ndim > k.ndim else ""
 
 
-def _scores(start, scale, q, k, q_shared, k_shared, window=None, back=0):
-    """Masked, scaled scores ``[B, Hkv, (G,) bq, end]`` of the queries
-    ``start ..``.  Under a ``window`` the keys are the slice that starts
-    ``back`` before the queries (before position 0 for the first blocks:
-    masked), and a query sees the ``window`` keys that end with its own."""
-    g = _g(q, k)
-    s = jnp.einsum(
-        f"bqh{g}d,bkhd->bh{g}qk", q, k, preferred_element_type=jnp.float32
-    ) + jnp.einsum(
-        f"bqh{g}d,bkd->bh{g}qk", q_shared, k_shared,
-        preferred_element_type=jnp.float32,
-    )
-    q_ids = start + jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
-    k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
-    if window is None:
-        seen = k_ids <= q_ids
-    else:
-        k_ids = k_ids + (start - back)
-        seen = (k_ids <= q_ids) & (k_ids > q_ids - window) & (k_ids >= 0)
-    return jnp.where(seen, s * scale, -jnp.inf)
-
-
-def _after(x, done):
-    """The arrays ``x``, not to be computed before ``done`` is: the bands share
-    no data, and a scheduler free to run them side by side keeps every
-    band's scores live at once (4 GB at 32 blocks, found by compiling for
-    the chip's memory, PR 28)."""
-    return x if done is None else jax.lax.optimization_barrier((x, done))[0]
-
-
-def _bands(S, block, band):
-    """``[(first query, queries, keys), ...]``: a band is ``band`` blocks of
-    ``block`` queries that share one key prefix, the one its last query
-    needs.  One loop (``lax.scan``) a band: a band is one piece of code
-    whatever its blocks, where a block of its own length each was 96 pieces
-    at 32 blocks (a 420 MB program that took 12 minutes to compile on the
-    chip's host, PR 28); the price is the masked scores between a block's
-    own prefix and its band's (a sixteenth more at 8 bands of 4)."""
-    padded = -(-S // block) * block
-    width = band * block
-    return [(a, min(width, padded - a), min(a + width, S))
-            for a in range(0, padded, width)]
-
-
 def _pad_queries(x, padded):
     extra = padded - x.shape[1]
     if not extra:
@@ -159,6 +121,270 @@ def _join(x):
     return x.reshape(x.shape[0], x.shape[1] * x.shape[2], *x.shape[3:])
 
 
+def _per_query(x):
+    """``[B, bq, Hkv, (G)]`` -> ``[B, Hkv, (G,) bq, 1]``, beside the scores."""
+    return jnp.moveaxis(x, 1, -1)[..., None]
+
+
+def _to_rows(x):
+    """``[B, Hkv, (G,) bq, 1]`` -> ``[B, bq, Hkv, (G,) 1]``, beside the
+    output rows."""
+    return jnp.moveaxis(x[..., 0], -1, 1)[..., None]
+
+
+def _tied(x):
+    """A block's index and its arrays, the index not to be had without the
+    arrays.  Where a checkpointed layer runs one sequence at a time (a
+    ``lax.map`` of a ``jax.checkpoint``: ``models/moe.py::by_sequence``),
+    what depends on no input of the layer (the index, so the mask, so the
+    mask broadcast to the scores' shape) is split off into a loop of its own
+    that runs in the forward pass and keeps its outputs stacked over all
+    blocks for the backward pass: 1.9 GB a window layer at 64 heads and
+    8,192 tokens, 1.7 GB a full layer at 48 (found by compiling the step for
+    the chip's memory; ``tests/test_tpu_compile.py`` holds it).  Tied to the
+    queries, they are computed where they are used."""
+    return jax.lax.optimization_barrier(tuple(x))
+
+
+def _slices(xs, start, width):
+    return tuple(
+        jax.lax.dynamic_slice_in_dim(x, start, width, axis=1) for x in xs
+    )
+
+
+def _lse(lse, H):
+    """A scan's ``[n, B, Hkv, (G,) block]`` log-sum-exp -> ``[B, H, n block]``."""
+    return jnp.moveaxis(_ungrouped(_join(jnp.moveaxis(lse, -1, 2)), H), 1, 2)
+
+
+# -- the running softmax over tiles, and a tile's backward ------------------------
+#: a running maximum before any key was seen (``exp(-inf - _FLOOR)`` is 0, and
+#: a row whose keys so far were all masked keeps a zero sum, not a NaN)
+_FLOOR = -1e30
+
+
+def _empty(q, dv):
+    """The running softmax of the rows of ``q [B, bq, Hkv, (G,) D]`` before
+    any key: ``(max, sum, weighted values)``."""
+    stat = (q.shape[0], *q.shape[2:-1], q.shape[1], 1)
+    return (jnp.full(stat, _FLOOR, jnp.float32), jnp.zeros(stat, jnp.float32),
+            jnp.zeros((*q.shape[:-1], dv), jnp.float32))
+
+
+def _online(g, state, s, v):
+    """The running softmax after one more tile of masked scores ``s`` and
+    the values ``v`` they weigh."""
+    m, l, acc = state  # noqa: E741
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    return m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True), (
+        acc * _to_rows(alpha) + jnp.einsum(
+            f"bh{g}qk,bkhd->bqh{g}d", p, v, preferred_element_type=jnp.float32
+        )
+    )
+
+
+def _finish(state):
+    """A block of rows' output and log-sum-exp: every real row saw a key
+    (causal: its own; block diffusion: the first ``x0`` key, or its own
+    ``xt`` key in the first diffusion block), so its sum is positive."""
+    m, l, acc = state  # noqa: E741
+    return acc / _to_rows(l), (m + jnp.log(l))[..., 0]
+
+
+def _tile_grads(g, scale, s, lse, delta, q, do, k, v, shared=()):
+    """One tile of scores' part of the backward from the recomputed masked
+    scores ``s``, its queries' log-sum-exp and ``delta`` (``[B, bq, Hkv,
+    (G)]``), queries ``q``, ``d_out`` and the keys ``k``, ``v`` it saw;
+    ``shared``: ``(q_shared, k_shared)`` of a shared key part, or empty.
+    Returns ``((dq, *dq_shared), (dk, dv, *dk_shared))``."""
+    f32 = jnp.float32
+    p = jnp.exp(s - _per_query(lse))
+    # dk, dv: the product sums over a group's query heads
+    dv = jnp.einsum(f"bh{g}qk,bqh{g}d->bkhd", p, do, preferred_element_type=f32)
+    dp = jnp.einsum(f"bqh{g}d,bkhd->bh{g}qk", do, v, preferred_element_type=f32)
+    ds = p * (dp - _per_query(delta)) * scale
+    dq = jnp.einsum(f"bh{g}qk,bkhd->bqh{g}d", ds, k, preferred_element_type=f32)
+    dk = jnp.einsum(f"bh{g}qk,bqh{g}d->bkhd", ds, q, preferred_element_type=f32)
+    if not shared:
+        return (dq,), (dk, dv)
+    qs, ks = shared
+    return (dq, jnp.einsum(f"bh{g}qk,bkd->bqh{g}d", ds, ks, preferred_element_type=f32)), (
+        dk, dv, jnp.einsum(f"bh{g}qk,bqh{g}d->bkd", ds, qs, preferred_element_type=f32)
+    )
+
+
+# -- causal: a loop over blocks of queries, one over the key tiles up to its own ---
+def _width(block, head):
+    """Keys a tile: a block's, two blocks' where the ``head`` is narrower
+    than the 128 a product contracts at full depth."""
+    return block if head >= 128 else 2 * block
+
+
+def _tile(scale, q_start, k_start, q, k, shared):
+    """Masked, scaled scores ``[B, Hkv, (G,) bq, bk]`` of the queries from
+    ``q_start`` against the keys from ``k_start``: a query sees the keys up
+    to its own; ``shared``: ``(q_shared, k_shared)`` scored beside, or
+    empty."""
+    g = _g(q, k)
+    s = jnp.einsum(
+        f"bqh{g}d,bkhd->bh{g}qk", q, k, preferred_element_type=jnp.float32
+    )
+    if shared:
+        s = s + jnp.einsum(
+            f"bqh{g}d,bkd->bh{g}qk", *shared, preferred_element_type=jnp.float32
+        )
+    q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
+    k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
+    return jnp.where(k_ids <= q_ids, s * scale, -jnp.inf)
+
+
+def _key_tiles(i, block, width):
+    """The tiles of ``width`` keys that the block ``i`` of ``block`` queries
+    sees: ``0 ..`` the one that holds its last query."""
+    return (i * block + block - 1) // width + 1
+
+
+def _keys(width, S, k, v, k_shared):
+    """``(k, v[, k_shared])`` padded behind to whole tiles of ``width``,
+    the shared part only where it has a width."""
+    keys = (k, v, k_shared) if k_shared.shape[-1] else (k, v)
+    padded = -(-S // width) * width
+    return tuple(_pad_queries(x, padded) for x in keys)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _attention(block, scale, q, k, v, q_shared, k_shared):
+    return _forward(block, scale, q, k, v, q_shared, k_shared)[0]
+
+
+def _forward(block, scale, q, k, v, q_shared, k_shared):
+    S, H, dv = q.shape[1], q.shape[2], v.shape[-1]
+    width = _width(block, q.shape[-1])
+    padded = -(-S // block) * block
+    q, q_shared = (
+        _grouped(_pad_queries(x, padded), k.shape[2]) for x in (q, q_shared)
+    )
+    g = _g(q, k)
+    keys = _keys(width, S, k, v, k_shared)
+
+    def one(_, x):
+        i, qb, qsb = _tied(x)
+
+        def key_tile(j, state):
+            kb, vb, *ksb = _slices(keys, j * width, width)
+            s = _tile(scale, i * block, j * width, qb, kb,
+                      (qsb, *ksb) if ksb else ())
+            return _online(g, state, s, vb)
+
+        return None, _finish(jax.lax.fori_loop(
+            0, _key_tiles(i, block, width), key_tile, _empty(qb, dv)
+        ))
+
+    _, (out, lse) = jax.lax.scan(
+        one, None,
+        (jnp.arange(padded // block), _split(q, block), _split(q_shared, block)),
+    )
+    return _ungrouped(_join(out), H)[:, :S], _lse(lse, H)[:, :, :S]
+
+
+def _fwd(block, scale, q, k, v, q_shared, k_shared):
+    out, lse = _forward(block, scale, q, k, v, q_shared, k_shared)
+    return out, (q, k, v, q_shared, k_shared, out, lse)
+
+
+def _padded_for_bwd(block, res, d_out):
+    """What a backward loop reads per block of queries, padded to whole
+    blocks and grouped: ``(q, q_shared, d_out, delta, lse)``."""
+    q, k, _v, q_shared, _k_shared, out, lse = res
+    S = q.shape[1]
+    padded = -(-S // block) * block
+    # rowsum(dO * O) = rowsum(P * dP): the softmax's own term
+    delta = jnp.sum(d_out * out, axis=-1)  # [B, S, H]
+    q, q_shared, d_out, delta = (
+        _pad_queries(x, padded) for x in (q, q_shared, d_out, delta)
+    )
+    # a query of the padding has no row of its own: its probabilities are 0
+    lse = _pad_queries(jnp.moveaxis(lse, 1, 2), padded)  # [B, S, H]
+    if padded > S:
+        lse = lse.at[:, S:].set(jnp.inf)
+    return tuple(
+        _grouped(x, k.shape[2]) for x in (q, q_shared, d_out, delta, lse)
+    )
+
+
+def _bwd(block, scale, res, d_out):
+    q_in, k, v, qs_in, k_shared, out, _lse_in = res
+    S, H = out.shape[1:3]
+    width = _width(block, q_in.shape[-1])
+    xs = _padded_for_bwd(block, res, d_out)
+    g = _g(xs[0], k)
+    keys = _keys(width, S, k, v, k_shared)
+    f32 = jnp.float32
+
+    def one(acc, x):
+        i, qb, qsb, dob, dlb, lsb = _tied(x)
+
+        def key_tile(j, carry):
+            acc, dq = carry
+            at = j * width
+            kb, vb, *ksb = _slices(keys, at, width)
+            shared = (qsb, *ksb) if ksb else ()
+            dq_t, dk_t = _tile_grads(
+                g, scale, _tile(scale, i * block, at, qb, kb, shared),
+                lsb, dlb, qb, dob, kb, vb, shared,
+            )
+            # the tile's dk, dv are added into the keys it saw
+            acc = tuple(
+                jax.lax.dynamic_update_slice_in_dim(a, b + t, at, axis=1)
+                for a, b, t in zip(acc, _slices(acc, at, width), dk_t)
+            )
+            return acc, tuple(a + t for a, t in zip(dq, dq_t))
+
+        zeros = tuple(
+            jnp.zeros(x.shape, f32) for x in ((qb, qsb) if len(keys) > 2 else (qb,))
+        )
+        return jax.lax.fori_loop(
+            0, _key_tiles(i, block, width), key_tile, (acc, zeros)
+        )
+
+    acc, dq = jax.lax.scan(
+        one, tuple(jnp.zeros(x.shape, f32) for x in keys),
+        (jnp.arange(xs[0].shape[1] // block), *(_split(x, block) for x in xs)),
+    )
+    dq = tuple(_ungrouped(_join(x), H)[:, :S] for x in dq)
+    dk, dv, *dks = (x[:, :S] for x in acc)
+    return (
+        dq[0].astype(q_in.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+        dq[1].astype(qs_in.dtype) if dks else jnp.zeros_like(qs_in),
+        dks[0].astype(k_shared.dtype) if dks else jnp.zeros_like(k_shared),
+    )
+
+
+_attention.defvjp(_fwd, _bwd)
+
+
+# -- under a window: one loop over all blocks, a slice of the keys each ----------
+def _scores(start, scale, q, k, q_shared, k_shared, window, back):
+    """Masked, scaled scores ``[B, Hkv, (G,) bq, end]`` of the queries
+    ``start ..`` against the slice of the keys that starts ``back`` before
+    them (before position 0 for the first blocks: masked): a query sees the
+    ``window`` keys that end with its own."""
+    g = _g(q, k)
+    s = jnp.einsum(
+        f"bqh{g}d,bkhd->bh{g}qk", q, k, preferred_element_type=jnp.float32
+    ) + jnp.einsum(
+        f"bqh{g}d,bkd->bh{g}qk", q_shared, k_shared,
+        preferred_element_type=jnp.float32,
+    )
+    q_ids = start + jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
+    k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
+    k_ids = k_ids + (start - back)
+    seen = (k_ids <= q_ids) & (k_ids > q_ids - window) & (k_ids >= 0)
+    return jnp.where(seen, s * scale, -jnp.inf)
+
+
 def _block_out(g, s, vb):
     """A block's output and the log-sum-exp of its queries from its masked
     scores ``s`` and the values ``vb`` they weigh."""
@@ -170,11 +396,6 @@ def _block_out(g, s, vb):
         preferred_element_type=jnp.float32,
     )
     return out, (m + jnp.log(l))[..., 0]
-
-
-def _per_query(x):
-    """``[B, bq, Hkv, (G)]`` -> ``[B, Hkv, (G,) bq, 1]``, beside the scores."""
-    return jnp.moveaxis(x, 1, -1)[..., None]
 
 
 def _block_grads(g, scale, s, acc, x, keys):
@@ -211,104 +432,6 @@ def _block_grads(g, scale, s, acc, x, keys):
     return (dkb, dvb, dksb), (dqb, dqsb)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _attention(block, band, scale, q, k, v, q_shared, k_shared):
-    return _forward(block, band, scale, q, k, v, q_shared, k_shared)[0]
-
-
-def _forward(block, band, scale, q, k, v, q_shared, k_shared):
-    S, H = q.shape[1:3]
-    padded = -(-S // block) * block
-    q, q_shared = (
-        _grouped(_pad_queries(x, padded), k.shape[2]) for x in (q, q_shared)
-    )
-    g = _g(q, k)
-    outs, lses = [], []
-    for a, n, end in _bands(S, block, band):
-        qa, qsa = _after(
-            (q[:, a:a + n], q_shared[:, a:a + n]), outs[-1] if outs else None
-        )
-        kb, vb, ksb = k[:, :end], v[:, :end], k_shared[:, :end]
-
-        def one(_, x, a=a, kb=kb, vb=vb, ksb=ksb):
-            i, qb, qsb = x
-            s = _scores(a + i * block, scale, qb, kb, qsb, ksb)
-            return None, _block_out(g, s, vb)
-
-        _, (out, lse) = jax.lax.scan(
-            one, None, (jnp.arange(n // block), _split(qa, block), _split(qsa, block))
-        )
-        outs.append(_ungrouped(_join(out), H))
-        lses.append(jnp.moveaxis(
-            _ungrouped(_join(jnp.moveaxis(lse, -1, 2)), H), 1, 2
-        ))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    lse = lses[0] if len(lses) == 1 else jnp.concatenate(lses, axis=2)
-    return out[:, :S], lse[:, :, :S]
-
-
-def _fwd(block, band, scale, q, k, v, q_shared, k_shared):
-    out, lse = _forward(block, band, scale, q, k, v, q_shared, k_shared)
-    return out, (q, k, v, q_shared, k_shared, out, lse)
-
-
-def _padded_for_bwd(block, res, d_out):
-    """What a backward loop reads per block of queries, padded to whole
-    blocks and grouped: ``(q, q_shared, d_out, delta, lse)``."""
-    q, k, _v, q_shared, _k_shared, out, lse = res
-    S = q.shape[1]
-    padded = -(-S // block) * block
-    # rowsum(dO * O) = rowsum(P * dP): the softmax's own term
-    delta = jnp.sum(d_out * out, axis=-1)  # [B, S, H]
-    q, q_shared, d_out, delta = (
-        _pad_queries(x, padded) for x in (q, q_shared, d_out, delta)
-    )
-    # a query of the padding has no row of its own: its probabilities are 0
-    lse = _pad_queries(jnp.moveaxis(lse, 1, 2), padded)  # [B, S, H]
-    if padded > S:
-        lse = lse.at[:, S:].set(jnp.inf)
-    return tuple(
-        _grouped(x, k.shape[2]) for x in (q, q_shared, d_out, delta, lse)
-    )
-
-
-def _bwd(block, band, scale, res, d_out):
-    _q, k, v, _q_shared, k_shared, out, _lse = res
-    S = out.shape[1]
-    q, q_shared, d_out, delta, lse = _padded_for_bwd(block, res, d_out)
-    g = _g(q, k)
-    dk, dv, dks = jnp.zeros_like(k), jnp.zeros_like(v), jnp.zeros_like(k_shared)
-    dq, dqs = [], []
-    for a, n, end in _bands(S, block, band):
-        xs = _after(
-            tuple(x[:, a:a + n] for x in (q, q_shared, d_out, delta, lse)),
-            (dq[-1], dqs[-1], dk, dv, dks) if dq else None,
-        )
-        kb, vb, ksb = k[:, :end], v[:, :end], k_shared[:, :end]
-
-        def one(carry, x, a=a, kb=kb, vb=vb, ksb=ksb):
-            i, *xb = x
-            s = _scores(a + i * block, scale, xb[0], kb, xb[1], ksb)
-            return _block_grads(g, scale, s, carry, xb, (kb, vb, ksb))
-
-        (dkb, dvb, dksb), (dqa, dqsa) = jax.lax.scan(
-            one, (jnp.zeros_like(kb), jnp.zeros_like(vb), jnp.zeros_like(ksb)),
-            (jnp.arange(n // block), *(_split(x, block) for x in xs)),
-        )
-        dk, dv, dks = (
-            acc.at[:, :end].add(part)
-            for acc, part in ((dk, dkb), (dv, dvb), (dks, dksb))
-        )
-        dq.append(_ungrouped(_join(dqa), out.shape[2]))
-        dqs.append(_ungrouped(_join(dqsa), out.shape[2]))
-    cat = lambda xs: (xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=1))[:, :S]  # noqa: E731
-    return cat(dq), dk, dv, cat(dqs), dks
-
-
-_attention.defvjp(_fwd, _bwd)
-
-
-# -- under a window: one loop over all blocks, a slice of the keys each ----------
 def _window_keys(block, window, S, k, v, k_shared):
     """``(back, width, (k, v, k_shared) padded)``: a block of queries at
     ``start`` sees the ``width`` rows from ``start`` of the keys padded in
@@ -319,29 +442,6 @@ def _window_keys(block, window, S, k, v, k_shared):
         x, ((0, 0), (back, padded - S)) + ((0, 0),) * (x.ndim - 2)
     )
     return back, back + block, tuple(pad(x) for x in (k, v, k_shared))
-
-
-def _tied(x):
-    """A block's index and its arrays, the index not to be had without the
-    arrays.  Where a checkpointed layer runs one sequence at a time (a
-    ``lax.map`` of a ``jax.checkpoint``: ``models/moe.py::by_sequence``),
-    what depends on no input of the layer (the index, so the mask, so the
-    mask broadcast to the scores' shape; the scores of a shared part of
-    width 0) is split off into a loop of its own that runs in the forward
-    pass and keeps its outputs stacked over all blocks for the backward
-    pass: 1.9 GB a window layer at 64 heads and 8,192 tokens (found by
-    compiling the step for the chip's memory, PR 35;
-    ``tests/test_tpu_compile.py`` holds it).  Tied to the queries, they are
-    computed where they are used.  The causal loops above are as they were,
-    stacks and all (1.7 GB of masks at 48 heads): they are other cells'
-    programs too."""
-    return jax.lax.optimization_barrier(tuple(x))
-
-
-def _slices(xs, start, width):
-    return tuple(
-        jax.lax.dynamic_slice_in_dim(x, start, width, axis=1) for x in xs
-    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
@@ -368,9 +468,7 @@ def _window_forward(block, window, scale, q, k, v, q_shared, k_shared):
         one, None,
         (jnp.arange(padded // block), _split(q, block), _split(q_shared, block)),
     )
-    out = _ungrouped(_join(out), H)
-    lse = jnp.moveaxis(_ungrouped(_join(jnp.moveaxis(lse, -1, 2)), H), 1, 2)
-    return out[:, :S], lse[:, :, :S]
+    return _ungrouped(_join(out), H)[:, :S], _lse(lse, H)[:, :, :S]
 
 
 def _window_fwd(block, window, scale, q, k, v, q_shared, k_shared):
@@ -412,25 +510,21 @@ _window_attention.defvjp(_window_fwd, _window_bwd)
 
 
 def blocked_causal_attention(q, k, v, *, block: int, scale: float,
-                             band: int = 4, q_shared=None, k_shared=None,
-                             window=None):
+                             q_shared=None, k_shared=None, window=None):
     """``q [B, S, H, D]``, ``k [B, S, Hkv, D]``, ``v [B, S, Hkv, Dv]`` with
-    ``H % Hkv == 0`` -> ``[B, S, H, Dv]`` float32.  ``band``: blocks of
-    ``block`` queries that share a key prefix.  ``window``: a query sees
-    itself and the ``window - 1`` keys before it (None, or at least ``S``:
-    every key up to its own, the causal program with its bands).
+    ``H % Hkv == 0`` -> ``[B, S, H, Dv]`` float32.  ``block``: queries a
+    block (a causal tile's keys: one block, two under a head narrower than
+    128).  ``window``: a query sees itself and the ``window - 1`` keys
+    before it (None, or at least ``S``: every key up to its own, the causal
+    tiles).
 
     ``q_shared`` / ``k_shared``: the part all heads share, or None.  A caller
     without one whose layer is a ``jax.checkpoint`` run one sequence at a time
-    (``models/moe.py::by_sequence``) hands over the empty slices
-    ``q[..., :0]`` and ``k[:, :, 0, :0]`` instead of None: the zeros made
-    below depend on no input of the layer, so the causal loops' scores of 0
-    are computed in the forward pass for every block of a band and kept
-    stacked for the backward pass (0.8 GB a band at 48 heads, :func:`_tied`;
-    found by compiling the step for the chip's memory, PR 35).  The window's
-    loop ties them itself; tying the causal loops here, so that no caller
-    need know, moves three cells' programs and waits for its own measurement
-    (``PERF.md`` section 7)."""
+    (``models/moe.py::by_sequence``) hands a window over the empty slices
+    ``q[..., :0]`` and ``k[:, :, 0, :0]`` instead of None: the window's slice
+    loop scores a shared part of width 0 as zeros made below, which depend
+    on no input of the layer (:func:`_tied`).  The causal tiles score no
+    part of width 0, and take None or empty slices alike."""
     B, S, H, _ = q.shape
     if k.shape[2] != v.shape[2] or H % k.shape[2]:
         raise ValueError(
@@ -438,22 +532,17 @@ def blocked_causal_attention(q, k, v, *, block: int, scale: float,
         )
     if window is not None and window < 1:
         raise ValueError(f"a window of {window} keys")
-    if q_shared is None:  # a shared part of width 0 scores 0
+    if q_shared is None:  # a shared part of width 0
         q_shared = jnp.zeros((B, S, H, 0), q.dtype)
         k_shared = jnp.zeros((B, S, 0), k.dtype)
     if window is None or window >= S:
-        return _attention(block, band, float(scale), q, k, v, q_shared, k_shared)
+        return _attention(block, float(scale), q, k, v, q_shared, k_shared)
     return _window_attention(
         block, int(window), float(scale), q, k, v, q_shared, k_shared
     )
 
 
 # -- block diffusion: a noisy copy beside the clean sequence ----------------------
-#: a running maximum before any key was seen (``exp(-inf - _FLOOR)`` is 0, and
-#: a row whose keys so far were all masked keeps a zero sum, not a NaN)
-_FLOOR = -1e30
-
-
 def _bd_tile(scale, diffusion, L, q_start, k_start, q, k, rule):
     """Masked, scaled scores ``[B, Hkv, (G,) bq, bk]`` of the block of
     queries from ``q_start`` against the block of keys from ``k_start`` (a
@@ -476,62 +565,6 @@ def _bd_tile(scale, diffusion, L, q_start, k_start, q, k, rule):
     else:
         seen = k_blk == q_blk
     return jnp.where(seen & (k_ids < L), s * scale, -jnp.inf)
-
-
-def _to_rows(x):
-    """``[B, Hkv, (G,) bq, 1]`` -> ``[B, bq, Hkv, (G,) 1]``, beside the
-    output rows."""
-    return jnp.moveaxis(x[..., 0], -1, 1)[..., None]
-
-
-def _bd_empty(q, dv):
-    """The running softmax of the rows of ``q [B, bq, Hkv, (G,) D]`` before
-    any key: ``(max, sum, weighted values)``."""
-    stat = (q.shape[0], *q.shape[2:-1], q.shape[1], 1)
-    return (jnp.full(stat, _FLOOR, jnp.float32), jnp.zeros(stat, jnp.float32),
-            jnp.zeros((*q.shape[:-1], dv), jnp.float32))
-
-
-def _bd_online(g, state, s, v):
-    """The running softmax after one more block of masked scores ``s`` and
-    the values ``v`` they weigh."""
-    m, l, acc = state  # noqa: E741
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m - m_new)
-    return m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True), (
-        acc * _to_rows(alpha) + jnp.einsum(
-            f"bh{g}qk,bkhd->bqh{g}d", p, v, preferred_element_type=jnp.float32
-        )
-    )
-
-
-def _bd_finish(state):
-    """A block of rows' output and log-sum-exp: every real row saw a key
-    (the first ``x0`` key, or its own ``xt`` key in the first diffusion
-    block), so its sum is positive."""
-    m, l, acc = state  # noqa: E741
-    return acc / _to_rows(l), (m + jnp.log(l))[..., 0]
-
-
-def _bd_grads(g, scale, s, lse, delta, q, do, k, v):
-    """One tile of scores' part of the backward: ``(dq, dk, dv)`` from the
-    recomputed masked scores ``s``, its queries' log-sum-exp and ``delta``
-    (``[B, bq, Hkv, (G)]``), queries ``q``, ``d_out`` and the keys ``k``,
-    ``v`` it saw."""
-    f32 = jnp.float32
-    p = jnp.exp(s - _per_query(lse))
-    dv = jnp.einsum(f"bh{g}qk,bqh{g}d->bkhd", p, do, preferred_element_type=f32)
-    dp = jnp.einsum(f"bqh{g}d,bkhd->bh{g}qk", do, v, preferred_element_type=f32)
-    ds = p * (dp - _per_query(delta)) * scale
-    dq = jnp.einsum(f"bh{g}qk,bkhd->bqh{g}d", ds, k, preferred_element_type=f32)
-    dk = jnp.einsum(f"bh{g}qk,bqh{g}d->bkhd", ds, q, preferred_element_type=f32)
-    return dq, dk, dv
-
-
-def _bd_lse(lse, H):
-    """A scan's ``[n, B, Hkv, (G,) block]`` log-sum-exp -> ``[B, H, n block]``."""
-    return jnp.moveaxis(_ungrouped(_join(jnp.moveaxis(lse, -1, 2)), H), 1, 2)
 
 
 def _bd_halves(block, q, k, v):
@@ -563,17 +596,17 @@ def _bd_forward(diffusion, block, scale, q, k, v):
             clean, noisy = states
             kb, vb = _slices((k0, v0), j * block, block)
             tile = functools.partial(_bd_tile, scale, diffusion, L, start, j * block)
-            return (_bd_online(g, clean, tile(q0b, kb, "clean"), vb),
-                    _bd_online(g, noisy, tile(qtb, kb, "prefix"), vb))
+            return (_online(g, clean, tile(q0b, kb, "clean"), vb),
+                    _online(g, noisy, tile(qtb, kb, "prefix"), vb))
 
         # the x0 keys of the blocks up to this one, then the xt keys of its own
         clean, noisy = jax.lax.fori_loop(
-            0, i + 1, key_block, (_bd_empty(q0b, dv), _bd_empty(qtb, dv))
+            0, i + 1, key_block, (_empty(q0b, dv), _empty(qtb, dv))
         )
-        noisy = _bd_online(g, noisy, _bd_tile(
+        noisy = _online(g, noisy, _bd_tile(
             scale, diffusion, L, start, start, qtb, ktb, "own"
         ), vtb)
-        return None, (_bd_finish(noisy), _bd_finish(clean))
+        return None, (_finish(noisy), _finish(clean))
 
     _, parts = jax.lax.scan(
         one, None,
@@ -582,7 +615,7 @@ def _bd_forward(diffusion, block, scale, q, k, v):
     out = jnp.concatenate(
         [_ungrouped(_join(o), H)[:, :L] for o, _ in parts], axis=1
     ).astype(q.dtype)
-    lse = jnp.concatenate([_bd_lse(s, H)[:, :, :L] for _, s in parts], axis=2)
+    lse = jnp.concatenate([_lse(s, H)[:, :, :L] for _, s in parts], axis=2)
     return out, lse
 
 
@@ -625,10 +658,10 @@ def _bd_bwd(diffusion, block, scale, res, d_out):
             at = j * block
             kb, vb = _slices((k0, v0), at, block)
             tile = functools.partial(_bd_tile, scale, diffusion, L, start, at)
-            dq_c, dk_c, dv_c = _bd_grads(
+            (dq_c,), (dk_c, dv_c) = _tile_grads(
                 g, scale, tile(q0b, kb, "clean"), ls0b, dl0b, q0b, do0b, kb, vb
             )
-            dq_p, dk_p, dv_p = _bd_grads(
+            (dq_p,), (dk_p, dv_p) = _tile_grads(
                 g, scale, tile(qtb, kb, "prefix"), lstb, dltb, qtb, dotb, kb, vb
             )
             # the x0 keys take both halves' parts
@@ -640,7 +673,7 @@ def _bd_bwd(diffusion, block, scale, res, d_out):
 
         zeros = jnp.zeros(q0b.shape, f32)
         acc, dq0b, dqtb = jax.lax.fori_loop(0, i + 1, key_block, (acc, zeros, zeros))
-        dq_o, dktb, dvtb = _bd_grads(
+        (dq_o,), (dktb, dvtb) = _tile_grads(
             g, scale, _bd_tile(scale, diffusion, L, start, start, qtb, ktb, "own"),
             lstb, dltb, qtb, dotb, ktb, vtb,
         )

@@ -60,7 +60,7 @@ def dense(window, q, k, v, qs=None, kshared=None):
 
 def blocked(window, block, q, k, v, qs=None, kshared=None):
     return blocked_causal_attention(
-        q, k, v, block=block, band=2, scale=SCALE, q_shared=qs,
+        q, k, v, block=block, scale=SCALE, q_shared=qs,
         k_shared=kshared, window=window,
     )
 
@@ -124,9 +124,22 @@ def _operations(jaxpr, depth=0, out=None):
     return out
 
 
-def causal_operations(H, Hkv, D, Dv, Dr):
+#: the attention shapes of the cells that run the causal tiles: ``(H, Hkv, D,
+#: Dv, Dr)``, and whether the body hands over empty shared slices
+CAUSAL_CELLS = {
+    # latent attention: 32 heads, no groups, a shared positional key part
+    "kimi_linear_a3b": ((32, 32, 128, 128, 64), False),
+    # grouped queries: 32 heads of 64 over 8 key heads, no shared part
+    "lfm2_8b_a1b": ((32, 8, 64, 64, 0), False),
+    # a full layer: 48 heads of 128 over 8, empty shared slices
+    "laguna_xs2": ((48, 8, 128, 128, 0), True),
+}
+
+
+def causal_operations(H, Hkv, D, Dv, Dr, empty=False):
     """The operations of value and gradients without a window, one sequence
-    of 8,192 tokens, blocks of 256 in bands of 8."""
+    of 8,192 tokens, blocks of 256; ``empty``: the shared part handed over
+    as the empty slices ``q[..., :0]``, ``k[:, :, 0, :0]``."""
     S = 8192
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     args = [sds(1, S, H, D), sds(1, S, Hkv, D), sds(1, S, Hkv, Dv)]
@@ -134,8 +147,10 @@ def causal_operations(H, Hkv, D, Dv, Dr):
         args += [sds(1, S, H, Dr), sds(1, S, Dr)]
 
     def f(q, k, v, qs=None, ks=None):
+        if empty:
+            qs, ks = q[..., :0], k[:, :, 0, :0]
         return jnp.sum(blocked_causal_attention(
-            q, k, v, block=256, band=8, scale=0.125, q_shared=qs, k_shared=ks,
+            q, k, v, block=256, scale=0.125, q_shared=qs, k_shared=ks,
         ))
 
     nums = tuple(range(len(args)))
@@ -143,19 +158,17 @@ def causal_operations(H, Hkv, D, Dv, Dr):
 
 
 @pytest.mark.parametrize("cell,H,Hkv,D,Dv,Dr", [
-    # latent attention: 32 heads, no groups, a shared positional key part
-    ("kimi_linear_a3b", 32, 32, 128, 128, 64),
-    # grouped queries: 32 heads over 8 key heads, no shared part
-    ("lfm2_8b_a1b", 32, 8, 64, 64, 0),
+    (cell, *shape) for cell, (shape, empty) in CAUSAL_CELLS.items() if not empty
 ])
 def test_the_causal_program_of_a_cell_is_what_it_was(cell, H, Hkv, D, Dv, Dr):
     """Without a window, at the attention shapes of the two cells that had
     this kernel before windows existed, value and gradients trace to the
-    operations they traced to at the commit before PR 35
-    (``tests/data/blocked_causal_ops.<cell>.txt``, written there by
+    operations of the causal tiles (``tests/data/blocked_causal_ops.<cell>.txt``,
+    re-taken from the change that replaced the banded rows by tiles, by
     ``python tests/test_blocked_window.py``; a failure shows the lines that
-    differ).  A change to the causal path moves those cells: if it is meant,
-    measure them and write the files again."""
+    differ; laguna's list: ``tests/test_sdar_moe.py``).  A change to the
+    causal path moves those cells: if it is meant, measure them and write
+    the files again."""
     with open(os.path.join(DATA, f"blocked_causal_ops.{cell}.txt")) as f:
         want = f.read().splitlines()
     assert causal_operations(H, Hkv, D, Dv, Dr) == want
@@ -185,9 +198,55 @@ def _scored(jaxpr, block, turns=1, out=None):
             # in, it holds HEADS x block x keys numbers
             if block in shape and contracted == HEAD:
                 out.append((turns, int(np.prod(shape)) // (HEADS * block)))
+        if name == "while":  # counted turn by turn by the scan around it
+            continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
             _scored(sub, block, turns * eqn.params.get("length", 1), out)
+        if name == "scan":
+            for i in range(eqn.params["length"]):
+                for trips, body in _inner_loops(eqn.params["jaxpr"], i):
+                    _scored(body, block, turns * trips, out)
     return out
+
+
+def _inner_loops(closed, i):
+    """``[(turns, body)]`` of the first ``while`` loop (a ``fori_loop`` with
+    traced bounds) in a scan's body at the scan's turn ``i``, or ``[]``: the
+    loop's bounds, read off the carry it starts from (``fori_loop``'s
+    ``(lower, upper, ...)``), evaluated from the body's operations that lead
+    to them with ``i`` for the body's index (its one integer scalar input)
+    and zeros for its other inputs."""
+    from jax.extend.core import Literal
+
+    jaxpr = closed.jaxpr
+    at = next(
+        (n for n, e in enumerate(jaxpr.eqns) if e.primitive.name == "while"), None
+    )
+    if at is None:
+        return []
+    loop = jaxpr.eqns[at]
+    first = loop.params["cond_nconsts"] + loop.params["body_nconsts"]
+    bounds = loop.invars[first:first + 2]
+    # the operations the bounds depend on, in order
+    needed, leading = {v for v in bounds if not isinstance(v, Literal)}, []
+    for eqn in reversed(jaxpr.eqns[:at]):
+        if needed & set(eqn.outvars):
+            leading.insert(0, eqn)
+            needed |= {v for v in eqn.invars if not isinstance(v, Literal)}
+    env = dict(zip(jaxpr.constvars, closed.consts))
+    for var in jaxpr.invars:
+        aval = var.aval
+        env[var] = (
+            np.int32(i) if aval.shape == () and aval.dtype == jnp.int32
+            else np.zeros(aval.shape, aval.dtype)
+        )
+    read = lambda v: v.val if isinstance(v, Literal) else env[v]  # noqa: E731
+    for eqn in leading:
+        subfuns, params = eqn.primitive.get_bind_params(eqn.params)
+        outs = eqn.primitive.bind(*subfuns, *map(read, eqn.invars), **params)
+        env.update(zip(eqn.outvars, outs if eqn.primitive.multiple_results else [outs]))
+    lower, upper = (int(read(v)) for v in bounds)
+    return [(upper - lower, loop.params["body_jaxpr"].jaxpr)]
 
 
 HEAD, HEADS = 128, 64  # a window layer's published heads, one sequence
@@ -199,14 +258,16 @@ def test_a_block_of_queries_is_scored_against_three_key_blocks(passes):
     shapes of a window layer, one sequence): every block of queries is
     scored against 768 keys, 3 key blocks, in the forward and in the
     written-out backward (which recomputes the scores and forms ``dP``), and
-    against the causal program's count that is under 0.19."""
+    against the causal tiles' count that is under 0.19: 528 tiles of 256 a
+    pass (32 x 33 / 2), read from the loops' bounds, where the banded rows
+    before the tiles scored 640 blocks' worth."""
     S, block, window = 8192, 256, 512
     q = jax.ShapeDtypeStruct((1, S, HEADS, HEAD), jnp.float32)
     kv = jax.ShapeDtypeStruct((1, S, 8, HEAD), jnp.float32)
 
     def attn(w):
         f = lambda q, k, v: blocked_causal_attention(  # noqa: E731
-            q, k, v, block=block, band=8, scale=0.1, window=w
+            q, k, v, block=block, scale=0.1, window=w
         )
         if passes == "forward":
             return f
@@ -218,8 +279,9 @@ def test_a_block_of_queries_is_scored_against_three_key_blocks(passes):
     assert sum(t for t, _k in win) == per_pass * (S // block)
     causal = _scored(jax.make_jaxpr(attn(None))(q, kv, kv).jaxpr, block)
     blocks = lambda found: sum(t * keys for t, keys in found) // block  # noqa: E731
-    # 32 query blocks x 3 key blocks against 8 x (8 + 16 + 24 + 32)
-    assert blocks(win) == per_pass * 96 and blocks(causal) == per_pass * 640
+    # 32 query blocks x 3 key blocks against 1 + 2 + ... + 32 tiles
+    assert {keys for _t, keys in causal} == {block}
+    assert blocks(win) == per_pass * 96 and blocks(causal) == per_pass * 528
     assert blocks(win) / blocks(causal) < 0.19
     # no [S, S] tensor and nothing stacked over the blocks but their outputs
     biggest = max(
@@ -237,8 +299,41 @@ def _all_eqns(jaxpr):
             yield from _all_eqns(sub)
 
 
-if __name__ == "__main__":  # the files of the test above, from the checkout on the path
-    for cell, *shape in (("kimi_linear_a3b", 32, 32, 128, 128, 64),
-                         ("lfm2_8b_a1b", 32, 8, 64, 64, 0)):
+@pytest.mark.parametrize("passes", ["forward", "backward"])
+def test_no_causal_intermediate_is_larger_than_the_queries(passes):
+    """At a full layer's published shapes (one sequence of 8,192, 48 heads
+    of 128 over 8, blocks of 256), forward and written-out backward: every
+    block of scores is one ``[256, 256]`` tile, and no value the program
+    forms is larger than the queries' own ``[S, 48, 128]`` (a block of
+    queries scored against the whole prefix, ``[48, 256, 8,192]``, is 1.5
+    times that; an ``[S, S]`` block 1,365 times)."""
+    S, H, block = 8192, 48, 256
+    q = jax.ShapeDtypeStruct((1, S, H, HEAD), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, S, 8, HEAD), jnp.float32)
+
+    def attn(q, k, v):
+        return blocked_causal_attention(q, k, v, block=block, scale=0.1)
+
+    f = attn if passes == "forward" else jax.grad(
+        lambda *a: jnp.sum(attn(*a)), argnums=(0, 1, 2)
+    )
+    jaxpr = jax.make_jaxpr(f)(q, kv, kv).jaxpr
+    tiles = {  # the scores and dP, the head summed over: pairs a head
+        int(np.prod(eqn.outvars[0].aval.shape)) // H for eqn in _all_eqns(jaxpr)
+        if eqn.primitive.name == "dot_general" and eqn.invars[0].aval.shape[
+            eqn.params["dimension_numbers"][0][0][0]
+        ] == HEAD
+    }
+    assert tiles == {block * block}
+    biggest = max(
+        int(np.prod(v.aval.shape))
+        for eqn in _all_eqns(jaxpr)
+        for v in eqn.outvars if hasattr(v.aval, "shape")
+    )
+    assert biggest <= H * S * HEAD
+
+
+if __name__ == "__main__":  # the causal lists, from the checkout on the path
+    for cell, (shape, empty) in CAUSAL_CELLS.items():
         with open(os.path.join(DATA, f"blocked_causal_ops.{cell}.txt"), "w") as f:
-            f.write("\n".join(causal_operations(*shape)) + "\n")
+            f.write("\n".join(causal_operations(*shape, empty=empty)) + "\n")

@@ -51,7 +51,7 @@ def test_a_mixer_is_its_reference(kind):
     with jax.default_matmul_precision("highest"):
         got = (
             km.kda_mixer(cfg, p, emb) if kind == "kda"
-            else km.mla_mixer(cfg, 2, p, emb)  # two blocks a band
+            else km.mla_mixer(cfg, p, emb)  # three blocks of 16 queries
         )
         want = jnp.stack([getattr(ref, kind)(sz, p, emb[b]) for b in range(2)])
     assert rel(got, want) < OUT
@@ -148,18 +148,19 @@ def test_a_slot_that_finds_no_row_is_counted(rows, dropped):
 
 def test_the_schedule_follows_from_the_shapes():
     """At the published widths and the cell's 2 x 8,192 tokens: one sequence
-    at a time, KDA's 32 heads in 4 groups, attention's 32 blocks in bands of
-    8; a tiny body is not cut; one budget moves all of it."""
+    at a time, KDA's 32 heads in 4 groups; a tiny body is not cut; one budget
+    moves all of it (attention's tiles follow from its shapes: nothing to
+    cut)."""
     pub = json.load(open(os.path.join(ROOT, "benchmarks/configs/kimi_linear_a3b.json")))
     cfg = km.KimiLinearConfig.from_published(
         pub, n_layers=5, experts_held=8, vocab_size=20480
     )
-    assert km.schedule(cfg, 2, 8192) == (True, 4, 8)
-    assert km.schedule(cfg, 1, 8192) == (False, 4, 8)
-    assert km.schedule(cfg, 2, 1024) == (False, 1, 1)
+    assert km.schedule(cfg, 2, 8192) == (True, 4)
+    assert km.schedule(cfg, 1, 8192) == (False, 4)
+    assert km.schedule(cfg, 2, 1024) == (False, 1)
     tiny = km.tiny_config()
-    assert km.schedule(tiny, 2, 64) == (False, 1, 1)
-    assert km.schedule(km.tiny_config(live_elems=1024), 2, 64) == (True, 2, 1)
+    assert km.schedule(tiny, 2, 64) == (False, 1)
+    assert km.schedule(km.tiny_config(live_elems=1024), 2, 64) == (True, 2)
     assert km.moe_capacity(cfg, 16384) == 131072 + 4096
 
 
@@ -176,7 +177,7 @@ PERIOD = dict(n_layers=5, kda_layers=(1, 2, 3, 5), full_attn_layers=(4,),
 def test_loss_and_gradients_are_the_reference_s(how, B, live, cut):
     cfg = km.tiny_config(**PERIOD, live_elems=live)
     params, emb, tok = setup(cfg, B=B)
-    assert km.schedule(cfg, *emb.shape[:2])[:2] == cut
+    assert km.schedule(cfg, *emb.shape[:2]) == cut
     sz = ref.sizes_of(cfg)
     with jax.default_matmul_precision("highest"):
         (loss, counters), (gp, ge) = jax.jit(jax.value_and_grad(

@@ -205,9 +205,12 @@ def test_eight_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
 # -- the whole body ------------------------------------------------------------------
 @pytest.mark.parametrize("how,B,live,block,cut", [
     # S 40: a window layer's widest activation is 6 x 16 x 40 = 3,840 a sequence
-    ("plain", 2, 1 << 26, 8, (False, 2)),
-    ("by_sequence", 2, 3840, 8, (True, 2)),
-    ("one_band_one_block_windows", 1, 1 << 26, 16, (False, 1)),
+    ("plain", 2, 1 << 26, 8, False),
+    ("by_sequence", 2, 3840, 8, True),
+    # the name is from when a full layer's blocks shared key prefixes in
+    # bands: now three blocks of 16 queries in tiles of 32 keys (heads of
+    # 16); a window layer's block sees a slice of 32 keys, one block back
+    ("one_band_one_block_windows", 1, 1 << 26, 16, False),
 ])
 def test_loss_and_gradients_are_the_reference_s(how, B, live, block, cut):
     cfg = lg.tiny_config(live_elems=live, attn_block=block)
@@ -340,15 +343,16 @@ def test_the_published_shapes_hold_33_4_b_whole_and_665_9_m_cut():
 def test_the_schedule_follows_from_the_shapes():
     """At the published widths and the cell's 2 x 8,192 tokens: one sequence
     at a time (a window layer's 64 heads of 128 are the widest), a full
-    layer's 32 blocks in bands of 8; a tiny body is not cut."""
+    layer's 32 blocks of queries in tiles that follow from the shapes; a
+    tiny body is not cut."""
     pub = json.load(open(PUBLISHED))
     cfg = lg.LagunaConfig.from_published(
         pub, n_layers=5, experts_held=32, vocab_size=12544
     )
-    assert lg.schedule(cfg, 2, 8192) == (True, 8)
-    assert lg.schedule(cfg, 1, 8192) == (False, 8)
-    assert lg.schedule(cfg, 2, 1024) == (False, 1)
-    assert lg.schedule(lg.tiny_config(), 2, 64) == (False, 2)
+    assert lg.schedule(cfg, 2, 8192) is True
+    assert lg.schedule(cfg, 1, 8192) is False
+    assert lg.schedule(cfg, 2, 1024) is False
+    assert lg.schedule(lg.tiny_config(), 2, 64) is False
     # 16,384 x 8 slots a layer laid out for the worst case in blocks of 512
     assert moe.moe_capacity(lg.expert_layer(cfg), 16384) == 131072 + 32 * 512
 

@@ -77,24 +77,30 @@ def test_a_mixer_is_its_reference(kind):
     with jax.default_matmul_precision("highest"):
         got = (
             lm.conv_mixer(cfg, p, emb) if kind == "conv"
-            else lm.gqa_mixer(cfg, 2, p, emb)  # two blocks a band
+            else lm.gqa_mixer(cfg, p, emb)  # three blocks of 16 queries
         )
         want = jnp.stack([getattr(ref, kind)(sz, p, emb[b]) for b in range(2)])
     assert rel(got, want) < OUT
 
 
-@pytest.mark.parametrize("H,Hkv,shared", [
-    (32, 8, False),  # the published heads
-    (8, 2, True),  # groups and a shared key part together
-    (4, 4, True),  # no groups: what it was
-    (4, 1, False),  # one key head for all
+@pytest.mark.parametrize("H,Hkv,shared,D", [
+    pytest.param(32, 8, False, 8, id="32-8-False"),  # the published heads
+    # groups and a shared key part together
+    pytest.param(8, 2, True, 8, id="8-2-True"),
+    pytest.param(4, 4, True, 8, id="4-4-True"),  # no groups: what it was
+    pytest.param(4, 1, False, 8, id="4-1-False"),  # one key head for all
+    # heads of 128: tiles of one block of keys, not two
+    pytest.param(8, 2, False, 128, id="8-2-False-head128"),
+    pytest.param(6, 3, True, 128, id="6-3-True-head128"),
 ])
-def test_blocked_attention_with_fewer_key_heads_is_dense_attention(H, Hkv, shared):
+def test_blocked_attention_with_fewer_key_heads_is_dense_attention(H, Hkv, shared, D):
     """Value, ``dq``, ``dk``, ``dv`` (and the shared part's) against dense
     attention with ``k`` and ``v`` repeated for every query head of their
     group: ``dk`` and ``dv`` are then summed over the group by the repeat's
-    own derivative."""
-    B, S, D, Dv, Dr = 2, 45, 8, 5, 4
+    own derivative.  45 tokens in blocks of 16: the last block is short,
+    and a tile of keys is two blocks wide under heads of 8, one under heads
+    of 128."""
+    B, S, Dv, Dr = 2, 45, 5, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     q = jax.random.normal(ks[0], (B, S, H, D))
     k = jax.random.normal(ks[1], (B, S, Hkv, D))
@@ -113,7 +119,7 @@ def test_blocked_attention_with_fewer_key_heads_is_dense_attention(H, Hkv, share
 
     def blocked(q, k, v, qs, kshared):
         return blocked_causal_attention(
-            q, k, v, block=16, band=2, scale=scale, q_shared=qs, k_shared=kshared
+            q, k, v, block=16, scale=scale, q_shared=qs, k_shared=kshared
         )
 
     args = (q, k, v, qs, kshared)
@@ -209,9 +215,11 @@ def test_kimi_s_route_is_what_it_was():
 # -- the whole body ------------------------------------------------------------------
 @pytest.mark.parametrize("how,B,live,block,cut", [
     # S 40, hidden 64: the conv mixer's widest activation is 7,680 a sequence
-    ("plain", 2, 1 << 26, 16, (False, 1)),
-    ("by_sequence", 2, 7680, 16, (True, 1)),
-    ("bands_of_two", 1, 1 << 26, 4, (False, 3)),
+    ("plain", 2, 1 << 26, 16, False),
+    ("by_sequence", 2, 7680, 16, True),
+    # the name is from when blocks shared key prefixes in bands: now ten
+    # blocks of 4 queries in tiles of 8 keys (heads of 16), 30 tiles a pass
+    ("bands_of_two", 1, 1 << 26, 4, False),
 ])
 def test_loss_and_gradients_are_the_reference_s(how, B, live, block, cut):
     cfg = lm.tiny_config(**PERIOD, live_elems=live, attn_block=block)
@@ -293,15 +301,16 @@ def test_the_published_shapes_hold_8_34_b_whole_and_507_8_m_cut():
 
 def test_the_schedule_follows_from_the_shapes():
     """At the published widths and the cell's 2 x 8,192 tokens: one sequence
-    at a time, attention's 32 blocks in bands of 8; a tiny body is not cut."""
+    at a time (attention's tiles follow from its shapes: nothing to cut); a
+    tiny body is not cut."""
     pub = json.load(open(PUBLISHED))
     cfg = lm.Lfm2MoeConfig.from_published(
         pub, n_layers=5, layers_first=1, experts_held=8, vocab_size=16384
     )
-    assert lm.schedule(cfg, 2, 8192) == (True, 8)
-    assert lm.schedule(cfg, 1, 8192) == (False, 8)
-    assert lm.schedule(cfg, 2, 1024) == (False, 1)
-    assert lm.schedule(lm.tiny_config(), 2, 64) == (False, 1)
+    assert lm.schedule(cfg, 2, 8192) is True
+    assert lm.schedule(cfg, 1, 8192) is False
+    assert lm.schedule(cfg, 2, 1024) is False
+    assert lm.schedule(lm.tiny_config(), 2, 64) is False
     assert moe.moe_capacity(lm.expert_layer(cfg), 16384) == 65536 + 4096
 
 

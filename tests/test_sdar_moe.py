@@ -192,9 +192,11 @@ def test_the_sigmoid_router_is_the_program_it_was(bias):
 def test_laguna_s_attention_programs_are_what_they_were(kind, H, window):
     """A window layer's and a full layer's value and gradients at Laguna's
     published shapes (one sequence of 8,192, 8 key heads of 128, empty
-    shared slices as the body passes them) trace to the operations of the
-    parent of the block-diffusion loops (``tests/data``; kimi's and lfm2's
-    causal programs: ``tests/test_blocked_window.py``)."""
+    shared slices as the body passes them) trace to the stored operations
+    (``tests/data``): the window's slice loop as at the parent of the
+    block-diffusion loops, the full layer's causal tiles as re-taken from
+    the change that replaced the banded rows by tiles (``python
+    tests/test_blocked_window.py`` writes the three cells' causal lists)."""
     from test_blocked_window import _operations
 
     S = 8192
@@ -202,7 +204,7 @@ def test_laguna_s_attention_programs_are_what_they_were(kind, H, window):
 
     def f(q, k, v):
         return jnp.sum(ba.blocked_causal_attention(
-            q, k, v, block=256, band=8, scale=0.125, window=window,
+            q, k, v, block=256, scale=0.125, window=window,
             q_shared=q[..., :0], k_shared=k[:, :, 0, :0],
         ))
 
@@ -211,6 +213,37 @@ def test_laguna_s_attention_programs_are_what_they_were(kind, H, window):
     ).jaxpr)
     with open(os.path.join(DATA, f"blocked_{kind}_ops.laguna_xs2.txt")) as fh:
         assert got == fh.read().splitlines()
+
+
+def block_diffusion_operations():
+    """The operations of value and gradients of block-diffusion attention at
+    the cell's attention shape: one sequence of 2 x 4,096 positions, 32
+    query heads over 4 key heads of 128, diffusion blocks of 4, tiles of
+    256."""
+    from test_blocked_window import _operations
+
+    L, H, Hkv, D = 4096, 32, 4, 128
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+
+    def f(q, k, v):
+        return jnp.sum(ba.block_diffusion_attention(
+            q, k, v, diffusion_block=4, block=256, scale=D ** -0.5
+        ))
+
+    return _operations(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(
+        sds(1, 2 * L, H, D), sds(1, 2 * L, Hkv, D), sds(1, 2 * L, Hkv, D)
+    ).jaxpr)
+
+
+def test_block_diffusion_s_program_is_what_it_was():
+    """The block-diffusion loop shares its running-softmax helpers with the
+    causal loop of the same module, and its program is held to the
+    operations it traced to before they were shared
+    (``tests/data/block_diffusion_ops.sdar_30b_a3b.txt``, written by
+    ``python tests/test_sdar_moe.py`` from the checkout before the causal
+    loop was tiled; a failure shows the lines that differ)."""
+    with open(os.path.join(DATA, "block_diffusion_ops.sdar_30b_a3b.txt")) as fh:
+        assert block_diffusion_operations() == fh.read().splitlines()
 
 
 # -- the body against the reference ------------------------------------------------------
@@ -473,3 +506,8 @@ def test_the_registered_app_trains(tmp_path):
     assert set(out["counters"]) == set(moe.COUNTERS) | {"bd_masked_positions"}
     assert out["counters"]["moe_dropped_slots"] == 0
     assert out["counters"]["bd_masked_positions"] > 0
+
+
+if __name__ == "__main__":  # the file of the program test above, from the checkout on the path
+    with open(os.path.join(DATA, "block_diffusion_ops.sdar_30b_a3b.txt"), "w") as f:
+        f.write("\n".join(block_diffusion_operations()) + "\n")

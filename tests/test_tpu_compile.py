@@ -11,7 +11,9 @@ plane are the gather and scatter fusions and the in-place trash reset.
 Blocked attention under a window (``ops/blocked_attention.py``, PR 35) is
 compiled here at a window layer's published shapes: what the step's own
 compile showed (stacked masks of 1.9 GB a layer under a checkpoint,
-``blocked_attention._tied``) shows only for the described chip's memory.
+``blocked_attention._tied``) shows only for the described chip's memory;
+so are the causal tiles at each cell's full-attention shapes, run one
+sequence at a time as the bodies run them.
 
 All such compiles live in this one file, and the topology is described
 inside a fixture: only one process may load the TPU's library.
@@ -198,7 +200,7 @@ def _attention(window, by_sequence=False):
     layer (``models/moe.py::by_sequence``)."""
     def attn(q, k, v, qs=None, ks=None):
         return blocked_causal_attention(
-            q, k, v, block=256, band=8, scale=0.125, q_shared=qs, k_shared=ks,
+            q, k, v, block=256, scale=0.125, q_shared=qs, k_shared=ks,
             window=window,
         )
 
@@ -242,6 +244,33 @@ def test_windowed_attention_at_a_window_layer_s_published_shapes(
         for dims in re.findall(r"= (?:f32|pred)\[([\d,]+)\]", c.as_text())
     ]
     assert max(sizes) <= B * 64 * S * 128
+
+
+@pytest.mark.parametrize("cell,H,Hkv,D,Dv,Dr", [
+    ("kimi_linear_a3b.pretrain8k", 32, 32, 128, 128, 64),
+    ("lfm2_8b_a1b.pretrain8k", 32, 8, 64, 64, 0),
+    ("laguna_xs2.pretrain8k", 48, 8, 128, 128, 0),
+])
+def test_causal_attention_by_sequence_keeps_no_stacked_masks(one_chip, cell, H, Hkv, D, Dv, Dr):
+    """Two sequences of 8,192 run as a body runs them (a ``lax.map`` over a
+    checkpointed layer), value and gradients: the causal tiles keep nothing
+    stacked over the blocks, so no value the compiled program forms is
+    larger than the queries' ``[S, H, D]`` and its temporaries stay under
+    1.5 GiB (1.10 GiB for kimi's, 0.41 lfm2's, 1.08 laguna's).  The banded
+    rows the tiles replaced kept their masks stacked from the forward to
+    the backward pass: 2.06, 3.91 and 5.74 GiB, a largest value of 8 to 16
+    times the queries'."""
+    S, B = 8192, 2
+    shapes = [_sds((B, S, H, D)), _sds((B, S, Hkv, D)), _sds((B, S, Hkv, Dv))]
+    if Dr:
+        shapes += [_sds((B, S, H, Dr)), _sds((B, S, Dr))]
+    c = _compile(_attention(None, by_sequence=True)(len(shapes)), one_chip, *shapes)
+    assert c.memory_analysis().temp_size_in_bytes < 3 << 29
+    sizes = [
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"= (?:f32|pred)\[([\d,]+)\]", c.as_text())
+    ]
+    assert max(sizes) <= B * S * H * D
 
 
 @pytest.mark.parametrize("cell,H,Hkv,D,Dv,Dr", [
