@@ -392,6 +392,9 @@ def run_other_apps(tmp, n_devices):
         {"app": "sdar_hybrid", "table": emb, "data": small,
          "consistency": {"mode": "ssp", "max_delay": 1},
          "topology": {"num_servers": 2}},
+        {"app": "qwen3_next_hybrid", "table": emb, "data": small,
+         "consistency": {"mode": "ssp", "max_delay": 1},
+         "topology": {"num_servers": 2}},
         {"app": "sp_lm", "table": emb, "data": small},
         {"app": "sptp_lm", "table": emb, "data": small,
          "topology": {"mesh_shape": [sp, tp]}},

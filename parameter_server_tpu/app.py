@@ -406,6 +406,26 @@ def _build_sdar_hybrid(cfg: AppConfig) -> Callable[[], dict]:
     return run
 
 
+@register_app("qwen3_next_hybrid")
+def _build_qwen3_next_hybrid(cfg: AppConfig) -> Callable[[], dict]:
+    """The same hybrid path under a body of gated DeltaNet and gated
+    attention layers (``models/qwen3_next.py``: the delta rule under a
+    scalar decay a head over grouped key heads, attention gated element by
+    element, a held share of softmax-routed experts beside a gated shared
+    one in every layer), tiny sizes by default so the app runs anywhere;
+    the benchmark's ``qwen3_next_80b_a3b`` configuration runs the published
+    widths through the same trainer."""
+
+    def run() -> dict:
+        from parameter_server_tpu.models import qwen3_next
+
+        return _run_hybrid(cfg, qwen3_next.tiny_config(
+            vocab_size=min(cfg.data.key_space, 1 << 16),
+        ))
+
+    return run
+
+
 def _sp_app_knobs(cfg: AppConfig, round_to: int):
     """Shared knobs of the long-context apps (sp_lm / sptp_lm).
 

@@ -75,10 +75,11 @@ class HybridLMTrainer:
     (gated short convolutions and grouped-query attention, dense and
     bias-selected expert MLPs), a ``LagunaConfig`` (window and full
     attention layers with their own head counts and rotary tables, a gated
-    attention output, dense and expert MLPs with a shared expert) or an
+    attention output, dense and expert MLPs with a shared expert), an
     ``SdarMoeConfig`` (trained by block diffusion: the tokens are ``[xt ;
     x0]``, a masked copy beside the clean sequence, and the body reads its
-    loss off them).
+    loss off them) or a ``Qwen3NextConfig`` (gated DeltaNet and gated
+    attention layers, every one with experts beside a gated shared one).
 
     **Buffers.**  A body may hold leaves that are state and not weights (an
     expert layer's selection bias): its config names them (``cfg.buffers``,

@@ -289,18 +289,6 @@ def init_params(cfg: KimiLinearConfig, key) -> dict:
 
 
 # -- layers ---------------------------------------------------------------------
-def _causal_conv(x, w):
-    """Depthwise causal convolution over time: ``x [B, S, H, K]``, ``w [W, H,
-    K]``; ``y_t = sum_i w_i x_{t - (W - 1) + i}``."""
-    width, S = w.shape[0], x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0), (0, 0)))
-    return sum(xp[:, i:i + S] * w[i] for i in range(width))
-
-
-def _l2(x):
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-
-
 def kda_mixer(cfg: KimiLinearConfig, p, x):
     K = cfg.linear_head_dim
     with _scope("kda.proj"):
@@ -316,10 +304,10 @@ def kda_mixer(cfg: KimiLinearConfig, p, x):
         beta = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", x, p["b"]["kernel"]))
     with _scope("kda.conv"):
         q, k, v = (
-            jax.nn.silu(_causal_conv(a, p[f"conv_{n}"]))
+            jax.nn.silu(moe.causal_conv(a, p[f"conv_{n}"]))
             for a, n in ((q, "q"), (k, "k"), (v, "v"))
         )
-        q, k = _l2(q) / np.sqrt(K), _l2(k)
+        q, k = moe.l2_normalize(q) / np.sqrt(K), moe.l2_normalize(k)
         log_a = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(f + p["dt_bias"])
     with _scope("kda.scan"):
         o, _state = chunk_kda(q, k, v, log_a, beta, chunk=cfg.kda_chunk)

@@ -281,19 +281,11 @@ def init_params(cfg: Lfm2MoeConfig, key) -> dict:
 
 
 # -- layers ---------------------------------------------------------------------
-def _causal_conv(z, taps):
-    """Depthwise causal convolution over time: ``z [B, S, C]``, ``taps [L,
-    C]``; ``c_t = sum_j taps_j z_{t - (L - 1) + j}``."""
-    L, S = taps.shape[0], z.shape[1]
-    zp = jnp.pad(z, ((0, 0), (L - 1, 0), (0, 0)))
-    return sum(zp[:, j:j + S] * taps[j] for j in range(L))
-
-
 def conv_mixer(cfg: Lfm2MoeConfig, p, x):
     with _scope("conv.proj"):
         b, c, u = jnp.einsum("bsd,gdc->gbsc", x, p["in_proj"]["kernel"])
     with _scope("conv.gate"):
-        y = c * _causal_conv(b * u, p["taps"])
+        y = c * moe.causal_conv(b * u, p["taps"])
     with _scope("conv.out"):
         return y @ p["out_proj"]["kernel"]
 

@@ -1,9 +1,10 @@
 """What the layer-pattern bodies of the hybrid path share
 (``models/kimi_linear.py``, ``models/lfm2_moe.py``, ``models/laguna.py``,
-``models/sdar_moe.py``): the held-share expert layer, the norm and the
-SwiGLU around it, the checkpointed loop over a body's layers, the counters a
-step returns, and the two losses: next-token (:func:`head_loss`) and
-masked, weighted and unshifted (:func:`weighted_loss`).
+``models/sdar_moe.py``, ``models/qwen3_next.py``): the held-share expert
+layer, the norms and the SwiGLU around it, the checkpointed loop over a
+body's layers, the counters a step returns, and the two losses: next-token
+(:func:`head_loss`) and masked, weighted and unshifted
+(:func:`weighted_loss`).
 
 **The expert layer.**  ``s = sigmoid(x' W_r)`` over all routed experts
 (where the body scores by a softmax, ``s = softmax(x' W_r)`` over all
@@ -11,7 +12,8 @@ routed outputs) in float32 at the highest matrix precision; ``sel =
 top_k(s + bias)`` (the selection bias takes part in the selection only; a
 caller without one passes none); ``w_i = scale s_i / sum_{j in sel} s_j``
 (without renormalisation ``scale s_i``); ``y = sum_{i in sel, held} w_i E_i(x')``
-plus a shared expert where the parameters hold one.  **The held share**:
+plus a shared expert where the parameters hold one (``sigmoid(x' W_sg)
+E_shared(x')`` where they hold its gate too).  **The held share**:
 this process holds experts ``[first, first + held)``; the router keeps
 every output; what the absent experts would add is left out, here and in
 the references alike, and nothing stands in for them.  Token slots that
@@ -34,8 +36,10 @@ counts ``moe_blocks_run``.
 What differs between the bodies is :class:`ExpertLayer` (experts a token,
 the scale, the share, the block, the router's scoring, the body's device
 scope) and what the
-layer's parameters hold (``shared``: a shared expert; ``expert_bias``: the
-selection bias, a buffer that takes no gradient and no update).
+layer's parameters hold (``shared``: a shared expert; ``shared_gate``: its
+gate; ``expert_bias``: the selection bias, a buffer that takes no gradient
+and no update), and a norm's weight (``scale``, or ``offset`` for a
+zero-centred norm, :func:`norm`).
 
 Device scopes are written as paths under the body's own
 (``<root>/ps.model.<name>``, :func:`scope`): ``ps.model.moe.router`` /
@@ -103,6 +107,27 @@ def rms_norm(x, scale, eps):
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
+def norm(p, x, eps):
+    """The RMSNorm whose weight ``p`` holds: ``scale`` (``x^ * scale``) or
+    ``offset``, zero-centred (``x^ * (1 + offset)``)."""
+    if "offset" in p:
+        return rms_norm(x, 1.0 + p["offset"], eps)
+    return rms_norm(x, p["scale"], eps)
+
+
+def causal_conv(x, w):
+    """Depthwise causal convolution over time: ``x [B, S, *C]``, ``w [W,
+    *C]``; ``y_t = sum_i w_i x_{t - (W - 1) + i}``."""
+    width, S = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (width - 1, 0)) + ((0, 0),) * (x.ndim - 2))
+    return sum(xp[:, i:i + S] * w[i] for i in range(width))
+
+
+def l2_normalize(x):
+    """``x / |x|`` over the last axis, 1e-6 under the root."""
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+
 def swiglu(p, x):
     h = jax.nn.silu(x @ p["gate"]["kernel"]) * (x @ p["up"]["kernel"])
     return h @ p["down"]["kernel"]
@@ -130,15 +155,16 @@ def init_tree(shapes, key, init_scale: float, special):
     """Seeded float32 parameters for the tree ``{name: ... shape}``: a leaf's
     key is the tree's folded with a hash of its path, so a leaf keeps its
     values whatever else the tree holds.  ``scale`` leaves are ones,
-    ``bias`` leaves zeros, ``special(leaf, key, shape)`` gives a body's own
-    (None: not one of them), every other leaf is normal at ``init_scale``."""
+    ``bias`` and ``offset`` leaves zeros, ``special(leaf, key, shape)``
+    gives a body's own (None: not one of them), every other leaf is normal
+    at ``init_scale``."""
     def make(path, shape):
         name = "/".join(str(getattr(p, "key", p)) for p in path)
         k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
         leaf = name.rsplit("/", 1)[-1]
         if leaf == "scale":
             return jnp.ones(shape, jnp.float32)
-        if leaf == "bias":
+        if leaf in ("bias", "offset"):
             return jnp.zeros(shape, jnp.float32)
         own = special(leaf, k, shape)
         if own is not None:
@@ -315,7 +341,8 @@ def route(layer: ExpertLayer, router_kernel, xt, bias=None):
 
 def moe_layer(layer: ExpertLayer, p, x):
     """``x [B, S, D]`` (normed) -> ``(y, counters)``: the held experts' part
-    of the routed sum, plus the shared expert where ``p`` holds one.
+    of the routed sum, plus the shared expert where ``p`` holds one, under
+    its sigmoid gate ``sigmoid(x W_sg)`` where ``p`` holds ``shared_gate``.
     ``p["expert_bias"]``, where present, is the selection bias."""
     B, S, D = x.shape
     N, k, Eh, bm = B * S, layer.top_k, layer.held, layer.block
@@ -344,14 +371,17 @@ def moe_layer(layer: ExpertLayer, p, x):
     y = y[:N]
     if "shared" in p:
         with scope(layer.root, "moe.shared"):
-            y = y + swiglu(p["shared"], xt)
+            shared = swiglu(p["shared"], xt)
+            if "shared_gate" in p:  # [D, 1]: one gate a token
+                shared = jax.nn.sigmoid(xt @ p["shared_gate"]["kernel"]) * shared
+            y = y + shared
     return y.reshape(B, S, D), counters
 
 
 def mlp_block(layer: ExpertLayer, kind: str, eps: float, p, x):
     """A layer's second half, ``x + FF(RMSNorm(x))`` -> ``(x, counters)``:
     ``kind`` ``dense`` (a SwiGLU, no counters) or ``experts``."""
-    h = rms_norm(x, p["mlp_norm"]["scale"], eps)
+    h = norm(p["mlp_norm"], x, eps)
     if kind == "dense":
         with scope(layer.root, "mlp"):
             return x + swiglu(p["mlp"], h), {}
@@ -390,7 +420,7 @@ def trunk(layer: ExpertLayer, kinds, mixer_block, eps, per_sequence, params, x):
             most = jnp.maximum(most, c["moe_max_expert_slots"])
             if "moe_blocks_run" in c:
                 run = c["moe_blocks_run"] + (zero if run is None else run)
-    hidden = rms_norm(x, params["final_norm"]["scale"], eps)
+    hidden = norm(params["final_norm"], x, eps)
     counters = dict(zip(COUNTERS, (held, dropped, most)))
     if run is not None:
         counters["moe_blocks_run"] = run

@@ -1,5 +1,5 @@
-"""Chunked gated delta rule with a per-channel decay (KDA), forward and
-backward, in plain XLA operations.
+"""Chunked gated delta rule with a per-channel decay (KDA) or a per-head
+scalar one (gated DeltaNet), forward and backward, in plain XLA operations.
 
 The recurrence, per head, with a state ``S [d_k, d_v]``::
 
@@ -31,6 +31,14 @@ chunk).  So a chunk is cut into sub-blocks of ``sub`` steps: a pair of
 sub-blocks ``i > j`` factors through the cumulative sum at the end of
 sub-block ``i - 1`` (three terms, each at most 1, one matrix product), and a
 diagonal sub-block is summed exactly over its ``sub x sub x d_k`` terms.
+
+**A scalar decay a head** (gated DeltaNet, :func:`chunk_gated_delta`):
+``a_t`` is one value broadcast over ``d_k``, so ``e^(G_t - G_s)`` leaves
+the sum over channels, ``A = (K K^T) * D`` and ``P = (Q K^T) * D`` with
+``D[t, s] = e^(G_t - G_s)`` (``s <= t``) one ``C x C`` matrix a head, its
+exponent masked before it is taken: plain matrix products, no sub-blocks.
+Key heads may be fewer than value heads: a key head's products serve each
+of its value heads under that head's decay.
 
 **The backward** is jax's differentiation of this file under
 ``jax.checkpoint``: every step is a matrix product, an elementwise function
@@ -112,33 +120,30 @@ def _chunk_terms(q, k, v, g, beta, sub):
     return w[..., :K], w[..., K:], q * decay, P, k_end, g_end
 
 
-def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
-              initial_state=None):
-    """``q, k, g [B, S, H, K]``, ``v [B, S, H, V]``, ``beta [B, S, H]`` ->
-    ``(o [B, S, H, V], final state [B, H, K, V])``.  ``q`` and ``k`` arrive
-    normalised and scaled by the caller; ``g = log a <= 0``.  A length that
-    is no multiple of ``chunk`` is padded with steps that leave the state as
-    it is (``g = 0``, ``beta = 0``) and whose outputs are cut off."""
-    B, S, H, K = q.shape
-    V = v.shape[-1]
-    chunk = min(chunk, max(sub, -(-S // sub) * sub))
+def _chunks(xs, chunk: int):
+    """``xs``: ``[B, S, H, *]`` arrays -> ``[NC, B, H, C, *]`` float32, a
+    length that is no multiple of ``chunk`` padded with zeros (steps whose
+    ``g = 0`` and ``beta = 0`` leave the state as it is)."""
+    S = xs[0].shape[1]
     pad = (-S) % chunk
-    if pad:
-        q, k, v, g = (
-            jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v, g)
-        )
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
     nc = (S + pad) // chunk
 
-    def chunks(a):  # [B, S, H, *] -> [NC, B, H, C, *]
-        a = a.reshape(B, nc, chunk, H, *a.shape[3:])
+    def one(a):
+        a = a.astype(jnp.float32)
+        if pad:
+            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape(a.shape[0], nc, chunk, *a.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(a, 3, 1), 2, 0)
 
+    return tuple(one(a) for a in xs)
+
+
+def _scan_chunks(terms, initial_state, S: int):
+    """The chunks in turn over the state: ``terms`` ``(W_k, W_v, Q~, P,
+    K_end, g_C)`` ``[NC, B, H, C, *]`` (``g_C`` with a last axis of ``K``,
+    or of 1 where the decay is a head's scalar) -> ``(o [B, S, H, V],
+    final state [B, H, K, V])``."""
     f32 = jnp.float32
-    terms = _chunk_terms(
-        chunks(q.astype(f32)), chunks(k.astype(f32)), chunks(v.astype(f32)),
-        chunks(g.astype(f32)), chunks(beta.astype(f32)), sub,
-    )
 
     def step(state, inp):
         w_k, w_v, q_dec, p, k_end, g_end = inp
@@ -153,12 +158,101 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
         )
         return state, o
 
-    if initial_state is None:
-        initial_state = jnp.zeros((B, H, K, V), f32)
     state, o = jax.lax.scan(step, initial_state.astype(f32), terms)
     # [NC, B, H, C, V] -> [B, S, H, V]
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3).reshape(B, nc * chunk, H, V)
+    nc, B, H, C, V = o.shape
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3).reshape(B, nc * C, H, V)
     return o[:, :S], state
+
+
+def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
+              initial_state=None):
+    """``q, k, g [B, S, H, K]``, ``v [B, S, H, V]``, ``beta [B, S, H]`` ->
+    ``(o [B, S, H, V], final state [B, H, K, V])``.  ``q`` and ``k`` arrive
+    normalised and scaled by the caller; ``g = log a <= 0``.  A length that
+    is no multiple of ``chunk`` is padded with steps that leave the state as
+    it is (``g = 0``, ``beta = 0``) and whose outputs are cut off."""
+    B, S, H, K = q.shape
+    V = v.shape[-1]
+    chunk = min(chunk, max(sub, -(-S // sub) * sub))
+    terms = _chunk_terms(*_chunks((q, k, v, g, beta), chunk), sub)
+    if initial_state is None:
+        initial_state = jnp.zeros((B, H, K, V), jnp.float32)
+    return _scan_chunks(terms, initial_state, S)
+
+
+def _gated_terms(q, k, v, g, beta):
+    """:func:`_chunk_terms` under a head's scalar decay, its key heads
+    grouped: ``q, k [..., Hk, C, K]``, ``v [..., Hv, C, V]``, ``g, beta
+    [..., Hv, C]``, value head ``j`` reading key head ``j // (Hv / Hk)``.
+    ``A`` and ``P`` are the key heads' products times one ``C x C`` decay
+    matrix a value head, its exponent masked before it is taken."""
+    *lead, Hk, C, K = k.shape
+    Hv = v.shape[-3]
+    grouped = (*lead, Hk, Hv // Hk, C)
+
+    def per_value(x):  # [..., Hk, C, *] -> [..., Hv, C, *]
+        x = jnp.broadcast_to(x[..., :, None, :, :], (*grouped, x.shape[-1]))
+        return x.reshape(*lead, Hv, C, x.shape[-1])
+
+    G = jnp.cumsum(g, axis=-1)
+    t = jnp.arange(C)
+    decay = jnp.exp(jnp.where(
+        t[None, :] <= t[:, None], G[..., :, None] - G[..., None, :], -jnp.inf
+    ))  # [..., Hv, t, s]: e^(G_t - G_s) for s <= t, else 0
+    kk, qk = (
+        jnp.einsum("...tc,...sc->...ts", a, k, preferred_element_type=jnp.float32)
+        for a in (k, q)
+    )
+
+    def decayed(x):  # [..., Hk, C, C] x the value heads' decay
+        return (x[..., :, None, :, :] * decay.reshape(*grouped, C)).reshape(decay.shape)
+
+    A = jnp.tril(decayed(kk), -1)
+    P = decayed(qk)
+    e = jnp.exp(G)[..., None]
+    k_v = per_value(k)
+    rhs = jnp.concatenate([beta[..., None] * (k_v * e), beta[..., None] * v], axis=-1)
+    w = jax.lax.linalg.triangular_solve(
+        beta[..., None] * A, rhs, left_side=True, lower=True,
+        unit_diagonal=True,
+    )
+    g_end = G[..., -1:]
+    k_end = k_v * jnp.exp(g_end - G)[..., None]
+    return w[..., :K], w[..., K:], per_value(q) * e, P, k_end, g_end
+
+
+def chunk_gated_delta(q, k, v, g, beta, *, chunk: int = 64,
+                      initial_state=None):
+    """The delta rule under a scalar decay a head and position (gated
+    DeltaNet): ``q, k [B, S, Hk, K]``, ``v [B, S, Hv, V]``, ``g, beta [B, S,
+    Hv]`` with ``Hv`` a multiple of ``Hk`` (value head ``j`` reads key head
+    ``j // (Hv / Hk)``) -> ``(o [B, S, Hv, V], final state [B, Hv, K, V])``.
+    ``q`` and ``k`` arrive normalised and scaled by the caller; ``g = log a
+    <= 0``.  The recurrence of the module docstring with ``a_t`` one value
+    broadcast over ``d_k``: its chunked form needs no sub-blocks, since
+    ``e^(G_t - G_s)`` is one ``C x C`` matrix a head, masked before the
+    exponent is taken; a length that is no multiple of ``chunk`` is padded
+    as :func:`chunk_kda` pads it."""
+    B, S, Hk, K = q.shape
+    Hv, V = v.shape[2:]
+    if Hv % Hk:
+        raise ValueError(f"{Hv} value heads over {Hk} key heads")
+    terms = _gated_terms(*_chunks((q, k, v, g, beta), min(chunk, S)))
+    if initial_state is None:
+        initial_state = jnp.zeros((B, Hv, K, V), jnp.float32)
+    return _scan_chunks(terms, initial_state, S)
+
+
+def recurrent_gated_delta(q, k, v, g, beta, initial_state=None):
+    """:func:`chunk_gated_delta`'s recurrence token by token: the key heads
+    repeated for their value heads and the decay broadcast over ``d_k``
+    into :func:`recurrent_kda`.  Same shapes."""
+    G = v.shape[2] // q.shape[2]
+    q, k = (jnp.repeat(a, G, axis=2) for a in (q, k))
+    return recurrent_kda(
+        q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta, initial_state
+    )
 
 
 def recurrent_kda(q, k, v, g, beta, initial_state=None):

@@ -31,6 +31,15 @@ H]``, one value a head: column-parallel over the heads beside ``q``, whose
 heads it gates.  A layer's head count is its own kernels' (48 and 64 in one
 body): every rule here reads a leaf's shape and none a config's one count,
 and each count splits where the key-value heads it is grouped over do.
+The gated DeltaNet body (``models/qwen3_next.py``) adds its projections
+``qkvz`` ``[D, Hk, 2 d_k + 2 r d_v]`` and ``ba`` ``[D, Hk, 2 r]`` over the
+key-head axis, which carries each key head's ``r`` value heads whole, the
+convolution's taps ``conv`` ``[W, Hk, 2 d_k + r d_v]`` over the same axis
+(each channel is one key head's), ``A_log`` and
+``dt_bias`` ``[Hv]`` over the value heads (``o`` ``[Hv, d_v, D]``
+row-parallel beside them), and the shared expert's gate ``shared_gate``
+``[D, 1]`` whole; its attention's ``q`` ``[D, H, 2 K]`` holds a head's
+query and gate, both split with the head.
 """
 
 from __future__ import annotations
@@ -55,7 +64,7 @@ def _spec_for(path: tuple[str, ...], value: Any) -> P:
         return P()
     if parent == "experts":  # [E, d_model, width] / [E, width, d_model]
         return P(MODEL_AXIS, None, None)
-    if leaf.startswith("conv_"):  # depthwise [width, heads, head_dim]
+    if leaf == "conv" or leaf.startswith("conv_"):  # depthwise [width, heads, n]
         return P(None, MODEL_AXIS, None)
     if leaf == "taps":  # depthwise [taps, channels]
         return P(None, MODEL_AXIS)
@@ -65,14 +74,18 @@ def _spec_for(path: tuple[str, ...], value: Any) -> P:
         return P(MODEL_AXIS, None)
     if leaf == "A_log":  # [heads]
         return P(MODEL_AXIS)
-    if leaf == "dt_bias":  # [heads, head_dim]
-        return P(MODEL_AXIS, None)
+    if leaf == "dt_bias":  # [heads, head_dim] or [heads]
+        return P(MODEL_AXIS, *[None] * (ndim - 1))
     if parent in ("f_b", "g_b", "kv_b"):
         if leaf == "kernel":  # [rank, heads, head_dim]
             return P(None, MODEL_AXIS, None)
         return P(MODEL_AXIS, None)  # bias [heads, head_dim]
     if parent in ("b", "o_gate"):  # [d_model, heads]
         return P(None, MODEL_AXIS)
+    if parent in ("qkvz", "ba"):  # [d_model, key heads, a key head's share]
+        return P(None, MODEL_AXIS, None)
+    if parent == "shared_gate":  # [d_model, 1]: one gate a token, whole
+        return P()
     if parent in ("q", "k", "v"):
         if leaf == "kernel":  # [d_model, heads, head_dim]
             return P(None, MODEL_AXIS, None)

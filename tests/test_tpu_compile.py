@@ -250,13 +250,16 @@ def test_windowed_attention_at_a_window_layer_s_published_shapes(
     ("kimi_linear_a3b.pretrain8k", 32, 32, 128, 128, 64),
     ("lfm2_8b_a1b.pretrain8k", 32, 8, 64, 64, 0),
     ("laguna_xs2.pretrain8k", 48, 8, 128, 128, 0),
+    ("qwen3_next_80b_a3b.pretrain8k", 16, 2, 256, 256, 0),
 ])
 def test_causal_attention_by_sequence_keeps_no_stacked_masks(one_chip, cell, H, Hkv, D, Dv, Dr):
     """Two sequences of 8,192 run as a body runs them (a ``lax.map`` over a
     checkpointed layer), value and gradients: the causal tiles keep nothing
     stacked over the blocks, so no value the compiled program forms is
     larger than the queries' ``[S, H, D]`` and its temporaries stay under
-    1.5 GiB (1.10 GiB for kimi's, 0.41 lfm2's, 1.08 laguna's).  The banded
+    1.5 GiB (1.10 GiB for kimi's, 0.41 lfm2's, 1.08 laguna's, 0.65
+    qwen3_next's at head size 256, whose rotary turns 64 of them and is
+    not the kernel's).  The banded
     rows the tiles replaced kept their masks stacked from the forward to
     the backward pass: 2.06, 3.91 and 5.74 GiB, a largest value of 8 to 16
     times the queries'."""
@@ -292,3 +295,33 @@ def test_a_window_of_every_key_compiles_to_the_causal_program(one_chip, cell, H,
         for w in (None, S)
     )
     assert causal == whole and "while" in causal
+
+
+# -- the gated delta rule --------------------------------------------------------
+def test_the_scalar_decay_scan_keeps_only_its_chunk_states(one_chip):
+    """Value and gradients of ``ops/delta_rule.py::chunk_gated_delta`` under a
+    checkpoint, as a DeltaNet layer runs it: one sequence of 8,192 tokens,
+    16 key heads and 32 value heads of 128, chunks of 64.  The chip's
+    compiler takes it; no value it forms is larger than the per-chunk
+    states the backward's reverse scan needs (``[128 chunks, 32, 128,
+    128]``: ``W`` of all chunks, ``[128, 32, 64, 256]``, is as large), so
+    nothing of a chunk's ``C x C`` decay matrices or of the elementwise
+    terms is stacked across the checkpoint beyond them, and its temporaries
+    stay under 3 GiB (1.90 GiB)."""
+    from parameter_server_tpu.ops.delta_rule import chunk_gated_delta
+
+    S, Hk, Hv, K = 8192, 16, 32, 128
+
+    @jax.checkpoint
+    def f(q, k, v, g, beta):
+        return jnp.sum(chunk_gated_delta(q, k, v, g, beta, chunk=64)[0])
+
+    step = jax.jit(jax.value_and_grad(f, argnums=tuple(range(5))))
+    c = _compile(step, one_chip, _sds((1, S, Hk, K)), _sds((1, S, Hk, K)),
+                 _sds((1, S, Hv, K)), _sds((1, S, Hv)), _sds((1, S, Hv)))
+    assert c.memory_analysis().temp_size_in_bytes < 3 << 30
+    sizes = [
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"= (?:f32|pred)\[([\d,]+)\]", c.as_text())
+    ]
+    assert max(sizes) <= (S // 64) * Hv * K * K
